@@ -285,7 +285,7 @@ WARM_PACK_PATH = _conf(
     "(constructing the program-cache builders) and each recorded "
     "program signature is compiled in the background pool, so the "
     "first user-visible query per shape is already warm. The "
-    "manifest is validated against the host CPU-feature fingerprint "
+    "manifest is validated against the jaxlib + mesh fingerprint "
     "and version; a mismatched or corrupt pack is skipped with a "
     "warning, never an error. Empty: no preload. Hard-disabled by "
     "SRTPU_COMPILE_CACHE=0 alongside the persistent XLA cache.", str)

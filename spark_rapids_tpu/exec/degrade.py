@@ -1,7 +1,7 @@
 """Graceful device->host degradation for device-kernel failures.
 
 A device kernel that raises a non-OOM, non-cancellation error (a
-miscompile, a broken accelerator tunnel, an injected fault) used to
+miscompile, a lost device, an injected fault) used to
 fail the whole query. With `sql.exec.degradeToHost.enabled` the
 operator instead re-evaluates the FAILED batch on the host interpreter
 (the exec/host_fallback path), and after ``FAILURE_THRESHOLD`` device

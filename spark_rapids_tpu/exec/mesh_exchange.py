@@ -34,12 +34,6 @@ from typing import List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
-# jax.shard_map is the public spelling from ~0.6; older jax ships it as
-# jax.experimental.shard_map.shard_map
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from ..columnar import dtypes as dt
 from ..columnar.column import bucket_capacity
 from ..columnar.table import Schema
@@ -121,7 +115,7 @@ class MeshExchangeExec(TpuExec):
             return _flatten_cvs(out_cvs), jnp.stack(stats)
 
         def step(flat, mask):
-            return _shard_map(
+            return jax.shard_map(
                 shard_fn, mesh=mesh,
                 in_specs=(tuple(P(axis) for _ in flat), P(axis)),
                 out_specs=(tuple(P(axis) for _ in flat), P(axis)),

@@ -61,12 +61,6 @@ from typing import List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-# jax.shard_map is the public spelling from ~0.6; older jax ships it as
-# jax.experimental.shard_map.shard_map
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from ..columnar.column import bucket_capacity
 from ..expr.expressions import EmitCtx
 from ..ops.concat import concat_cvs, concat_masks, pad_mask
@@ -432,7 +426,7 @@ class SpmdStageExec(TpuExec):
             return _flatten_cvs(outs), jnp.stack(stats)
 
         def step(flat, mask):
-            return _shard_map(
+            return jax.shard_map(
                 shard_fn, mesh=mesh,
                 in_specs=(tuple(P(axis) for _ in flat), P(axis)),
                 out_specs=(tuple(P(axis) for _ in range(n_out_flat)),

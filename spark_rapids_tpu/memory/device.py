@@ -18,6 +18,9 @@ import jax
 __all__ = ["DeviceManager", "BudgetExceeded", "device_manager"]
 
 
+_CPU_BUDGET_BYTES = 12 * (1 << 30)
+
+
 class BudgetExceeded(Exception):
     """Raised when an HBM reservation cannot be satisfied even after
     spilling everything spillable."""
@@ -35,13 +38,16 @@ class DeviceManager:
 
     @staticmethod
     def _detect_budget(fraction: float) -> int:
-        try:
-            stats = jax.devices()[0].memory_stats()
-            if stats and "bytes_limit" in stats:
-                return int(stats["bytes_limit"] * fraction)
-        except Exception:
-            pass
-        return int(12 * (1 << 30) * fraction)  # v5e-ish default
+        dev = jax.devices()[0]
+        stats = dev.memory_stats()
+        if stats and "bytes_limit" in stats:
+            return int(stats["bytes_limit"] * fraction)
+        if dev.platform != "cpu":
+            raise RuntimeError(
+                f"{dev.platform} device {dev.device_kind!r} reports no "
+                f"bytes_limit; pass budget_bytes explicitly")
+        # XLA:CPU reports no limit: budget host memory as a 12 GiB device
+        return int(_CPU_BUDGET_BYTES * fraction)
 
     # ------------------------------------------------------------------
     def register_spill_hook(self, hook: Callable[[int], int]):

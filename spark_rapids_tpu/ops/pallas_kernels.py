@@ -22,15 +22,6 @@ _LANES = 128
 _SUBLANES = 8
 
 
-def _x64_disabled():
-    """jax.enable_x64(False) is the public spelling from ~0.6; older
-    jax ships the equivalent as jax.experimental.disable_x64()."""
-    if hasattr(jax, "enable_x64"):
-        return jax.enable_x64(False)
-    from jax.experimental import disable_x64
-    return disable_x64()
-
-
 def _make_kernel(num_partitions: int):
     def kernel(vals_ref, valid_ref, out_ref):
         x = vals_ref[:, :].astype(jnp.uint32)
@@ -76,7 +67,7 @@ def pallas_partition_ids_i32(vals, validity, num_partitions: int,
     v2 = vals.reshape(rows, _LANES)
     m2 = validity.reshape(rows, _LANES)
     grid = (rows // _SUBLANES,)
-    with _x64_disabled():
+    with jax.enable_x64(False):
         out = pl.pallas_call(
             _make_kernel(num_partitions),
             grid=grid,

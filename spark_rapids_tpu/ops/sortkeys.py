@@ -149,9 +149,64 @@ def lexsort(keys: Sequence[jnp.ndarray],
             jax.ShapeDtypeStruct((n,), jnp.int32),
             *keys, vmap_method="sequential")
     iota = jnp.arange(n, dtype=jnp.int32)
+    if jax.default_backend() != "cpu":
+        return _lexsort_lsd32(keys, iota)
     ops = list(keys) + [iota]
     out = jax.lax.sort(ops, num_keys=len(keys), is_stable=True)
     return out[-1]
+
+
+def _words32(keys: Sequence[jnp.ndarray]) -> List[jnp.ndarray]:
+    """`keys` as 32-bit words, most-significant first, whose lexicographic
+    order is the keys' own: runs of up to four 8-bit flag keys pack into
+    one uint32, a 64-bit integer splits into (high word keeping its sign,
+    low word unsigned). float64 keys stay one 64-bit word (no f64<->s64
+    bitcast exists under the x64 rewrite)."""
+    words, flags = [], []
+
+    def flush():
+        if flags:
+            w = flags[0].astype(jnp.uint32)
+            for f in flags[1:]:
+                w = (w << 8) | f.astype(jnp.uint32)
+            words.append(w)
+            flags.clear()
+
+    for k in keys:
+        if k.dtype in (jnp.uint8, jnp.bool_):
+            flags.append(k)
+            if len(flags) == 4:
+                flush()
+            continue
+        flush()
+        if k.dtype == jnp.int64:
+            words += [(k >> 32).astype(jnp.int32), k.astype(jnp.uint32)]
+        elif k.dtype == jnp.uint64:
+            words += [(k >> 32).astype(jnp.uint32), k.astype(jnp.uint32)]
+        elif k.dtype in (jnp.int8, jnp.int16):
+            words.append(k.astype(jnp.int32))
+        else:
+            words.append(k)
+    flush()
+    return words
+
+
+def _lexsort_lsd32(keys: Sequence[jnp.ndarray], iota) -> jnp.ndarray:
+    """The same stable permutation as the variadic sort, as one stable
+    single-key sort per 32-bit word, least-significant word first.
+
+    The TPU compiler's time for a sort grows steeply with the operand
+    count and with 8-bit and emulated 64-bit operands (q3's top-k sort,
+    six keys of which two s64, took 408 s to compile for a v5e at 128 Ki
+    rows; seven chained (uint32 key, int32 perm) sorts of the same rows
+    took 22 s), so off the CPU every pass sorts exactly two 32-bit
+    operands."""
+    import jax
+    perm = iota
+    for i, w in enumerate(reversed(_words32(keys))):
+        _, perm = jax.lax.sort([w if i == 0 else w[perm], perm],
+                               num_keys=1, is_stable=True)
+    return perm
 
 
 def group_boundaries(sorted_keys: Sequence[jnp.ndarray]) -> jnp.ndarray:

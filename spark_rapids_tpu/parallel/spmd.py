@@ -16,12 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# jax.shard_map is the public spelling from ~0.6; older jax ships it as
-# jax.experimental.shard_map.shard_map
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from ..ops import sortkeys as sk
 from ..ops.hash import partition_ids
 from ..ops.kernel_utils import CV
@@ -73,7 +67,7 @@ def make_distributed_groupby_sum(mesh: Mesh, axis_name: str = "data"):
             ko, so, lo = local_group_sum(karr, varr, mask2)
             return ko, so, lo
 
-        return _shard_map(
+        return jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(P(axis_name), P(axis_name), P(axis_name), P()),
             out_specs=(P(axis_name), P(axis_name), P(axis_name)),

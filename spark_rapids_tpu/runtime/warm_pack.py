@@ -15,10 +15,10 @@ so a query arriving mid-preload is never queued behind warm-up work.
 
 Safety posture mirrors the persistent cache it extends:
 
-- the manifest is bound to `_cache_fingerprint()` (CPU model + features
-  + jaxlib) and a format version; a mismatch logs one warning and
-  preloads nothing — programs traced for another microarchitecture
-  must not be reconstructed here.
+- the manifest is bound to the jaxlib version, the mesh fingerprint
+  (device kind + count) and a format version; a mismatch logs one
+  warning and preloads nothing — programs traced for another
+  installation or topology must not be reconstructed here.
 - a corrupt/unreadable pack logs a warning, never raises: warm-up is
   advisory.
 - keys carrying identity fallbacks (`('id', N)` / `('inst', N)`) are
@@ -89,13 +89,13 @@ def reset() -> None:
 
 
 def _fingerprint() -> str:
-    from .. import _cache_fingerprint
+    import jaxlib
     from ..parallel.mesh import mesh_fingerprint
     # packs are per-topology: a manifest recorded against an 8-device
     # mesh carries sharded collective signatures that can never warm a
     # 1-device process (and would waste its compile-pool budget), so
     # the device kind + visible device count gates the load
-    return _cache_fingerprint() + "|" + mesh_fingerprint()
+    return f"jaxlib-{jaxlib.__version__}|{mesh_fingerprint()}"
 
 
 def build_manifest(conf=None) -> dict:

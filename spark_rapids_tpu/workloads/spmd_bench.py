@@ -104,13 +104,12 @@ def main() -> int:
 
     n_dev = min(int(os.environ.get("SPMD_BENCH_DEVICES", "8")),
                 len(jax.devices()))
-    doc = {"n_devices": n_dev, "queries": {}, "ok": True,
-           "skipped": False}
+    doc = {"n_devices": n_dev, "queries": {}, "ok": True}
     if n_dev < 2:
-        doc.update(ok=True, skipped=True,
+        doc.update(ok=False,
                    reason=f"{len(jax.devices())} device(s); mesh needs 2+")
         print(json.dumps(doc))
-        return 0
+        return 2
 
     sf = float(os.environ.get("SPMD_BENCH_SF", "0.02"))
     # small batches force multiple shards/batches per partition so the

@@ -8,12 +8,6 @@ distributed groupby/join end-to-end.
 """
 import jax
 import jax.numpy as jnp
-
-# jax.shard_map is the public spelling from ~0.6; older jax ships it as
-# jax.experimental.shard_map.shard_map
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
 import numpy as np
 import pyarrow as pa
 import pytest
@@ -67,7 +61,7 @@ def _run_exchange(mesh, arrays, mask, pids, use_cvs=False, cvs=None):
                     out_flat.append(cv.offsets)
             return tuple(out_flat), out_mask
 
-        step = jax.jit(_shard_map(
+        step = jax.jit(jax.shard_map(
             fn, mesh=mesh,
             in_specs=(tuple(P("data") for _ in flat), P("data"),
                       P("data")),
@@ -81,7 +75,7 @@ def _run_exchange(mesh, arrays, mask, pids, use_cvs=False, cvs=None):
         out, om = exchange_rows(list(arrs), m, p, n)
         return tuple(out), om
 
-    step = jax.jit(_shard_map(
+    step = jax.jit(jax.shard_map(
         fn, mesh=mesh,
         in_specs=(tuple(P("data") for _ in arrays), P("data"), P("data")),
         out_specs=(tuple(P("data") for _ in arrays), P("data"))))
@@ -404,3 +398,10 @@ def _walk(node):
         yield m
     for c in node.children:
         yield from _walk(c)
+
+
+def test_make_mesh_too_few_devices_raises_not_cpu_fallback():
+    """Too few devices on the default platform is an error: a mesh never
+    moves to another platform (it used to return virtual CPU devices)."""
+    with pytest.raises(ValueError, match="need 9 devices, have 8"):
+        make_mesh(len(jax.devices()) + 1)

@@ -1,4 +1,4 @@
-"""Plan-time static auditor (analysis/audit.py): verdict taxonomy,
+"""Plan-time static auditor (analysis/audit.py): verdict classes,
 VALIDATE explain, strict mode, and the NOT_ON_TPU event-log surface.
 
 The acceptance case: a dtype mismatch the binders accept but the device
@@ -79,7 +79,7 @@ def test_non_strict_keeps_verdict_but_does_not_raise():
 
 
 # ----------------------------------------------------------------------
-# verdict taxonomy
+# verdict classes
 # ----------------------------------------------------------------------
 def test_unregistered_expression_tags_will_not_work(monkeypatch):
     """An expression class with no TypeSig registration is flagged: the

@@ -1,0 +1,144 @@
+"""Main-path programs, asked of the TPU compiler at SF1 shapes without a chip.
+
+The TPU's compiler is installed here and compiles for a v5e chip that is
+described, not attached (on-chip-measurement guide, section 2). Nothing
+runs: a case passes when the chip's compiler accepts the engine's own
+program at the width chip_smoke.py runs it. The topology is described
+inside a module-scoped fixture (only one process may load libtpu, so never
+at import), and the persistent compile cache is off around the compiles
+(an entry written here cannot be read back without a chip).
+
+All cases live in this one file: a second file could land on another
+xdist worker, whose fixture would then skip every case in silence.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+BATCH = 1 << 20          # sql.batchSizeRows: one SF1 batch capacity
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+
+
+def _compile(fn, *args):
+    """Lower and compile `fn` for the described chip. Engine code that
+    asks jax.default_backend() while it is traced is steered here, in the
+    test, onto the branch it takes on the chip (ops/sortkeys.lexsort)."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        compiled = jax.jit(fn).lower(*args).compile()
+    assert compiled.memory_analysis() is not None
+    return compiled
+
+
+def _at_rows(tree, cap0, rows, sharding):
+    """Shapes of `tree` (a small batch's cvs/mask at capacity `cap0`)
+    scaled to `rows`: row-indexed leaves, offsets (rows+1) and string
+    byte buffers grow with the batch."""
+    def leaf(x):
+        n = x.shape[0]
+        n = rows + 1 if n == cap0 + 1 else max(n * rows // cap0, n)
+        return jax.ShapeDtypeStruct((n,) + x.shape[1:], x.dtype,
+                                    sharding=sharding)
+    return jax.tree.map(leaf, tree)
+
+
+def _planned(session, qn, kind):
+    """q`qn` over small HBM-cached tables, planned by the engine: the
+    first exec node of class `kind` and one cached lineitem batch."""
+    from spark_rapids_tpu.plan.planner import Planner
+    from spark_rapids_tpu.workloads import tpch
+    dfs = {"lineitem": tpch.gen_lineitem(0.001, 0, True),
+           "orders": tpch.gen_orders(0.001, 1, True),
+           "customer": tpch.gen_customer(0.001, 2, True)}
+    dfs = {k: session.create_dataframe(v).cache() for k, v in dfs.items()}
+    root = Planner(session.conf).plan(tpch.queries()[qn](dfs)._plan)
+    stack = [root]
+    while stack:
+        node = stack.pop()
+        if type(node).__name__ == kind:
+            return node, dfs["lineitem"]._plan.batches[0]
+        stack.extend(node.children)
+    raise AssertionError(f"no {kind} in q{qn}'s plan")
+
+
+def test_q6_step_8mi_rows(one_chip):
+    """The q6 step of __graft_entry__.entry() at 8 Mi rows of FLOAT64."""
+    import __graft_entry__ as graft
+    step, example = graft.entry()
+    n = 8 << 20
+    args = [jax.ShapeDtypeStruct((n,), jnp.asarray(a).dtype,
+                                 sharding=one_chip) for a in example]
+    _compile(step, *args)
+
+
+def test_pallas_partition_ids_1mi_rows(one_chip):
+    """The one Pallas kernel through Mosaic (never interpret=True)."""
+    from spark_rapids_tpu.ops.pallas_kernels import pallas_partition_ids_i32
+    vals = jax.ShapeDtypeStruct((BATCH,), jnp.int32, sharding=one_chip)
+    valid = jax.ShapeDtypeStruct((BATCH,), jnp.bool_, sharding=one_chip)
+    compiled = _compile(
+        lambda v, m: pallas_partition_ids_i32(v, m, 16), vals, valid)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_q1_partial_aggregate_update_1mi_rows(session, one_chip):
+    """q1's first-pass aggregate program (HashAggregateExec, tag
+    'hash_update': filter + projections + bucketed hash aggregate, one
+    dispatch per lineitem batch) at one SF1 batch capacity. At SF1 the
+    planner splits q1 into partial / exchange / final (input above 2 Mi
+    rows, plan/planner.py); the small tables here plan 'complete', so the
+    test rebuilds the same node in partial mode from the planned node's
+    own child, keys and aggregates. q1 plans no FusedStageExec: the
+    filter chain collapses into this program."""
+    from spark_rapids_tpu.config import AGG_STRING_HASH_KEYS
+    from spark_rapids_tpu.exec.aggregate import HashAggregateExec
+    agg, small = _planned(session, 1, "HashAggregateExec")
+    child = agg.children[0]
+    partial = HashAggregateExec(child, agg.key_names, agg.keys,
+                                agg.agg_names, agg.aggs, child.schema,
+                                mode="partial")
+    partial._resolve_fusion()
+    update = partial._hash_update_fn(
+        partial._batch_nchunks(small),
+        partial._has_string_keys()
+        and bool(session.conf.get(AGG_STRING_HASH_KEYS)))
+    cvs, mask = _at_rows((tuple(small.cvs()), small.row_mask),
+                         small.capacity, BATCH, one_chip)
+    _compile(update, cvs, mask)
+
+
+def test_q3_topk_sort_64bit_keys(session, one_chip):
+    """q3's SortExec program (lexsort on revenue DESC — a two-limb
+    decimal key of 64-bit words — then o_orderdate) at the capacity its
+    SF1 aggregate emits."""
+    from spark_rapids_tpu.exec.sort import sort_batch_cvs
+    sort, _ = _planned(session, 3, "SortExec")
+    cap = 1 << 17
+    cvs = []
+    for f in sort.children[0].schema.fields:
+        assert not f.dtype.is_variable_width
+        limbs = (2,) if getattr(f.dtype, "is_decimal128", False) else ()
+        cvs.append((jax.ShapeDtypeStruct((cap,) + limbs, f.dtype.np_dtype,
+                                         sharding=one_chip),
+                    jax.ShapeDtypeStruct((cap,), jnp.bool_,
+                                         sharding=one_chip)))
+    mask = jax.ShapeDtypeStruct((cap,), jnp.bool_, sharding=one_chip)
+    from spark_rapids_tpu.ops.kernel_utils import CV
+    _compile(lambda c, m: sort_batch_cvs([CV(*x) for x in c], m,
+                                         sort.orders, (0, 0)), cvs, mask)
