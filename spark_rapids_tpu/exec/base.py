@@ -81,7 +81,8 @@ class ExecContext:
     def metrics_for(self, op_id: str) -> MetricSet:
         with self._metrics_lock:
             if op_id not in self.metrics:
-                self.metrics[op_id] = MetricSet(sync=self.metrics_sync)
+                self.metrics[op_id] = MetricSet(sync=self.metrics_sync,
+                                                op_id=op_id)
             return self.metrics[op_id]
 
     def check_cancel(self):

@@ -11,7 +11,7 @@ bounds DEVICE admission.) Two pieces live here:
 
 The deadlock `PermitRider` exists to avoid: the thread that triggers
 `_ensure_shuffled` usually already HOLDS a semaphore permit —
-`collect_to_arrow.run_part` acquires around `next(it)`, and advancing
+`nodes._collect.run_part` acquires around `next(it)`, and advancing
 the iterator is exactly what materializes the shuffle. With
 `sql.concurrentTpuTasks=1`, map workers blocking on `sem.acquire`
 would wait forever on a permit their own caller holds. Worse, with

@@ -19,7 +19,7 @@ from ..columnar.column import Column, bucket_capacity
 from ..columnar.table import Field, Schema, Table
 from ..expr.expressions import EmitCtx, Expression
 from ..ops.kernel_utils import CV
-from ..profiler import xla_stats
+from ..profiler import tracing, xla_stats
 from ..runtime import faults
 from .base import ExecContext, TpuExec
 from .batch import DeviceBatch
@@ -980,6 +980,11 @@ class UnionExec(TpuExec):
 
 # ----------------------------------------------------------------------
 def _batch_to_arrow(batch: DeviceBatch):
+    with tracing.span("export", "op"):
+        return _export_batch(batch)
+
+
+def _export_batch(batch: DeviceBatch):
     import pyarrow as pa
     from ..columnar.column import Column
     from ..utils.transfer import fetch
@@ -1004,6 +1009,11 @@ def collect_to_arrow(root: TpuExec, ctx: ExecContext):
     GpuColumnarToRowExec + collect). Partitions run as concurrent tasks
     bounded by the TpuSemaphore (the GpuSemaphore admission model:
     reference GpuSemaphore.scala:183)."""
+    with tracing.span("collect", "op"):
+        return _collect(root, ctx)
+
+
+def _collect(root: TpuExec, ctx: ExecContext):
     import pyarrow as pa
     nparts = root.num_partitions(ctx)
     if nparts <= 1:

@@ -31,6 +31,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..profiler import tracing
 from . import parquet_thrift as pt
 
 __all__ = ["chunk_device_plan", "decode_chunk_device",
@@ -253,127 +254,128 @@ def chunk_device_plan(pf, path: str, rg: int, ci: int,
     element table and decompress on device."""
     import time as _time
 
-    col = pf.metadata.row_group(rg).column(ci)
-    start = col.data_page_offset
-    if col.has_dictionary_page and col.dictionary_page_offset is not None:
-        start = min(start, col.dictionary_page_offset)
-    size = col.total_compressed_size
-    staging = []
-    if pool is not None:
-        lease = pool.acquire(size)
-        staging.append(lease)
-        with open(path, "rb") as f:
-            f.seek(start)
-            if f.readinto(lease.view()) != size:
+    with tracing.span("io.read", "io"):
+        col = pf.metadata.row_group(rg).column(ci)
+        start = col.data_page_offset
+        if col.has_dictionary_page and col.dictionary_page_offset is not None:
+            start = min(start, col.dictionary_page_offset)
+        size = col.total_compressed_size
+        staging = []
+        if pool is not None:
+            lease = pool.acquire(size)
+            staging.append(lease)
+            with open(path, "rb") as f:
+                f.seek(start)
+                if f.readinto(lease.view()) != size:
+                    for b in staging:
+                        b.release()
+                    return None
+            raw = memoryview(lease.array)[:size]
+        else:
+            with open(path, "rb") as f:
+                f.seek(start)
+                raw = f.read(size)
+        try:
+            pages = pt.parse_page_headers(raw, col.num_values)
+        except pt.ThriftError:
+            for b in staging:
+                b.release()
+            return None
+        for p in pages:
+            ok = True
+            if p.page_type == pt.DATA_PAGE:
+                if p.encoding not in (pt.PLAIN, pt.PLAIN_DICTIONARY,
+                                      pt.RLE_DICTIONARY):
+                    ok = False
+                if nullable and p.def_level_encoding != pt.RLE:
+                    ok = False
+            elif p.page_type == pt.DATA_PAGE_V2:
+                if p.encoding not in (pt.PLAIN, pt.PLAIN_DICTIONARY,
+                                      pt.RLE_DICTIONARY):
+                    ok = False
+                if p.rep_levels_byte_length > 0:
+                    ok = False                 # flat columns only
+            if not ok:
                 for b in staging:
                     b.release()
                 return None
-        raw = memoryview(lease.array)[:size]
-    else:
-        with open(path, "rb") as f:
-            f.seek(start)
-            raw = f.read(size)
-    try:
-        pages = pt.parse_page_headers(raw, col.num_values)
-    except pt.ThriftError:
-        for b in staging:
-            b.release()
-        return None
-    for p in pages:
-        ok = True
-        if p.page_type == pt.DATA_PAGE:
-            if p.encoding not in (pt.PLAIN, pt.PLAIN_DICTIONARY,
-                                  pt.RLE_DICTIONARY):
-                ok = False
-            if nullable and p.def_level_encoding != pt.RLE:
-                ok = False
-        elif p.page_type == pt.DATA_PAGE_V2:
-            if p.encoding not in (pt.PLAIN, pt.PLAIN_DICTIONARY,
-                                  pt.RLE_DICTIONARY):
-                ok = False
-            if p.rep_levels_byte_length > 0:
-                ok = False                 # flat columns only
-        if not ok:
-            for b in staging:
-                b.release()
-            return None
 
-    dev_pages = []
-    if col.compression == "SNAPPY":
-        t0 = _time.perf_counter()
-        total_out = sum(max(p.uncompressed_size, 0) for p in pages)
-        if pool is not None:
-            out_lease = pool.acquire(total_out)
-            staging.append(out_lease)
-            out = out_lease.array
-        else:
-            out = np.zeros(max(total_out, 1), np.uint8)
-        new_pages = []
-        tasks = []                    # (src span, out_off, expect)
-        dst = 0
-        for p in pages:
-            usize = max(p.uncompressed_size, 0)
-            np_page = replace(p, data_offset=dst, compressed_size=usize)
-            new_pages.append(np_page)
-            off, end = p.data_offset, p.data_offset + p.compressed_size
-            if p.page_type == pt.DATA_PAGE_V2:
-                # v2 keeps levels UNCOMPRESSED ahead of the data section
-                lvl = max(p.rep_levels_byte_length, 0) \
-                    + max(p.def_levels_byte_length, 0)
-                lvl = min(lvl, min(p.compressed_size, usize))
-                out[dst:dst + lvl] = np.frombuffer(
-                    raw[off:off + lvl], np.uint8)
-                if p.data_compressed:
-                    tasks.append((raw[off + lvl:end], dst + lvl,
-                                  usize - lvl))
-                else:
-                    out[dst + lvl:dst + usize] = np.frombuffer(
-                        raw[off + lvl:end], np.uint8)
-            elif (device_snappy and p.page_type == pt.DATA_PAGE
-                  and p.encoding == pt.PLAIN and not nullable):
-                try:
-                    out_len, dl, ll, sl = _parse_snappy_elements(
-                        raw, off, end)
-                except pt.ThriftError:
-                    tasks.append((raw[off:end], dst, usize))
-                else:
-                    if out_len != usize:
+        dev_pages = []
+        if col.compression == "SNAPPY":
+            t0 = _time.perf_counter()
+            total_out = sum(max(p.uncompressed_size, 0) for p in pages)
+            if pool is not None:
+                out_lease = pool.acquire(total_out)
+                staging.append(out_lease)
+                out = out_lease.array
+            else:
+                out = np.zeros(max(total_out, 1), np.uint8)
+            new_pages = []
+            tasks = []                    # (src span, out_off, expect)
+            dst = 0
+            for p in pages:
+                usize = max(p.uncompressed_size, 0)
+                np_page = replace(p, data_offset=dst, compressed_size=usize)
+                new_pages.append(np_page)
+                off, end = p.data_offset, p.data_offset + p.compressed_size
+                if p.page_type == pt.DATA_PAGE_V2:
+                    # v2 keeps levels UNCOMPRESSED ahead of the data section
+                    lvl = max(p.rep_levels_byte_length, 0) \
+                        + max(p.def_levels_byte_length, 0)
+                    lvl = min(lvl, min(p.compressed_size, usize))
+                    out[dst:dst + lvl] = np.frombuffer(
+                        raw[off:off + lvl], np.uint8)
+                    if p.data_compressed:
+                        tasks.append((raw[off + lvl:end], dst + lvl,
+                                      usize - lvl))
+                    else:
+                        out[dst + lvl:dst + usize] = np.frombuffer(
+                            raw[off + lvl:end], np.uint8)
+                elif (device_snappy and p.page_type == pt.DATA_PAGE
+                      and p.encoding == pt.PLAIN and not nullable):
+                    try:
+                        out_len, dl, ll, sl = _parse_snappy_elements(
+                            raw, off, end)
+                    except pt.ThriftError:
                         tasks.append((raw[off:end], dst, usize))
                     else:
-                        comp = np.frombuffer(raw[off:end], np.uint8)
-                        # tpulint: allow[host-sync] python lists, no
-                        el = [np.asarray(x, np.int32)  # device data
-                              for x in (dl, ll, sl)]
-                        dev_pages.append(
-                            (dst, comp, el[0], el[1], el[2], len(dl),
-                             out_len))
-            else:
-                tasks.append((raw[off:end], dst, usize))
-            dst += usize
-        codec = _snappy_codec()
-        try:
-            if decomp_pool is not None and len(tasks) > 1:
-                # per-page, parallel across pages: pyarrow's snappy
-                # releases the GIL, so the prefetch pool really fans out
-                list(decomp_pool.map(
-                    lambda t: _decompress_page(codec, t[0], out, t[1],
-                                               t[2]), tasks))
-            else:
-                for src, ooff, expect in tasks:
-                    _decompress_page(codec, src, out, ooff, expect)
-        except Exception:
-            for b in staging:
-                b.release()
-            return None
-        if metrics is not None:
-            metrics.add("decompressBusySecs",
-                        _time.perf_counter() - t0)
-            metrics.add("decompressedBytes", total_out)
-        raw = memoryview(out)[:total_out]
-        pages = new_pages
-    return DeviceChunk(name, col.physical_type, nullable, raw, pages,
-                       col.num_values, staging=staging,
-                       dev_pages=dev_pages)
+                        if out_len != usize:
+                            tasks.append((raw[off:end], dst, usize))
+                        else:
+                            comp = np.frombuffer(raw[off:end], np.uint8)
+                            # tpulint: allow[host-sync] python lists, no
+                            el = [np.asarray(x, np.int32)  # device data
+                                  for x in (dl, ll, sl)]
+                            dev_pages.append(
+                                (dst, comp, el[0], el[1], el[2], len(dl),
+                                 out_len))
+                else:
+                    tasks.append((raw[off:end], dst, usize))
+                dst += usize
+            codec = _snappy_codec()
+            try:
+                if decomp_pool is not None and len(tasks) > 1:
+                    # per-page, parallel across pages: pyarrow's snappy
+                    # releases the GIL, so the prefetch pool really fans out
+                    list(decomp_pool.map(
+                        lambda t: _decompress_page(codec, t[0], out, t[1],
+                                                   t[2]), tasks))
+                else:
+                    for src, ooff, expect in tasks:
+                        _decompress_page(codec, src, out, ooff, expect)
+            except Exception:
+                for b in staging:
+                    b.release()
+                return None
+            if metrics is not None:
+                metrics.add("decompressBusySecs",
+                            _time.perf_counter() - t0)
+                metrics.add("decompressedBytes", total_out)
+            raw = memoryview(out)[:total_out]
+            pages = new_pages
+        return DeviceChunk(name, col.physical_type, nullable, raw, pages,
+                           col.num_values, staging=staging,
+                           dev_pages=dev_pages)
 
 
 def _parse_sections(c: DeviceChunk):
@@ -639,87 +641,89 @@ def decode_chunk_device(c: DeviceChunk, cap: int, metrics=None):
 
     from ..ops import parquet_decode as pd
 
-    try:
-        def_runs, plain_pages, dict_idx_pages, dict_page = \
-            _parse_sections(c)
-    except pt.ThriftError:
-        return None                   # malformed page section: fallback
-    if plain_pages and dict_idx_pages:
-        return None                   # mixed-encoding chunk: fallback
-    chunk_dev = _chunk_device_bytes(c, metrics)
-    n = c.num_values
+    with tracing.span("io.decode", "io"):
+        try:
+            def_runs, plain_pages, dict_idx_pages, dict_page = \
+                _parse_sections(c)
+        except pt.ThriftError:
+            return None                   # malformed page section: fallback
+        if plain_pages and dict_idx_pages:
+            return None                   # mixed-encoding chunk: fallback
+        with tracing.span("io.upload", "io"):
+            chunk_dev = _chunk_device_bytes(c, metrics)
+        n = c.num_values
 
-    # -- def levels -> validity + per-page non-null counts -------------
-    if c.nullable and def_runs:
-        R = pd.bucket_len(len(def_runs))
-        rs = np.full(R, n, np.int32)
-        rc = np.zeros(R, np.int32)
-        rp = np.zeros(R, np.int32)
-        rv = np.zeros(R, np.int32)
-        rb = np.zeros(R, np.int32)
-        for i, r in enumerate(def_runs):
-            rs[i], rc[i], rp[i] = r.out_start, r.count, int(r.is_packed)
-            rv[i], rb[i] = r.value, r.byte_offset
-        def_levels = pd.expand_hybrid(
-            chunk_dev, jnp.asarray(rs), jnp.asarray(rc),
-            jnp.asarray(rp), jnp.asarray(rv), jnp.asarray(rb),
-            len(def_runs), n, 1, cap)
-        valid = def_levels == 1
-    else:
-        i = jnp.arange(cap, dtype=jnp.int32)
-        valid = i < n
-        def_levels = valid.astype(jnp.int32)
-
-    if c.physical == "BYTE_ARRAY":
-        return _decode_strings(c, valid, cap, plain_pages,
-                               dict_idx_pages, dict_page)
-
-    width = _PHYS_WIDTH[c.physical]
-    np_name = _PHYS_NP[c.physical]
-
-    # -- packed value stream -------------------------------------------
-    if plain_pages:
-        P = pd.bucket_len(len(plain_pages))
-        po = np.zeros(P, np.int32)
-        pr = np.full(P, n, np.int32)      # first ROW of page (sentinel n)
-        for i, (off, row) in enumerate(plain_pages):
-            po[i], pr[i] = off, row
-        if c.nullable:
-            # PLAIN stores non-null values only: first VALUE index of
-            # each page = count of valid rows before the page (device)
-            vcnt = jnp.cumsum(valid.astype(jnp.int32))
-            pr_dev = jnp.asarray(pr)
-            prev_row = jnp.clip(pr_dev - 1, 0, cap - 1)
-            first_val = jnp.where(pr_dev > 0, vcnt[prev_row], 0) \
-                .astype(jnp.int32)
+        # -- def levels -> validity + per-page non-null counts -------------
+        if c.nullable and def_runs:
+            R = pd.bucket_len(len(def_runs))
+            rs = np.full(R, n, np.int32)
+            rc = np.zeros(R, np.int32)
+            rp = np.zeros(R, np.int32)
+            rv = np.zeros(R, np.int32)
+            rb = np.zeros(R, np.int32)
+            for i, r in enumerate(def_runs):
+                rs[i], rc[i], rp[i] = r.out_start, r.count, int(r.is_packed)
+                rv[i], rb[i] = r.value, r.byte_offset
+            def_levels = pd.expand_hybrid(
+                chunk_dev, jnp.asarray(rs), jnp.asarray(rc),
+                jnp.asarray(rp), jnp.asarray(rv), jnp.asarray(rb),
+                len(def_runs), n, 1, cap)
+            valid = def_levels == 1
         else:
-            first_val = jnp.asarray(pr)
-        packed = pd.decode_plain_fixed(
-            chunk_dev, jnp.asarray(po), first_val,
-            len(plain_pages), n, width, cap)
-    elif dict_idx_pages:
-        if dict_page is None:
-            return None
-        ndict = dict_page.num_values
-        dcap = pd.bucket_len(max(ndict, 1), floor=128)
-        d_po = np.zeros(8, np.int32)
-        d_pr = np.full(8, ndict, np.int32)
-        d_po[0], d_pr[0] = dict_page.data_offset, 0
-        dict_words = pd.decode_plain_fixed(
-            chunk_dev, jnp.asarray(d_po), jnp.asarray(d_pr), 1,
-            ndict, width, dcap)
-        idx = _dict_indices(c, valid, dict_idx_pages, cap)
-        if idx is None:
-            return None
-        packed = dict_words[jnp.clip(idx, 0, dcap - 1)]
-    else:
-        return None
+            i = jnp.arange(cap, dtype=jnp.int32)
+            valid = i < n
+            def_levels = valid.astype(jnp.int32)
 
-    if c.nullable:
-        words, valid = pd.apply_def_levels(def_levels, packed, 1, n, cap)
-    else:
-        words = packed[:cap] if packed.shape[0] >= cap else jnp.pad(
-            packed, (0, cap - packed.shape[0]))
-        words = jnp.where(valid, words, 0)
-    vals = pd.words_to_device(words, np_name)
-    return vals, valid
+        if c.physical == "BYTE_ARRAY":
+            return _decode_strings(c, valid, cap, plain_pages,
+                                   dict_idx_pages, dict_page)
+
+        width = _PHYS_WIDTH[c.physical]
+        np_name = _PHYS_NP[c.physical]
+
+        # -- packed value stream -------------------------------------------
+        if plain_pages:
+            P = pd.bucket_len(len(plain_pages))
+            po = np.zeros(P, np.int32)
+            pr = np.full(P, n, np.int32)      # first ROW of page (sentinel n)
+            for i, (off, row) in enumerate(plain_pages):
+                po[i], pr[i] = off, row
+            if c.nullable:
+                # PLAIN stores non-null values only: first VALUE index of
+                # each page = count of valid rows before the page (device)
+                vcnt = jnp.cumsum(valid.astype(jnp.int32))
+                pr_dev = jnp.asarray(pr)
+                prev_row = jnp.clip(pr_dev - 1, 0, cap - 1)
+                first_val = jnp.where(pr_dev > 0, vcnt[prev_row], 0) \
+                    .astype(jnp.int32)
+            else:
+                first_val = jnp.asarray(pr)
+            packed = pd.decode_plain_fixed(
+                chunk_dev, jnp.asarray(po), first_val,
+                len(plain_pages), n, width, cap)
+        elif dict_idx_pages:
+            if dict_page is None:
+                return None
+            ndict = dict_page.num_values
+            dcap = pd.bucket_len(max(ndict, 1), floor=128)
+            d_po = np.zeros(8, np.int32)
+            d_pr = np.full(8, ndict, np.int32)
+            d_po[0], d_pr[0] = dict_page.data_offset, 0
+            dict_words = pd.decode_plain_fixed(
+                chunk_dev, jnp.asarray(d_po), jnp.asarray(d_pr), 1,
+                ndict, width, dcap)
+            idx = _dict_indices(c, valid, dict_idx_pages, cap)
+            if idx is None:
+                return None
+            packed = dict_words[jnp.clip(idx, 0, dcap - 1)]
+        else:
+            return None
+
+        if c.nullable:
+            words, valid = pd.apply_def_levels(def_levels, packed, 1, n, cap)
+        else:
+            words = packed[:cap] if packed.shape[0] >= cap else jnp.pad(
+                packed, (0, cap - packed.shape[0]))
+            words = jnp.where(valid, words, 0)
+        vals = pd.words_to_device(words, np_name)
+        return vals, valid

@@ -8,12 +8,26 @@ exchange/broadcast map pools and remote executors. One trace per query
 query's event log and reduced to latency shares by
 profiler/critical_path.py.
 
+TWO SINKS behind the one API. Every span also enters a
+`jax.profiler.TraceAnnotation("srt." + name)`, so while a profile is
+being taken the program's spans sit on the profiler's clock beside the
+device's timeline (whether a profile runs is the only switch; the
+annotation is inert otherwise). The dict record below is kept only
+under a sampled TraceContext. Spans of the kinds in `PROFILER_ONLY`
+(`launch`, `d2h`, `op`, `io`: many per query, meaningful only beside
+the device; `d2h` is the kind of `srt.fetch`, because `fetch` is
+already the kind of a remote block fetch, a dict-recorded edge) write
+no dict record at all, so critical_path.py's vocabulary stays what
+docs/observability.md says it is. Back-dated spans
+(`record_wait_span`) cannot be annotations and stay dict-only.
+
 Design constraints, in order:
 
 1. CHEAP WHEN OFF. `span()` resolves the active TraceContext with one
-   attribute read; an unsampled/disabled trace yields a shared no-op
-   span and touches nothing else. The <3% q6 A/B overhead gate in
-   tests/test_tracing.py holds the tracing-ON path to the same bar.
+   attribute read; an unsampled/disabled trace enters the (inert)
+   annotation, yields a shared no-op span and touches nothing else.
+   The <3% q6 A/B overhead gate in tests/test_tracing.py holds the
+   tracing-ON path to the same bar.
 2. ONE TRACE PER QUERY ACROSS PROCESSES. The context is three fields
    (trace_id, span_id, sampled) and rides:
      - `ExecContext.trace` on the query thread,
@@ -50,7 +64,10 @@ from collections import OrderedDict
 from contextlib import contextmanager
 from typing import Dict, List, Optional
 
-__all__ = ["TraceContext", "Span", "start_trace", "current", "use",
+from jax.profiler import TraceAnnotation as _Annotation
+
+__all__ = ["TraceContext", "Span", "PROFILER_ONLY", "PREFIX",
+           "start_trace", "current", "use",
            "span", "open_span", "record_span", "drain_trace",
            "record_queue_span", "record_wait_span", "finish",
            "to_wire", "from_wire",
@@ -61,6 +78,11 @@ __all__ = ["TraceContext", "Span", "start_trace", "current", "use",
 #: executor task functions rebuild TpuSession(conf) from this very dict,
 #: so the context crosses the RPC boundary with zero frame changes
 TRACE_CONF_KEY = "spark.rapids.tpu.sql.trace.context"
+
+#: every span's name on the profiler's clock is PREFIX + name
+PREFIX = "srt."
+#: kinds that go to the profiler only and never write a dict record
+PROFILER_ONLY = frozenset({"launch", "d2h", "op", "io"})
 
 _SEQ = itertools.count(1)
 _TLS = threading.local()
@@ -103,10 +125,12 @@ class TraceContext:
 
 class Span:
     """One open span. End it exactly once (with-statement or finally);
-    ending records the finished dict into the per-trace buffer."""
+    ending records the finished dict into the per-trace buffer. As a
+    context manager it also points the thread-local context at itself
+    for the block."""
 
     __slots__ = ("trace_id", "span_id", "parent_id", "name", "kind",
-                 "start_ns", "attrs", "_t0", "_done", "_restore")
+                 "start_ns", "attrs", "_t0", "_done", "_ann", "_restore")
 
     def __init__(self, trace_id, span_id, parent_id, name, kind, attrs):
         self.trace_id = trace_id
@@ -115,10 +139,22 @@ class Span:
         self.name = name
         self.kind = kind
         self.attrs = attrs
+        self._ann = _Annotation(PREFIX + name, query=trace_id)
+        self._ann.__enter__()
         self.start_ns = time.time_ns()
         self._t0 = time.perf_counter()
         self._done = False
         self._restore = None
+
+    def __enter__(self):
+        self._restore = getattr(_TLS, "ctx", None)
+        _TLS.ctx = TraceContext(self.trace_id, self.span_id, True)
+        return self
+
+    def __exit__(self, *exc):
+        _TLS.ctx = self._restore
+        self.end()
+        return False
 
     def set(self, key: str, value) -> None:
         """Attach one attribute (retry counts, byte sizes, fault tags)."""
@@ -131,6 +167,7 @@ class Span:
             return
         self._done = True
         dur = time.perf_counter() - self._t0
+        self._ann.__exit__(None, None, None)
         rec = {"trace_id": self.trace_id, "span_id": self.span_id,
                "parent_id": self.parent_id, "name": self.name,
                "kind": self.kind, "start_ns": self.start_ns,
@@ -153,6 +190,29 @@ class _NoopSpan:
 
 
 _NOOP = _NoopSpan()
+
+
+class _ProfilerSpan(_NoopSpan):
+    """A span that is off-trace or of a PROFILER_ONLY kind: no dict
+    record, only the profiler's annotation to end."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, ann):
+        self._ann = ann
+        ann.__enter__()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self.end()
+        return False
+
+    def end(self):
+        ann, self._ann = self._ann, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
 
 
 # ---------------------------------------------------------------------
@@ -213,33 +273,29 @@ def _resolve(ctx) -> Optional[TraceContext]:
 def open_span(name: str, kind: str, ctx=None, **attrs):
     """Open a span without the with-statement (callers that must end it
     in an async callback). MUST be paired with `.end()` in a finally —
-    the span-leak lint rule flags anything else. Returns a no-op span
-    off-trace."""
+    the span-leak lint rule flags anything else. Off-trace (and for a
+    PROFILER_ONLY kind) the span records nothing and only ends its
+    annotation."""
+    if kind in PROFILER_ONLY:
+        return _ProfilerSpan(_Annotation(PREFIX + name, **attrs))
     tc = _resolve(ctx)
     if tc is None:
-        return _NOOP
+        return _ProfilerSpan(_Annotation(PREFIX + name))
     return Span(tc.trace_id, _new_span_id(), tc.span_id, name, kind,
                 attrs or None)
 
 
-@contextmanager
 def span(name: str, kind: str, ctx=None, **attrs):
-    """Open/close one span around a block. While the block runs, the
-    thread-local context points at this span, so nested `span()` calls
-    (and worker threads seeded via `use(current())`) parent under it."""
-    tc = _resolve(ctx)
-    if tc is None:
-        yield _NOOP
-        return
-    sp = Span(tc.trace_id, _new_span_id(), tc.span_id, name, kind,
-              attrs or None)
-    prev = getattr(_TLS, "ctx", None)
-    _TLS.ctx = TraceContext(tc.trace_id, sp.span_id, True)
-    try:
-        yield sp
-    finally:
-        _TLS.ctx = prev
-        sp.end()
+    """`with span(...)`: open/close one span around a block. While the
+    block runs, the thread-local context points at this span, so nested
+    `span()` calls (and worker threads seeded via `use(current())`)
+    parent under it. Off-trace only the profiler's annotation is
+    entered. A PROFILER_ONLY kind gets the bare annotation (`attrs`
+    become its stats; there is no `.set()`): these are the spans that
+    are many per query, and this is a third of the wrapper's cost."""
+    if kind in PROFILER_ONLY:
+        return _Annotation(PREFIX + name, **attrs)
+    return open_span(name, kind, ctx, **attrs)
 
 
 def record_span(rec: dict) -> None:
@@ -320,9 +376,11 @@ def finish(ctx, wall_s=None) -> List[dict]:
     no root span of its own)."""
     tc = getattr(ctx, "trace", None)
     rsp = getattr(ctx, "_root_span", None)
-    if tc is None or rsp is None:
+    if rsp is None:
         return []
-    rsp.end()
+    rsp.end()           # off-trace this ends the annotation alone
+    if tc is None:
+        return []
     spans = drain_trace(tc.trace_id)
     if not spans:
         return []

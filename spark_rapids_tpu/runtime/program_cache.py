@@ -39,6 +39,7 @@ import weakref
 from collections import OrderedDict
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
+from ..profiler import tracing
 from . import lockdep, racedep
 
 __all__ = ["cached_program", "CachedProgram", "stats", "clear",
@@ -539,7 +540,7 @@ class CachedProgram:
     reuses the first-seen builder's trace (that is the point)."""
 
     __slots__ = ("_fn", "_base_key", "_donate", "_static", "_local",
-                 "__weakref__")
+                 "_name", "_launch", "__weakref__")
 
     def __init__(self, fn, base_key: tuple,
                  donate_argnums: Tuple[int, ...] = (),
@@ -549,6 +550,12 @@ class CachedProgram:
         self._donate = tuple(donate_argnums)
         self._static = tuple(static_argnums)
         self._local = None  # fallback jit when the cache is disabled
+        # the call site, "<cls>_<tag>" of ("prog", cls, tag, key): the
+        # XLA module reads jit_<cls>_<tag> in a device trace and the
+        # launch span srt.launch.<cls>.<tag> on the host's side
+        cls, tag = (base_key[1:3] if len(base_key) > 2 else ("", ""))
+        self._name = f"{cls}_{tag}" if cls else ""
+        self._launch = f"launch.{cls}.{tag}" if cls else "launch"
         try:
             _registry[base_key] = self   # last-registered wins; weak
         except TypeError:
@@ -565,7 +572,15 @@ class CachedProgram:
             kw["donate_argnums"] = self._donate
         if self._static:
             kw["static_argnums"] = self._static
-        return jax.jit(self._fn, **kw)
+        fn = self._fn
+        if self._name:
+            # builders are closures (`fn`, `_run`, bound methods): jit
+            # names the module after the function it is handed
+            def call(*args):
+                return fn(*args)
+            call.__name__ = call.__qualname__ = self._name
+            return jax.jit(call, **kw)
+        return jax.jit(fn, **kw)
 
     def _key_for(self, args: tuple):
         import jax
@@ -574,11 +589,17 @@ class CachedProgram:
                 jax.default_backend(), _active_conf_fp, sig)
 
     def __call__(self, *args):
+        launch = self._launch
         if not _enabled:
             if self._local is None:
                 self._local = self._jit()
-            return self._local(*args)
-        key = self._key_for(args)
+            with tracing.span(launch, "launch"):
+                return self._local(*args)
+        # the signature walks every leaf of `args` (a whole cached table
+        # for a single-dispatch stage): host time of the launch that is
+        # not the launch
+        with tracing.span("cache.key", "launch"):
+            key = self._key_for(args)
         miss = False
         with _lock:
             prog = _cache.get(key)
@@ -601,21 +622,22 @@ class CachedProgram:
                     _release(_cache.popitem(last=False)[1])
                     _stats["program_cache_evictions"] += 1
         if not miss:
-            return prog(*args)
+            with tracing.span(launch, "launch"):
+                return prog(*args)
         # sync miss: the actual trace+compile happens on this first
         # call (outside the lock). The timed wall includes one
         # dispatch — the event log documents it as such. The spec is
         # recorded BEFORE the call: donated arg buffers are dead after.
         _note_observed(key, self._base_key, self._donate, self._static,
                        args)
-        from ..profiler import tracing
         t0 = _time.perf_counter()
         # sync compile ON the dispatch path: exactly the latency the
         # critical path must blame on 'compile' (thread-local context —
         # the query thread runs under tracing.use)
         with tracing.span("xla.compile", "compile",
                           op=self._base_key[0] if self._base_key
-                          else None):
+                          else None), \
+                tracing.span(launch, "launch"):
             out = prog(*args)
         _note_compile(self._base_key,
                       (_time.perf_counter() - t0) * 1e3, "sync")

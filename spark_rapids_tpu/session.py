@@ -882,6 +882,7 @@ class DataFrame:
         # query_id, so admission accounting, the event log, and the
         # resource-ledger per-query balance check all see it whole.
         from .config import SERVICE_MAX_QUERY_RETRIES
+        from .profiler import tracing
         from .runtime.faults import is_transient_error, note_recovery
         max_retries = int(conf.get(SERVICE_MAX_QUERY_RETRIES))
         attempt = 0
@@ -891,8 +892,12 @@ class DataFrame:
             timeout = None
             if deadline is not None:
                 timeout = max(deadline - _time.monotonic(), 1e-3)
-            handle = mgr.open_query(plan=self._plan, conf=conf,
-                                    action=action, timeout=timeout)
+            # admission is the session's own time: a span of its own
+            # beside the query's, on the profiler's clock (the waited
+            # part is the back-dated `admission.queue` dict span)
+            with tracing.span("admit", "queue"):
+                handle = mgr.open_query(plan=self._plan, conf=conf,
+                                        action=action, timeout=timeout)
             if deadline is None:
                 deadline = handle.token.deadline
             try:
@@ -900,7 +905,8 @@ class DataFrame:
                                            cache_token=token,
                                            retry_of=retry_of)
             except BaseException as e:
-                mgr.close_query(handle, error=e)
+                with tracing.span("admit", "queue"):
+                    mgr.close_query(handle, error=e)
                 if (attempt < max_retries and is_transient_error(e)
                         and (deadline is None
                              or _time.monotonic() < deadline)):
@@ -911,7 +917,8 @@ class DataFrame:
                                 "error": repr(e)}
                     continue
                 raise
-            mgr.close_query(handle, result=out)
+            with tracing.span("admit", "queue"):
+                mgr.close_query(handle, result=out)
             return out
 
     def submit(self, action: str = "collect", pool=None, timeout=None):
@@ -983,21 +990,25 @@ class DataFrame:
             tracing.start_trace(handle.query_id, conf)
             if handle is not None else None)
         rsp = None
-        if tc is not None and not nested:
+        if not nested:
             # open the root span BEFORE planning so the plan span and
             # the back-dated admission wait parent under it — the trace
-            # is one rooted tree, not a forest of top-level siblings
+            # is one rooted tree, not a forest of top-level siblings.
+            # Off-trace it is the profiler's annotation alone.
             # tpulint: allow[span-leak] query root span: ended by tracing.finish() in this action's finally (idempotent close-out)
             rsp = tracing.open_span("query", "query", tc, action=action)
-            tc = tracing.TraceContext(tc.trace_id, rsp.span_id, True)
-            if handle is not None:
-                tracing.record_queue_span(tc, handle.queue_wait_ms,
-                                          pool=handle.pool)
-        if tc is not None:
+            if tc is not None:
+                tc = tracing.TraceContext(tc.trace_id, rsp.span_id, True)
+                if handle is not None:
+                    tracing.record_queue_span(tc, handle.queue_wait_ms,
+                                              pool=handle.pool)
+        try:
             with tracing.span("plan", "plan", tc):
                 root, ctx = self._execute(conf)
-        else:
-            root, ctx = self._execute(conf)
+        except BaseException:
+            if rsp is not None:
+                rsp.end()
+            raise
         ctx.trace = tc
         if handle is not None:
             ctx.cancel = handle.token
@@ -1019,7 +1030,8 @@ class DataFrame:
             try:
                 # under use(): the pool snapshots the submitter's trace
                 # context so background compiles land in this trace
-                with tracing.use(ctx.trace):
+                with tracing.use(ctx.trace), \
+                        tracing.span("prewarm", "compile"):
                     prewarm_tree(root, _cpool,
                                  handle.query_id if handle else None)
             except Exception:

@@ -14,13 +14,16 @@ pipelining; see docs/observability.md.
 from __future__ import annotations
 
 import time
-from contextlib import contextmanager
-
-from ..runtime import racedep
+from contextlib import contextmanager, nullcontext
 
 ESSENTIAL = 0
 MODERATE = 1
 DEBUG = 2
+
+# after the levels: the profiler package, which both of these import,
+# imports the levels from here
+from ..profiler import tracing  # noqa: E402
+from ..runtime import racedep  # noqa: E402
 
 __all__ = ["MetricSet", "ESSENTIAL", "MODERATE", "DEBUG"]
 
@@ -41,12 +44,16 @@ def _stream_barrier():
 class MetricSet:
     """Thread-safe: partitions update operator metrics concurrently."""
 
-    def __init__(self, sync: bool = False):
+    def __init__(self, sync: bool = False, op_id: str = ""):
         from ..runtime import lockdep
         self._values = {}
         self._levels = {}
         self._lock = lockdep.lock("MetricSet._lock")
         self._sync = sync
+        # a timed region is a span on the profiler's clock, named by
+        # the operator's class: "FusedStageExec@7f.." -> "FusedStageExec."
+        self._op_id = op_id
+        self._span_prefix = op_id.partition("@")[0] + "." if op_id else ""
 
     def add(self, name: str, amount, level: int = MODERATE):
         with self._lock:
@@ -69,7 +76,10 @@ class MetricSet:
     def timer(self, name: str, level: int = MODERATE):
         t0 = time.perf_counter()
         try:
-            yield
+            with (tracing.span(self._span_prefix + name, "op",
+                               op=self._op_id)
+                  if self._span_prefix else nullcontext()):
+                yield
         finally:
             if self._sync:
                 _stream_barrier()

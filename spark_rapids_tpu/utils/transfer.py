@@ -8,20 +8,23 @@ from __future__ import annotations
 
 import jax
 
+from ..profiler import tracing
+
 __all__ = ["fetch", "fetch_int"]
 
 
 def fetch(tree):
-    leaves = jax.tree_util.tree_leaves(tree)
-    for leaf in leaves:
-        copy_async = getattr(leaf, "copy_to_host_async", None)
-        if copy_async is not None:
-            try:
-                copy_async()
-            except Exception:
-                pass
-    # tpulint: allow[host-sync] the single blessed D2H chokepoint
-    return jax.device_get(tree)
+    with tracing.span("fetch", "d2h"):
+        leaves = jax.tree_util.tree_leaves(tree)
+        for leaf in leaves:
+            copy_async = getattr(leaf, "copy_to_host_async", None)
+            if copy_async is not None:
+                try:
+                    copy_async()
+                except Exception:
+                    pass
+        # tpulint: allow[host-sync] the single blessed D2H chokepoint
+        return jax.device_get(tree)
 
 
 def fetch_int(x) -> int:

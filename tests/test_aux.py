@@ -35,9 +35,3 @@ def test_metrics_surface(session):
     ms = q.last_metrics()
     assert any("FilterExec" in k for k in ms)
     assert any("numOutputBatches" in v for v in ms.values())
-
-
-def test_trace_annotation_smoke():
-    from spark_rapids_tpu.utils.trace import range_annotation
-    with range_annotation("test-range"):
-        pass

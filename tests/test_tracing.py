@@ -425,3 +425,154 @@ def test_event_log_writer_survives_dead_volume(tmp_path):
     w.close()                            # idempotent, still quiet
     evs = read_event_log(p)
     assert [e["event"] for e in evs] == ["alpha"]
+
+
+# ----------------------------------------------------------------------
+# the second sink: every span is a TraceAnnotation on the profiler's
+# clock ("srt." + name), whether or not a dict record is kept
+# ----------------------------------------------------------------------
+def _profiled(tmp_path, body):
+    """Run `body()` under the jax profiler and return its `srt.` host
+    events as [(thread line index, name, start_ns, end_ns, stats)]."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path / "prof"),
+                             profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (xplane,) = glob.glob(str(
+        tmp_path / "prof" / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    out = []
+    for plane in ProfileData.from_file(xplane).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for i, line in enumerate(plane.lines):
+            out.extend(
+                (i, e.name, e.start_ns, e.start_ns + e.duration_ns,
+                 dict(e.stats))
+                for e in line.events
+                if e.name.startswith(tracing.PREFIX))
+    return out
+
+
+def _inside(events, inner, outer):
+    """Every `inner` event lies within some `outer` event of its own
+    thread (annotations nest per thread)."""
+    outers = [e for e in events if e[1] == outer]
+    inners = [e for e in events if e[1] == inner]
+    return bool(inners) and all(
+        any(o[0] == i[0] and o[2] <= i[2] and i[3] <= o[3]
+            for o in outers) for i in inners)
+
+
+@pytest.mark.parametrize("trace_on", [True, False])
+def test_spans_land_on_the_profilers_clock(tmp_path, trace_on):
+    at = pa.table({
+        "k": pa.array(np.arange(20_000) % 50, type=pa.int64()),
+        "v": pa.array(np.random.default_rng(6).normal(0, 1, 20_000))})
+    path = str(tmp_path / "t.parquet")
+    pq.write_table(at, path, compression="snappy", row_group_size=5_000)
+    s = _session(tmp_path, **{
+        "spark.rapids.tpu.sql.trace.enabled": trace_on,
+        # set explicitly, so the device decode runs on the CPU backend
+        "spark.rapids.tpu.sql.format.parquet.deviceDecode.enabled": True})
+    df = s.create_dataframe(at)
+
+    def queries():
+        (df.filter(col("v") > 0.0).group_by("k")
+         .agg(F.sum(col("v")).alias("sv")).to_arrow())
+        (s.read.parquet(path).filter(col("v") > 0.0)
+         .select((col("v") * 2).alias("w")).to_arrow())
+
+    queries()                           # compile outside the profile
+    events = _profiled(tmp_path, queries)
+    names = {e[1] for e in events}
+    assert {"srt.query", "srt.admit", "srt.plan", "srt.prewarm",
+            "srt.collect", "srt.export", "srt.fetch",
+            "srt.HashAggregateExec.opTime",
+            "srt.launch.HashAggregateExec.hash_update",
+            "srt.launch.FusedStageExec.run", "srt.cache.key",
+            "srt.exchange.map",
+            "srt.io.read", "srt.io.upload", "srt.io.decode"} <= names
+    # nested as docs/observability.md says, thread by thread
+    assert _inside(events, "srt.plan", "srt.query")
+    assert _inside(events, "srt.prewarm", "srt.query")
+    assert _inside(events, "srt.collect", "srt.query")
+    assert _inside(events, "srt.export", "srt.collect")
+    assert _inside(events, "srt.launch.FusedStageExec.run",
+                   "srt.FusedStageExec.opTime")
+    assert _inside(events, "srt.launch.HashAggregateExec.hash_update",
+                   "srt.HashAggregateExec.opTime")
+    assert _inside(events, "srt.io.upload", "srt.io.decode")
+    assert _inside(events, "srt.io.read", "srt.ParquetScanExec.scanTime")
+    # admission is beside the query, not inside it
+    assert not _inside(events, "srt.admit", "srt.query")
+    # on worker threads too: the exchange map pool and the scan's
+    # prefetch thread launch, fetch and read off the query's thread
+    main = {e[0] for e in events if e[1] == "srt.query"}
+    assert len(main) == 1
+    for name in ("srt.launch.HashAggregateExec.hash_update", "srt.fetch",
+                 "srt.io.read"):
+        assert {e[0] for e in events if e[1] == name} - main, name
+    # stats, not names, carry the ids
+    op = next(e for e in events if e[1] == "srt.FusedStageExec.opTime")
+    assert op[4]["op"].startswith("FusedStageExec@")
+    root = next(e for e in events if e[1] == "srt.query")
+    assert ("query" in root[4]) == trace_on
+    # the dict sink keeps to the conf, and to its own vocabulary
+    recorded = [e for e in read_event_log(s.last_event_log)
+                if e["event"] == "trace_span"]
+    assert bool(recorded) == trace_on
+    assert not {sp["kind"] for sp in recorded} & tracing.PROFILER_ONLY
+
+
+@pytest.mark.parametrize("kind", sorted(tracing.PROFILER_ONLY))
+def test_profiler_only_kinds_never_reach_drain_trace(kind):
+    tc = tracing.TraceContext("unit-only-" + kind, None, True)
+    with tracing.use(tc):
+        with tracing.span("x", kind, tc, op="FooExec@1"):
+            # no dict record, so no new parent either
+            assert tracing.current() is tc
+        sp = tracing.open_span("y", kind, tc)
+        sp.set("a", 1)
+        sp.end()
+        sp.end()                        # idempotent, like Span.end
+        with tracing.span("z", "plan", tc):
+            pass
+    assert [s["name"] for s in tracing.drain_trace(tc.trace_id)] == ["z"]
+
+
+def test_cached_program_is_named_by_call_site():
+    import jax.numpy as jnp
+    from spark_rapids_tpu.runtime.program_cache import cached_program
+
+    def fn(x):                          # what builders are called today
+        return x + 1
+
+    prog = cached_program(fn, cls="FooExec", tag="bar", key=("unit",))
+    jitted = prog._jit()
+    assert jitted.__name__ == "FooExec_bar"
+    assert "jit_FooExec_bar" in jitted.lower(jnp.ones(4)).as_text()
+    assert prog._launch == "launch.FooExec.bar"
+    np.testing.assert_array_equal(np.asarray(prog(jnp.ones(4))),
+                                  np.full(4, 2.0, np.float32))
+
+
+def test_metric_timer_is_a_span_only_for_an_operators_set(tmp_path):
+    from spark_rapids_tpu.utils.metrics import MetricSet
+
+    def timed():
+        with MetricSet(op_id="FooExec@1f").timer("opTime"):
+            pass
+        with MetricSet().timer("opTime"):   # no operator: no span
+            pass
+
+    events = _profiled(tmp_path, timed)
+    assert [(e[1], e[4]) for e in events] == [
+        ("srt.FooExec.opTime", {"op": "FooExec@1f"})]
