@@ -182,7 +182,7 @@ class UngroupedAggExec(TpuExec):
         from .nodes import CachedScanExec
         if not isinstance(self._base, CachedScanExec):
             return None
-        batches = self._base.batches
+        batches = self._base.whole_input(ctx)
         if not batches or len(batches) > 64:  # unroll bound
             return None
         if not hasattr(self, "_whole_jit"):
@@ -959,7 +959,7 @@ class HashAggregateExec(TpuExec):
                 or getattr(self, "_whole_disabled", False)
                 or not isinstance(self._base, CachedScanExec)):
             return None
-        batches = self._base.batches
+        batches = self._base.whole_input(ctx)
         if not batches or len(batches) > 64:
             return None
         if not hasattr(self, "_whole_nchunks"):

@@ -707,14 +707,36 @@ def _prefetched(it: Iterator, depth: int, wait_metrics=None):
 class CachedScanExec(TpuExec):
     """Serves HBM-resident batches directly (GpuInMemoryTableScan analog)."""
 
-    def __init__(self, batches, schema: Schema):
+    def __init__(self, batches, schema: Schema, columns_cached=None):
         super().__init__([], schema)
         self.batches = list(batches)
+        # width of the cached table; `schema` is what this plan reads of
+        # it (plan/optimizer.py prunes the scan to zero-copy views)
+        self.columns_cached = (len(schema.fields) if columns_cached is None
+                               else columns_cached)
+
+    def describe(self):
+        return (f"CachedScanExec[{len(self.schema.fields)} of "
+                f"{self.columns_cached} columns, {len(self.batches)} "
+                f"batches]")
 
     def num_partitions(self, ctx):
         return max(1, len(self.batches))
 
+    def _report_width(self, ctx):
+        m = ctx.metrics_for(self._op_id)
+        m.set("columnsRead", len(self.schema.fields))
+        m.set("columnsCached", self.columns_cached)
+
+    def whole_input(self, ctx):
+        """Every batch at once, for a parent that hands them all to ONE
+        program as arguments (the aggregates' whole-input paths)."""
+        self._report_width(ctx)
+        return self.batches
+
     def execute_partition(self, ctx, pid):
+        if pid == 0:
+            self._report_width(ctx)
         if pid < len(self.batches):
             yield self.batches[pid]
 

@@ -63,10 +63,21 @@ class CachedScan(LogicalPlan):
     (reference: ParquetCachedBatchSerializer.scala): df.cache() pins the
     columnar data on device so repeated queries skip host decode + H2D."""
 
-    def __init__(self, batches, schema):
+    def __init__(self, batches, schema, columns_cached=None, table_id=None):
         self.batches = list(batches)
         self._schema = schema
         self.children = []
+        # width of the table df.cache() pinned; a pruned view reads fewer
+        self.columns_cached = (len(schema.fields) if columns_cached is None
+                               else columns_cached)
+        # what a view shares with its leaf: the rows are the same table's
+        # whichever columns a plan reads (plan/stats.py:_card_fp)
+        self.table_id = object() if table_id is None else table_id
+        # ordinals -> pruned view; a `_*_cache` name, so expr_fp skips it
+        self._pruned_cache = {}
+        # plan/stats.py's NDV memo, one for the table: the join reorder
+        # asks the leaf (before pruning), the planner and AQE the view
+        self._ndv_cache = {}
 
     @property
     def schema(self):
@@ -74,6 +85,45 @@ class CachedScan(LogicalPlan):
 
     def describe(self):
         return f"CachedScan[{len(self.batches)} device batches] {self._schema}"
+
+    def pruned(self, required) -> "CachedScan":
+        """This scan over the columns named in `required` only, in the
+        cached table's order; `self` where that is every column. The
+        batches of a view are new DeviceBatch / Table shells around the
+        SAME Column objects: no device operation, no copy, no HBM. One
+        view per column set is kept on the leaf, so every fresh query
+        tree over one cached DataFrame plans the identical node (and
+        plan/stats.py's per-node memos survive re-planning); it pins
+        nothing the leaf does not and dies with it."""
+        fields = self._schema.fields
+        keep = tuple(i for i, f in enumerate(fields) if f.name in required)
+        if not keep:
+            # count(*): one fixed-width column, the narrowest, so that
+            # the batches still have a length
+            fixed = [i for i, f in enumerate(fields)
+                     if not (f.dtype.is_variable_width or f.dtype.is_nested)]
+            if not fixed:
+                return self
+            keep = (min(fixed,
+                        key=lambda i: fields[i].dtype.np_dtype.itemsize),)
+        if len(keep) == len(fields):
+            return self
+        view = self._pruned_cache.get(keep)
+        if view is None:
+            from ..columnar.table import Table
+            from ..exec.batch import DeviceBatch
+            names = [fields[i].name for i in keep]
+            # row_mask and capacity pass through: DeviceBatch's defaults
+            # would launch an eager arange per batch
+            view = CachedScan(
+                [DeviceBatch(Table(names, [b.table.columns[i] for i in keep]),
+                             b.num_rows, b.row_mask, b.capacity)
+                 for b in self.batches],
+                Schema([fields[i] for i in keep]),
+                self.columns_cached, self.table_id)
+            view._ndv_cache = self._ndv_cache
+            view = self._pruned_cache.setdefault(keep, view)
+        return view
 
 
 class ParquetScan(LogicalPlan):

@@ -58,7 +58,9 @@ def prune(plan: L.LogicalPlan,
                 return L.InMemoryScan(plan.arrow.select(names))
         return plan
     if isinstance(plan, L.CachedScan):
-        return plan  # already device-resident; pruning would copy
+        # views of the resident columns (nothing is copied), memoised on
+        # the leaf: a fresh tree over the same cache plans the same node
+        return plan if required is None else plan.pruned(required)
     if isinstance(plan, L.ParquetScan):
         if required is not None:
             names = [f.name for f in plan.schema.fields

@@ -89,7 +89,8 @@ def _scan(meta: PlanMeta, conv, conf) -> TpuExec:
 @_rule(L.CachedScan)
 def _cached(meta, conv, conf):
     from ..exec.nodes import CachedScanExec
-    return CachedScanExec(meta.node.batches, meta.node.schema)
+    return CachedScanExec(meta.node.batches, meta.node.schema,
+                          meta.node.columns_cached)
 
 
 @_rule(L.ParquetScan)

@@ -185,6 +185,11 @@ def _card_fp(node: L.LogicalPlan):
         # the data version — not on the projected column subset
         return ("S", "parquet", tuple(node.paths),
                 expr_fp(node.filters), expr_fp(node.snapshot))
+    if isinstance(node, L.CachedScan):
+        # rows are the cached table's, whichever of its columns the plan
+        # reads: a pruned view (post-prune harvest) keys like its leaf
+        # (pre-prune lookup)
+        return ("S", "cached", id(node.table_id))
     if isinstance(node, L.Expand):
         return ("X", "Expand", len(node.include_masks),
                 logical_fp(node.children[0]))

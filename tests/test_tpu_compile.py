@@ -60,7 +60,9 @@ def _at_rows(tree, cap0, rows, sharding):
 
 def _planned(session, qn, kind):
     """q`qn` over small HBM-cached tables, planned by the engine: the
-    first exec node of class `kind` and one cached lineitem batch."""
+    first exec node of class `kind` and one batch of the cached scan at
+    the foot of its first-child chain, as that plan scans it (the views
+    of the columns the query reads, plan/optimizer.py:prune)."""
     from spark_rapids_tpu.plan.planner import Planner
     from spark_rapids_tpu.workloads import tpch
     dfs = {"lineitem": tpch.gen_lineitem(0.001, 0, True),
@@ -72,7 +74,10 @@ def _planned(session, qn, kind):
     while stack:
         node = stack.pop()
         if type(node).__name__ == kind:
-            return node, dfs["lineitem"]._plan.batches[0]
+            scan = node
+            while scan.children:
+                scan = scan.children[0]
+            return node, scan.batches[0]
         stack.extend(node.children)
     raise AssertionError(f"no {kind} in q{qn}'s plan")
 
