@@ -8,7 +8,7 @@ JSON line of set-up facts (not measurements); the LAST line is
 {"ok": ..., "device": {...}} and `ok` means "passed on a chip".
 
   python chip_smoke.py              one chip, SF1 (what the driver runs)
-  python chip_smoke.py --chips 4    the sharded path vs mesh.devices=0, only
+  python chip_smoke.py --chips 4    q3 over tables sharded on four chips, only
   JAX_PLATFORMS=cpu python chip_smoke.py --rehearse --sf 0.05   sandbox
 """
 import argparse
@@ -160,43 +160,34 @@ def one_chip(args, tables):
 
 
 def four_chips(args, tables):
-    """The sharded path (mesh.devices=4, fused SPMD stages) against the same
-    shapes with mesh.devices=0, and nothing else."""
+    """What the cell tpch_sf1_mesh4.q3 runs, and nothing else: q3 over tables
+    that cache() row-shards over four chips (mesh.devices=4), the exchanges as
+    fused SPMD stages and the joins in lockstep, against the oracle."""
     import spark_rapids_tpu as st
-    from spark_rapids_tpu.exec.spmd_stage import SpmdStageExec
-    from spark_rapids_tpu.workloads import spmd_bench as sb
-    held, orig = set(), SpmdStageExec._gather_global
+    from spark_rapids_tpu.workloads import tpch, tpch_oracle
+    want = tpch_oracle.q3({k: tables[k].select(c).to_pandas()
+                           for k, c in COLS.items()})
+    s = st.TpuSession(dict(CONF, **{P + "mesh.devices": 4,
+                                    P + "mesh.spmdStage.maxBytes": 4 << 30}))
+    t0 = time.perf_counter()
+    dfs = {k: s.create_dataframe(v).cache() for k, v in tables.items()}
+    held = {k: len(df.cached_devices()) for k, df in dfs.items()}
+    say(phase="load_mesh4", secs=time.perf_counter() - t0, devices=held)
+    need(set(held.values()) == {4}, f"tables not on every chip: {held}")
+    ran = []
 
-    def spy(self, pieces, sharding, devices):
-        arr = orig(self, pieces, sharding, devices)
-        held.update(sh.device for sh in arr.addressable_shards)
-        return arr
-    SpmdStageExec._gather_global = spy
-
-    def build(s, q):
-        d = {k: s.create_dataframe(v) for k, v in tables.items()}
-        return (sb._q6_shape(d["lineitem"]) if q == "q6" else
-                sb._q3_shape(d["customer"], d["orders"], d["lineitem"]))
-    for q in ("q6", "q3"):
-        t0 = time.perf_counter()
-        host = sb._canon(build(st.TpuSession(
-            dict(CONF, **{P + "mesh.devices": 0})), q).to_arrow())
-        t1 = time.perf_counter()
-        held.clear()
-        df = build(st.TpuSession(dict(CONF, **{
-            P + "mesh.devices": 4,
-            P + "mesh.spmdStage.maxBytes": 4 << 30})), q)
-        mesh = sb._canon(df.to_arrow())
-        t2 = time.perf_counter()
-        stages = sb._metric_sum(df, "spmdStages")
-        need(mesh.equals(host), f"{q}: mesh.devices=4 != mesh.devices=0")
-        need(stages > 0, f"{q}: no fused SPMD stage ran")
-        need(sb._metric_sum(df, "spmdDegraded") == 0, f"{q}: spmdDegraded")
-        need(len(held) == 4, f"{q}: shards on {sorted(map(str, held))}")
-        check_on_device(df, q)
-        say(phase=f"{q}_mesh4", rows=host.num_rows, mesh0_secs=t1 - t0,
-            mesh4_secs=t2 - t1, spmd_stages=stages, shard_devices=len(held),
-            collective_bytes=sb._metric_sum(df, "collectiveBytes"))
+    def build():
+        ran.append(tpch.queries()[3](dfs))
+        return ran[-1]
+    phase("q3_mesh4", build, want, ordered=True)
+    counts = {k: sum(int(m.get(k, 0)) for df in ran
+                     for m in df.last_metrics().values())
+              for k in ("spmdStages", "spmdDegraded", "meshRounds",
+                        "collectiveBytes")}
+    say(phase="q3_mesh4_stages", executions=len(ran), **counts)
+    need(counts["spmdStages"] > 0, "no fused SPMD stage ran")
+    need(counts["spmdDegraded"] == 0 and counts["meshRounds"] == 0,
+         f"a stage fell back to the round-based exchange: {counts}")
 
 
 def main():

@@ -65,6 +65,35 @@ class Schema:
                        for f in schema])
 
 
+def _pad_like(trees, s):
+    """Tree `s` of host buffer trees with every array padded to the
+    longest of its position: zeros, and an offsets array with its last
+    entry (so that padding rows stay empty)."""
+    t = trees[s]
+    if isinstance(t, dict):
+        return {k: (_pad_offsets([u[k] for u in trees], s) if k == "offsets"
+                    else _pad_like([u[k] for u in trees], s)) for k in t}
+    if isinstance(t, (list, tuple)):
+        return [_pad_like([u[i] for u in trees], s) for i in range(len(t))]
+    if not isinstance(t, np.ndarray):
+        return t
+    cap = max(u.shape[0] for u in trees)
+    if t.shape[0] == cap:
+        return t
+    return np.concatenate([t, np.zeros((cap - t.shape[0],) + t.shape[1:],
+                                       t.dtype)])
+
+
+def _pad_offsets(arrs, s):
+    t = arrs[s]
+    if t is None:
+        return None
+    cap = max(u.shape[0] for u in arrs)
+    if t.shape[0] == cap:
+        return t
+    return np.concatenate([t, np.full(cap - t.shape[0], t[-1], t.dtype)])
+
+
 class Table:
     """Immutable batch of columns. All columns share `num_rows`."""
 
@@ -143,6 +172,40 @@ class Table:
         cols = [Column.build(dtype, n, d)
                 for (dtype, n, _), d in zip(host, dev)]
         return Table(names, cols)
+
+    @staticmethod
+    def sharded_from_arrow(at, devices, batch_rows: int):
+        """The rows of `at` divided evenly over `devices` (a quarter each
+        to within a row), each share in batches of `batch_rows`. Returns,
+        for every batch position, one (Table, rows, mask) a device: the
+        shares of one position are padded on the host to the same
+        capacities, so the same program signature fits every device, and
+        each share goes to its device in one device_put."""
+        import jax
+        n = len(devices)
+        names = list(at.schema.names)
+        base, extra = divmod(at.num_rows, n)
+        lens = [base + (s < extra) for s in range(n)]
+        starts = [sum(lens[:s]) for s in range(n)]
+        per = max(1, int(batch_rows))
+        out = []
+        for j in range(max(1, -(-lens[0] // per))):
+            rows = [max(0, min(per, lens[s] - j * per)) for s in range(n)]
+            host = [[Column.host_from_arrow(at.column(i).slice(
+                starts[s] + j * per, rows[s])) for i in range(len(names))]
+                for s in range(n)]
+            group = []
+            for s, dev in enumerate(devices):
+                bufs = [_pad_like([host[t][i][2] for t in range(n)], s)
+                        for i in range(len(names))]
+                cap = bufs[0]["validity"].shape[0] if bufs else 0
+                dbufs, mask = jax.device_put(
+                    (bufs, np.arange(cap) < rows[s]), dev)
+                cols = [Column.build(host[s][i][0], rows[s], d)
+                        for i, d in enumerate(dbufs)]
+                group.append((Table(names, cols), rows[s], mask))
+            out.append(group)
+        return out
 
     def to_arrow(self):
         """One device_get for every buffer of every column (per-transfer

@@ -666,14 +666,15 @@ class HashAggregateExec(TpuExec):
         st2 = [s[idx] for s in st]
         return (ks2, st2, inb, new_cap)
 
-    def _update_fn(self, nchunks):
+    def _update_fn(self, nchunks, allow_host_sort: bool = True):
         def fn(cvs, mask):
             cvs, mask = self._stages(cvs, mask)
             cap = mask.shape[0]
             ctx = EmitCtx(cvs, cap)
             key_cvs = [k.emit(ctx) for k in self.keys]
             perm, seg_ids, live, seg_live, key_out = \
-                self._sort_and_segment(key_cvs, mask, nchunks)
+                self._sort_and_segment(key_cvs, mask, nchunks,
+                                       allow_host_sort=allow_host_sort)
             states = []
             for a in self.aggs:
                 if a.child is not None:
@@ -993,6 +994,45 @@ class HashAggregateExec(TpuExec):
         m.add("numOutputRows", int(cnt))
         m.add("numOutputBatches", 1)
         return DeviceBatch(tbl, int(cnt), sl_c, sl_c.shape[0])
+
+    def execute_mesh(self, ctx: ExecContext, n: int):
+        """The partial aggregate of a mesh plan in lockstep: every
+        shard's batch through the sort-and-segment update as ONE program
+        over the mesh, each result emitted as a partial batch (the final
+        merge behind the exchange takes same-key rows from any number of
+        them). Fixed-width keys and traceable reducers only; anything
+        else keeps the partition path."""
+        if (self.mode != "partial" or self._has_string_keys()
+                or any("custom" in a.state_reducers for a in self.aggs)):
+            return None
+        self._resolve_fusion()
+        from .lockstep import mesh_batches
+        src = mesh_batches(ctx, self._base, n)
+        if src is None:
+            return None
+        from ..parallel.mesh_program import MeshProgram
+        nchunks = (0,) * len(self.keys)
+        update = self._update_fn(nchunks, allow_host_sort=False)
+        prog = MeshProgram(
+            lambda t: update(*t), n, cls="HashAggregateExec",
+            tag="meshupdate", key=self._fp + (self._stage_fp,))
+        m = ctx.metrics_for(self._op_id)
+
+        def run():
+            for mb in src:
+                ctx.check_cancel()
+                with m.timer("opTime"):
+                    outs = prog(mb.trees())
+                xla_stats.count_dispatch()
+                shards = []
+                for b, (ks, st, sl) in zip(mb.shards, outs):
+                    cvs = list(ks) + [CV(a, sl) for a in st]
+                    shards.append(DeviceBatch(
+                        make_table(self.schema, cvs, b.capacity),
+                        b.capacity, sl, b.capacity))
+                m.add("numOutputBatches", n)
+                yield type(mb)(shards)
+        return run()
 
     def execute_partition(self, ctx: ExecContext, pid: int):
         self._resolve_fusion()

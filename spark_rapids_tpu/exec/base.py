@@ -124,6 +124,15 @@ class TpuExec:
                           pid: int) -> Iterator[DeviceBatch]:
         raise NotImplementedError
 
+    def execute_mesh(self, ctx: ExecContext, n: int):
+        """Lockstep execution over an n-device mesh: an iterator of
+        MeshBatch (exec/batch.py), every partition's next batch at once
+        and each on its partition's device, so the consumer runs ONE
+        program over the mesh where it would run one a partition. None
+        where this operator has no lockstep form here; the caller then
+        pulls execute_partition. Decided before any work is done."""
+        return None
+
     def release(self):
         """Free long-lived resources held by this operator (spill
         handles parked for re-execution, cached device buffers).

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from ..columnar.table import Table
 from ..ops.kernel_utils import CV
 
-__all__ = ["DeviceBatch"]
+__all__ = ["DeviceBatch", "MeshBatch"]
 
 
 class DeviceBatch:
@@ -50,6 +50,20 @@ class DeviceBatch:
     def __repr__(self):
         return (f"DeviceBatch(rows<={self.num_rows}, cap={self.capacity}, "
                 f"cols={self.table.num_columns})")
+
+
+class MeshBatch:
+    """One batch a shard of a mesh, in shard order, all of the same
+    capacities and each on its shard's device: what a lockstep operator
+    (TpuExec.execute_mesh) hands the next, so that one program over the
+    mesh (parallel/mesh_program.py) takes the n of them at once."""
+
+    def __init__(self, shards: List[DeviceBatch]):
+        self.shards = list(shards)
+
+    def trees(self):
+        """The argument a mesh program takes: (cvs, mask) a shard."""
+        return [(b.cvs(), b.row_mask) for b in self.shards]
 
 
 def maybe_compact(batch: DeviceBatch, schema, factor: int = 4):

@@ -102,6 +102,10 @@ class HashJoinExec(TpuExec):
         self._lstages = None
         self._n_fused = 0
         self._pre_jit = None
+        # the lockstep result, kept only for parents that pull partitions
+        from ..runtime import lockdep
+        self._mesh_lock = lockdep.rlock("HashJoinExec._mesh_lock")
+        self._mesh_out = None
 
     def num_partitions(self, ctx):
         if self.per_partition:
@@ -324,8 +328,12 @@ class HashJoinExec(TpuExec):
 
     def _probe_fn(self, cap_b, cap_s):
         """Per-stream-batch count phase against the sorted build keys."""
+        # not `self`: a cached program pinning its builder must not pin
+        # the operator tree (and what it parked) with it
+        u64, ldtype = self._single_key_u64, self.lkeys[0].dtype
+
         def fn(sorted_ukey, n_valid, skcv, smask):
-            ukey_s = self._single_key_u64(skcv, self.lkeys[0].dtype)
+            ukey_s = u64(skcv, ldtype)
             joinable = smask & skcv.validity
             lo = jnp.searchsorted(sorted_ukey, ukey_s, side="left")
             hi = jnp.searchsorted(sorted_ukey, ukey_s, side="right")
@@ -479,11 +487,211 @@ class HashJoinExec(TpuExec):
         from ..ops.gather import gather_cols
         return gather_cols(cvs, idx, inb)
 
+    # ---- the co-partitioned join in lockstep over a mesh ---------------
+    # Both children are hash-partitioned over n devices (exchanges below
+    # us) and hand over every shard's batch at once (execute_mesh). Each
+    # step then runs as ONE program over the mesh instead of one a
+    # device: sorted build + probe, ONE fetch of every shard's match
+    # statistics, and the expansion at one output capacity (the largest
+    # shard's, bucketed). A single fixed-width key and no residual
+    # condition, which is the fast path above; anything else keeps the
+    # per-partition path.
+    _MESH_HOWS = ("inner", "left", "left_semi", "left_anti")
+
+    def _mesh_sides(self, ctx, n):
+        """(stream, build) lockstep sources, or None."""
+        if (not self.per_partition or self.how not in self._MESH_HOWS
+                or self.condition is not None
+                or not self._fast_path_ok()
+                or any(f.dtype.is_nested for f in self.schema.fields)):
+            return None
+        from .lockstep import mesh_batches
+        build = mesh_batches(ctx, self.children[1], n)
+        if build is None:
+            return None
+        stream = mesh_batches(ctx, self.children[0], n)
+        return None if stream is None else (stream, build)
+
+    def _mesh_memo(self, ctx):
+        """The lockstep result kept for parents that pull partitions;
+        None where the join has no lockstep form here. The first caller
+        computes it and the others wait for it; `_mesh_lock` guards only
+        the hand-over, never the children's own locks."""
+        import threading
+        with self._mesh_lock:
+            once = self._mesh_out
+            lead = once is None
+            if lead:
+                once = self._mesh_out = {"done": threading.Event(),
+                                         "out": None, "err": None}
+        if lead:
+            try:
+                n = self.children[0].num_partitions(ctx)
+                it = self.execute_mesh(ctx, n) if n > 1 else None
+                once["out"] = None if it is None else list(it)
+            except BaseException as e:
+                once["err"] = e
+                with self._mesh_lock:
+                    self._mesh_out = None     # a retried action recomputes
+                raise
+            finally:
+                once["done"].set()
+        else:
+            while not once["done"].wait(0.05):
+                ctx.check_cancel()
+            if once["err"] is not None:
+                raise once["err"]
+        return once["out"]
+
+    def execute_mesh(self, ctx: ExecContext, n: int):
+        sides = self._mesh_sides(ctx, n)
+        if sides is None:
+            return None
+        stream, build = sides
+        builds = list(build)
+        if not builds:
+            return None     # an empty build side: the partition path
+        return self._mesh_join(ctx, n, stream, builds)
+
+    def _mesh_join(self, ctx, n, stream, builds):
+        from ..ops.gather import repeat_measures, take
+        from ..parallel.mesh_program import MeshProgram
+        from ..utils.transfer import fetch
+        m = ctx.metrics_for(self._op_id)
+        left, right = self.children
+        rkey, lkey = self.rkeys[0], self.lkeys[0]
+        rdtypes = [f.dtype for f in right.schema.fields]
+        how = self.how
+        u64 = self._single_key_u64
+        key = self._fp
+
+        def build_fn(tree):
+            if len(tree) == 1:
+                bcvs, bmask = tree[0]
+            else:
+                bcvs = [concat_cvs([t[0][ci] for t in tree], d)
+                        for ci, d in enumerate(rdtypes)]
+                bmask = concat_masks([t[1] for t in tree])
+            kcv = rkey.emit(EmitCtx(bcvs, bmask.shape[0]))
+            valid = bmask & kcv.validity
+            pinned = jnp.where(valid, u64(kcv, rkey.dtype),
+                               jnp.uint64(0xFFFFFFFFFFFFFFFF))
+            perm = sk.lexsort([jnp.logical_not(valid).astype(jnp.uint8),
+                               pinned], allow_host=False)
+            return (list(bcvs), pinned[perm], perm.astype(jnp.int32),
+                    jnp.sum(valid.astype(jnp.int32)))
+
+        with m.timer("buildTime"):
+            built = MeshProgram(build_fn, n, cls="HashJoinExec",
+                                 tag="meshbuild", key=key)(
+                [tuple(shard)
+                 for shard in zip(*(mb.trees() for mb in builds))])
+            xla_stats.count_dispatch()
+        cap_b = built[0][2].shape[0]
+        bvar = [ci for ci, d in enumerate(rdtypes) if d.is_variable_width]
+        svar = [ci for ci, f in enumerate(left.schema.fields)
+                if f.dtype.is_variable_width]
+        left_nulls = how == "left"
+
+        probe_body = self._probe_fn(cap_b, 0)
+
+        def probe_fn(tree):
+            (bcvs, sorted_ukey, perm, n_valid), (scvs, smask) = tree
+            skcv = lkey.emit(EmitCtx(scvs, smask.shape[0]))
+            cnt, offsets, total, lo, _ = probe_body(
+                sorted_ukey, n_valid[0], skcv, smask)
+            eff = jnp.where(smask & (cnt == 0), 1, cnt) if left_nulls \
+                else cnt
+            stats = [jnp.sum(eff), jnp.max(cnt)]
+            # var-width bytes the expansion will hold: a stream row's
+            # length times its copies; a build row's through the prefix
+            # sum of lengths in sorted-build order over [lo, lo + cnt)
+            for ci in svar:
+                stats.extend(repeat_measures(scvs[ci], eff))
+            for ci in bvar:
+                off = bcvs[ci].offsets
+                lens = jnp.where(bcvs[ci].validity,
+                                 (off[1:] - off[:-1]), 0)[perm]
+                pre = jnp.concatenate([jnp.zeros(1, jnp.int64),
+                                       jnp.cumsum(lens.astype(jnp.int64))])
+                stats.append(jnp.sum(pre[lo + cnt] - pre[lo]))
+            keep = smask & ((cnt == 0) if how == "left_anti" else (cnt > 0))
+            return cnt, offsets, lo, jnp.stack(
+                [jnp.asarray(v, jnp.int64) for v in stats]), keep
+
+        probe = MeshProgram(probe_fn, n, cls="HashJoinExec",
+                             tag="meshprobe", key=key + (cap_b,))
+        for mb in stream:
+            ctx.check_cancel()
+            streamed = mb.trees()
+            with m.timer("opTime"):
+                probed = probe(list(zip(built, streamed)))
+                xla_stats.count_dispatch()
+            if how in ("left_semi", "left_anti"):
+                # a mask update on the stream batch: no fetch, no copy
+                yield type(mb)([
+                    DeviceBatch(b.table, b.num_rows, p[4], b.capacity)
+                    for b, p in zip(mb.shards, probed)])
+                continue
+            with m.timer("opTime"):
+                st = [[int(v) for v in row]
+                      for row in fetch([p[3] for p in probed])]
+                rows = [row[0] for row in st]
+                if max(rows) == 0:
+                    continue
+                out_cap = bucket_capacity(max(rows))
+                caps = tuple(bucket_capacity(max(max(row[2 + j]
+                                                     for row in st), 1))
+                             for j in range(len(svar) + len(bvar)))
+
+                # built here and not in the closure: a bound method
+                # there would pin the operator tree in the program cache
+                expand_body = self._expand_fn(out_cap, cap_b, left_nulls)
+
+                def expand_fn(tree, out_cap=out_cap, caps=caps,
+                              expand_body=expand_body):
+                    (bcvs, _, perm, _), (scvs, smask), (cnt, offs, lo, _,
+                                                        _) = tree
+                    lg, rg, lvalid, rvalid, total = expand_body(
+                        cnt, offs, lo, perm, smask)
+                    it = iter(caps)
+                    out = [take(cv, lg, lvalid,
+                                iter((next(it),)) if ci in svar else None)
+                           for ci, cv in enumerate(scvs)]
+                    out += [take(cv, rg, rvalid,
+                                 iter((next(it),)) if ci in bvar else None)
+                            for ci, cv in enumerate(bcvs)]
+                    return out, jnp.arange(out_cap) < total
+
+                outs = MeshProgram(
+                    expand_fn, n, cls="HashJoinExec", tag="meshexpand",
+                    key=key + (out_cap, cap_b, left_nulls, caps))(
+                    list(zip(built, streamed, probed)))
+                xla_stats.count_dispatch()
+            m.add("numOutputRows", sum(rows))
+            m.add("numOutputBatches", n)
+            yield type(mb)([
+                DeviceBatch(make_table(self.schema, cvs, rows[s]), rows[s],
+                            mask, out_cap)
+                for s, (cvs, mask) in enumerate(outs)])
+
+    def release(self):
+        with self._mesh_lock:
+            self._mesh_out = None
+        super().release()
+
     # ------------------------------------------------------------------
     def execute_partition(self, ctx: ExecContext, pid: int):
         if self.how == "cross":
             yield from self._execute_cross(ctx)
             return
+        if self.per_partition:
+            out = self._mesh_memo(ctx)
+            if out is not None:
+                for mb in out:
+                    if mb.shards[pid].num_rows:
+                        yield mb.shards[pid]
+                return
         m = ctx.metrics_for(self._op_id)
         right = self.children[1]
         stream_batches = self._stream_batches(ctx, pid)

@@ -501,16 +501,22 @@ class MeshExchangeExec(TpuExec):
         this, every mesh query leaks device-budget accounting, host
         memory, and spill files for the process lifetime)."""
         with self._lock:
-            if self._out is not None:
-                for pile in self._out:
-                    for h in pile:
-                        h.close()
-                self._out = None
+            self._drop()
         super().release()
 
+    def _drop(self):
+        if self._out is not None:
+            for pile in self._out:
+                for h in pile:
+                    h.close()
+            self._out = None
+
     def __del__(self):
+        # unreachable, so there is no one to lock out; and a finalizer
+        # runs inside whatever lock region the collector interrupts, so
+        # it takes no lock of its own (children finalize themselves)
         try:
-            self.release()
+            self._drop()
         except Exception:
             pass
 
@@ -526,12 +532,8 @@ def _local_shards(arr, n: int):
         # host-side views of the global buffer
         shard_len = arr.shape[0] // n
         return [arr[s * shard_len:(s + 1) * shard_len] for s in range(n)]
-    shard_len = arr.shape[0] // n
-    loc = [None] * n
-    for sh in shards:
-        start = sh.index[0].start or 0
-        loc[start // shard_len] = sh.data
-    return loc
+    from ..parallel.mesh_program import local_pieces
+    return local_pieces(arr, n)
 
 
 def _flatten_cvs(cvs: Sequence[CV]):

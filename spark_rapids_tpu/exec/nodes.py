@@ -22,7 +22,7 @@ from ..ops.kernel_utils import CV
 from ..profiler import tracing, xla_stats
 from ..runtime import faults
 from .base import ExecContext, TpuExec
-from .batch import DeviceBatch
+from .batch import DeviceBatch, MeshBatch
 
 __all__ = ["InMemoryScanExec", "CachedScanExec", "ParquetScanExec",
            "ProjectExec", "FilterExec",
@@ -707,21 +707,34 @@ def _prefetched(it: Iterator, depth: int, wait_metrics=None):
 class CachedScanExec(TpuExec):
     """Serves HBM-resident batches directly (GpuInMemoryTableScan analog)."""
 
-    def __init__(self, batches, schema: Schema, columns_cached=None):
+    def __init__(self, batches, schema: Schema, columns_cached=None,
+                 n_shards=0):
         super().__init__([], schema)
         self.batches = list(batches)
         # width of the cached table; `schema` is what this plan reads of
         # it (plan/optimizer.py prunes the scan to zero-copy views)
         self.columns_cached = (len(schema.fields) if columns_cached is None
                                else columns_cached)
+        # rows divided over this many devices (a mesh session's cache()):
+        # one partition a device, `batches` shard after shard
+        self.n_shards = n_shards
 
     def describe(self):
+        sharded = (f" on {self.n_shards} devices" if self.n_shards else "")
         return (f"CachedScanExec[{len(self.schema.fields)} of "
                 f"{self.columns_cached} columns, {len(self.batches)} "
-                f"batches]")
+                f"batches{sharded}]")
 
     def num_partitions(self, ctx):
-        return max(1, len(self.batches))
+        return self.n_shards or max(1, len(self.batches))
+
+    def execute_mesh(self, ctx, n):
+        if n != self.n_shards:
+            return None
+        self._report_width(ctx)
+        per = len(self.batches) // n
+        return (MeshBatch([self.batches[s * per + j] for s in range(n)])
+                for j in range(per))
 
     def _report_width(self, ctx):
         m = ctx.metrics_for(self._op_id)
@@ -732,12 +745,17 @@ class CachedScanExec(TpuExec):
         """Every batch at once, for a parent that hands them all to ONE
         program as arguments (the aggregates' whole-input paths)."""
         self._report_width(ctx)
-        return self.batches
+        # a sharded table's batches lie on several devices: no one
+        # program takes them all
+        return None if self.n_shards else self.batches
 
     def execute_partition(self, ctx, pid):
         if pid == 0:
             self._report_width(ctx)
-        if pid < len(self.batches):
+        if self.n_shards:
+            per = len(self.batches) // self.n_shards
+            yield from self.batches[pid * per:(pid + 1) * per]
+        elif pid < len(self.batches):
             yield self.batches[pid]
 
 

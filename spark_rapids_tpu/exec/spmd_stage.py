@@ -68,9 +68,8 @@ from ..ops.gather import compact
 from ..ops.hash import partition_ids
 from ..ops.kernel_utils import CV
 from .base import ExecContext, TpuExec
-from .batch import DeviceBatch
-from .mesh_exchange import (MeshExchangeExec, _empty_cv, _flatten_cvs,
-                            _local_shards, _pad_round_cv, _unflatten_cvs)
+from .batch import DeviceBatch, MeshBatch
+from .mesh_exchange import MeshExchangeExec, _empty_cv, _pad_round_cv
 from .nodes import make_table
 
 __all__ = ["SpmdStageExec", "StagedSourceExec"]
@@ -132,7 +131,10 @@ class SpmdStageExec(TpuExec):
         self._lock = lockdep.rlock("SpmdStageExec._lock")
         self._staged: Optional[List[Tuple]] = None  # [(handle, nbytes)]
         self._staged_bytes = 0
+        self._groups: Optional[List[List[int]]] = None  # lockstep staging
+        self._shard_of: List[int] = []
         self._out: Optional[List[List]] = None      # per shard: handles
+        self._out_rows: List[int] = []              # per shard: live rows
         self._degraded = False
         self._fallback_src: Optional[StagedSourceExec] = None
         self._n_active = exchange.n
@@ -162,41 +164,121 @@ class SpmdStageExec(TpuExec):
                            background=True)
             except Exception:
                 return []       # prewarm is best-effort by contract
-        return list(self._jit_cache.values())
+        return [p._prog for p in self._jit_cache.values()]
 
     # -- staging -------------------------------------------------------
     def _ensure_staged(self, ctx: ExecContext):
-        """Drain the map side ONCE into spillable handles (priority 10,
-        original drain order preserved) with exact per-batch byte
-        accounting — the byte stats the AQE re-shard/demote rules and
-        the working-set budget check read."""
+        """Run the map side ONCE into spillable handles (priority 10)
+        with exact per-batch byte accounting — the byte stats the AQE
+        re-shard/demote rules and the working-set budget check read.
+        Where the map side has a lockstep form (exec/lockstep.py) every
+        shard's batches come at once, from one program over the mesh a
+        batch position; otherwise its partitions are drained
+        concurrently on the exchange map pool. Either way a batch is
+        staged on the device it was produced on, and `_groups` says
+        which shard that is."""
         with self._lock:
             if self._staged is not None:
                 return
             from ..memory.retry import retry_no_split
             from ..memory.spill import spill_store
+            from ..profiler import tracing
+            from .lockstep import mesh_batches
             store = spill_store(ctx.conf)
             m = ctx.metrics_for(self._op_id)
             child = self.children[0]
+            n = self.exchange.n
             staged: List[Tuple] = []
-            total = 0
+            groups: List[List[int]] = []    # lockstep: n indices a group
+            shard_of: List[int] = []        # else: the shard of each
+
+            def park(b):
+                staged.append((retry_no_split(
+                    lambda b=b: store.add_batch(b, priority=10)),
+                    int(b.nbytes)))
+                return len(staged) - 1
+
             try:
-                with m.timer("partitionTime"):
-                    for cpid in range(child.num_partitions(ctx)):
-                        for b in child.execute_partition(ctx, cpid):
+                with tracing.span("spmd.stage", "stage", ctx), \
+                        m.timer("partitionTime"):
+                    lock = mesh_batches(ctx, child, n,
+                                        self.exchange.axis_name)
+                    if lock is not None:
+                        for mb in lock:
                             ctx.check_cancel()
-                            nbytes = int(b.nbytes)
-                            total += nbytes
-                            staged.append((retry_no_split(
-                                lambda b=b: store.add_batch(
-                                    b, priority=10)), nbytes))
+                            groups.append([park(b) for b in mb.shards])
+                    else:
+                        nparts = child.num_partitions(ctx)
+                        for cpid, bs in enumerate(
+                                self._drain_partitions(ctx, child, nparts,
+                                                       m)):
+                            for b in bs:
+                                park(b)
+                                # co-partitioned input stays where it
+                                # is; anything else is dealt round-robin
+                                shard_of.append(cpid if nparts == n else
+                                                (len(staged) - 1) % n)
             except BaseException:
                 for h, _ in staged:
                     h.close()
                 raise
             self._staged = staged
-            self._staged_bytes = total
-            m.set("spmdStagedBytes", total)
+            self._groups = groups if lock is not None else None
+            self._shard_of = shard_of
+            self._staged_bytes = sum(nb for _, nb in staged)
+            m.set("spmdStagedBytes", self._staged_bytes)
+
+    @staticmethod
+    def _drain_partitions(ctx, child, nparts, m):
+        """Every partition of the map side, drained to a list: on the
+        exchange map pool (exec/exchange_pool.py) where it gives more
+        than one thread, so partitions on different chips run at the
+        same time; device admission a batch goes through the rider."""
+        from .exchange_pool import PermitRider, resolve_map_threads
+        threads = resolve_map_threads(ctx, nparts)
+        if threads <= 1 or nparts <= 1:
+            out = []
+            for cpid in range(nparts):
+                bs = []
+                for b in child.execute_partition(ctx, cpid):
+                    ctx.check_cancel()
+                    bs.append(b)
+                out.append(bs)
+            return out
+        import concurrent.futures as cf
+        from ..profiler import tracing
+        from .nodes import _session_semaphore
+        rider = PermitRider(_session_semaphore(ctx),
+                            priority=getattr(ctx, "sem_priority", 0),
+                            token=ctx.cancel)
+        tc = tracing.current()
+
+        def drain(cpid):
+            bs = []
+            with tracing.use(tc), tracing.span("spmd.map", "pool_task",
+                                               cpid=cpid):
+                it = child.execute_partition(ctx, cpid)
+                while True:
+                    ctx.check_cancel()
+                    with rider.step():
+                        b = next(it, None)
+                    if b is None:
+                        return bs
+                    bs.append(b)
+
+        with cf.ThreadPoolExecutor(
+                threads, thread_name_prefix="tpu-spmd-map") as pool:
+            futs = [pool.submit(drain, cpid) for cpid in range(nparts)]
+            try:
+                # tpulint: allow[wait-under-lock] map-pool join under the memoizing _lock, as in ShuffleExchangeExec: the rider guarantees worker progress and readers must wait for the stage anyway
+                out = [f.result() for f in futs]
+            except BaseException:
+                for f in futs:
+                    f.cancel()
+                raise
+        if rider.waited_secs > 0:
+            m.add("mapPoolWaitMs", round(rider.waited_secs * 1e3, 3))
+        return out
 
     def stage_bytes(self, ctx: ExecContext) -> int:
         """Materialize the map stage and return its staged device bytes
@@ -231,7 +313,10 @@ class SpmdStageExec(TpuExec):
         with self._lock:
             if self._reshard_decision is not None:
                 return self._reshard_decision
-            if (not conf.get(SPMD_RESHARD_ENABLED)
+            # a bare exchange feeds a co-partitioned join: its sibling
+            # stage would have to draw the same n_active, and each
+            # decides from its own bytes — so neither is re-sharded
+            if (not conf.get(SPMD_RESHARD_ENABLED) or self.kind == "exchange"
                     or self._out is not None or self._degraded):
                 return None
             self._ensure_staged(ctx)
@@ -266,7 +351,7 @@ class SpmdStageExec(TpuExec):
                 self._degrade(ctx, "budget")
                 return
             if not self._staged:
-                self._out = [[] for _ in range(self.exchange.n)]
+                self._out, self._out_rows = [], [0] * self.exchange.n
                 return
             try:
                 from ..profiler import tracing
@@ -284,10 +369,7 @@ class SpmdStageExec(TpuExec):
                 if faults.is_transient_error(e):
                     # recovery contract: the stage falls back to the
                     # round-based exchange over the SAME staged handles
-                    from ..profiler import tracing
-                    with tracing.span("spmd.degrade", "degrade", ctx,
-                                      reason=type(e).__name__):
-                        self._degrade(ctx, type(e).__name__)
+                    self._degrade(ctx, type(e).__name__)
                     faults.note_recovery("degradations")
                     return
                 raise
@@ -297,11 +379,13 @@ class SpmdStageExec(TpuExec):
         The exchange re-drains them in original order, so its output is
         byte-identical to a direct round-based run; the map side does
         NOT re-execute."""
-        m = ctx.metrics_for(self._op_id)
-        m.add("spmdDegraded", 1)
-        self._fallback_src = self.staged_source()
-        self.exchange.children = [self._fallback_src]
-        self._degraded = True
+        from ..profiler import tracing
+        with tracing.span("spmd.degrade", "degrade", ctx, reason=reason):
+            m = ctx.metrics_for(self._op_id)
+            m.add("spmdDegraded", 1)
+            self._fallback_src = self.staged_source()
+            self.exchange.children = [self._fallback_src]
+            self._degraded = True
 
     def _fallback_node(self) -> TpuExec:
         if self.kind == "agg":
@@ -315,67 +399,89 @@ class SpmdStageExec(TpuExec):
         if self._degraded:
             yield from self._fallback_node().execute_partition(ctx, pid)
             return
-        for h in self._out[pid]:
-            yield h.materialize()
+        if self._out_rows[pid]:
+            yield self._out[pid].materialize()
+
+    def execute_mesh(self, ctx: ExecContext, n: int):
+        """The stage's output as it left the program: one batch a shard
+        at one capacity, each on its shard's device. None once degraded
+        (the round-based exchange serves partitions only)."""
+        if n != self.exchange.n:
+            return None
+        self._ensure_executed(ctx)
+        if self._degraded:
+            return None
+        if not self._out:
+            return iter(())
+        return iter([MeshBatch([h.materialize() for h in self._out])])
 
     # -- the fused program ---------------------------------------------
-    def _gather_global(self, pieces, sharding, devices):
-        """Per-shard pieces -> one global array, each piece device_put
-        to its shard (no single-device staging; compression stays on
-        the round-based path — one-shot stages move raw)."""
-        shape = ((len(pieces) * pieces[0].shape[0],)
-                 + tuple(pieces[0].shape[1:]))
-        arrs = [jax.device_put(p, d) for p, d in zip(pieces, devices)]
-        return jax.make_array_from_single_device_arrays(
-            shape, sharding, arrs)
+    def _string_keys(self) -> List[int]:
+        from ..columnar import dtypes as dt
+        return [ki for ki, k in enumerate(self.consumer.keys)
+                if isinstance(k.dtype, (dt.StringType, dt.BinaryType))]
 
-    def _agg_nchunks(self, batches) -> Tuple[int, ...]:
+    def _agg_nchunks(self, groups) -> Tuple[int, ...]:
         """Static string-chunk counts for the consumer's keys, measured
         over the staged wire batches (per-row string LENGTH is exchange-
-        invariant, so pre-exchange maxima bound the merge's chunks).
-        All measurements batch into ONE device fetch (the same
+        invariant, so pre-exchange maxima bound the merge's chunks): one
+        program over the mesh and ONE device fetch (the same
         live-rows-only rule as HashAggregateExec._nchunks_for)."""
-        from ..columnar import dtypes as dt
         from ..ops import sortkeys as sk
+        from ..parallel.mesh_program import MeshProgram
         from ..utils.transfer import fetch
-        keys = self.consumer.keys
-        maxlens = []        # (key index, device max-len scalar)
-        for b in batches:
-            kcvs = list(b.cvs())[:len(keys)]
-            for ki, (kcv, kexpr) in enumerate(zip(kcvs, keys)):
-                if not isinstance(kexpr.dtype,
-                                  (dt.StringType, dt.BinaryType)):
-                    continue
-                lens = kcv.offsets[1:] - kcv.offsets[:-1]
-                lens = jnp.where(b.row_mask & kcv.validity, lens, 0)
-                if lens.shape[0]:
-                    maxlens.append((ki, jnp.max(lens)))
+        str_keys = self._string_keys()
         # string keys floor at the 1-byte chunk count even when every
         # staged value is null/empty (matches _nchunks_for)
-        ncs = [sk.nchunks_for_len(1)
-               if isinstance(k.dtype, (dt.StringType, dt.BinaryType))
-               else 0 for k in keys]
-        if maxlens:
-            # tpulint: allow[sync-under-lock] one batched max-length fetch while building the memoized stage program; readers block on _lock until _out is set regardless
-            fetched = fetch([v for _, v in maxlens])
-            for (ki, _), v in zip(maxlens, fetched):
-                ncs[ki] = max(ncs[ki],
-                              sk.nchunks_for_len(max(int(v), 1)))
-        return tuple(ncs)
+        ncs = [0] * len(self.consumer.keys)
+        for ki in str_keys:
+            ncs[ki] = sk.nchunks_for_len(1)
+        if not str_keys or not groups:
+            return tuple(ncs)
 
-    def _program(self, has_offsets, out_has, nchunks):
-        """Build (or fetch) THE one compiled program for this stage:
-        partition ids + all_to_all + consumer, inside one shard_map.
-        Keyed on the mesh topology first — collective lowering bakes in
-        replica groups and ICI routing, so programs must never cross
-        topologies (mesh-program-key lint rule)."""
-        from jax.sharding import PartitionSpec as P
-        from ..parallel.collectives import exchange_cvs
-        from ..parallel.mesh import mesh_topology_key
-        from ..runtime.program_cache import cached_program, exprs_fp
+        def maxlens(tree):
+            out = []
+            for ki in str_keys:
+                mx = jnp.int32(0)
+                for cvs, mask in tree:
+                    kcv = cvs[ki]
+                    lens = kcv.offsets[1:] - kcv.offsets[:-1]
+                    mx = jnp.maximum(mx, jnp.max(
+                        jnp.where(mask & kcv.validity, lens, 0)))
+                out.append(mx)
+            return jnp.stack(out)
 
         ex = self.exchange
-        mesh = ex._get_mesh()
+        prog = MeshProgram(maxlens, ex.n, ex.axis_name,
+                            cls="SpmdStageExec", tag="keylens",
+                            key=(tuple(str_keys),))
+        # tpulint: allow[sync-under-lock] one batched max-length fetch while building the memoized stage program; readers block on _lock until _out is set regardless
+        got = fetch(prog(self._group_trees(groups)))
+        for j, ki in enumerate(str_keys):
+            ncs[ki] = max(ncs[ki], sk.nchunks_for_len(
+                max(max(int(v[j]) for v in got), 1)))
+        return tuple(ncs)
+
+    @staticmethod
+    def _group_trees(groups):
+        """The argument of a program over the staged batches: a shard's
+        tree is the tuple of its (cvs, mask), one a group."""
+        n = len(groups[0])
+        return [tuple((g[s].cvs(), g[s].row_mask) for g in groups)
+                for s in range(n)]
+
+    def _program(self, has_offsets, nchunks):
+        """Build (or fetch) THE one compiled program for this stage:
+        concatenate the shard's staged batches, partition ids +
+        all_to_all + consumer, inside one shard_map. Keyed on the mesh
+        topology first (MeshProgram) — collective lowering bakes in
+        replica groups and ICI routing, so programs must never cross
+        topologies (mesh-program-key lint rule)."""
+        from ..parallel.collectives import exchange_cvs
+        from ..parallel.mesh_program import MeshProgram
+        from ..runtime.program_cache import exprs_fp
+
+        ex = self.exchange
         n = ex.n
         axis = ex.axis_name
         n_active = self._n_active
@@ -383,10 +489,10 @@ class SpmdStageExec(TpuExec):
         # cached entry pinning the builder must not pin staged output
         ex_keys = ex.keys
         ex_key_dtypes = [k.dtype for k in ex_keys]
+        wire_dtypes = [f.dtype for f in ex.schema.fields]
         kind = self.kind
         consumer = self.consumer
         chain_fns = [nd.fusable_stage() for nd in reversed(self.chain)]
-        n_out_flat = sum(3 if ho else 2 for ho in out_has)
 
         if kind == "agg":
             ckey = consumer._fp + (nchunks,)
@@ -395,13 +501,28 @@ class SpmdStageExec(TpuExec):
         else:
             ckey = ()
 
-        def shard_fn(flat, mask):
-            cvs = _unflatten_cvs(flat, has_offsets)
+        def shard_fn(tree):
+            if len(tree) == 1:
+                cvs, mask = tree[0]
+            else:
+                cvs = [concat_cvs([t[0][ci] for t in tree], d)
+                       for ci, d in enumerate(wire_dtypes)]
+                mask = concat_masks([t[1] for t in tree])
             cap = mask.shape[0]
             ectx = EmitCtx(cvs, cap)
             key_cvs = [k.emit(ectx) for k in ex_keys]
             pids = partition_ids(key_cvs, ex_key_dtypes, n_active)
             out_cvs, out_mask = exchange_cvs(cvs, mask, pids, n, axis)
+            got_rows = jnp.sum(out_mask.astype(jnp.int64))
+            got_bytes = jnp.int64(0)
+            for cv in out_cvs:      # live values and a validity byte a row
+                if cv.offsets is None:
+                    got_bytes += got_rows * (
+                        cv.data.dtype.itemsize * cv.data[0].size + 1)
+                else:
+                    lens = cv.offsets[1:] - cv.offsets[:-1]
+                    got_bytes += got_rows * 5 + jnp.sum(
+                        jnp.where(out_mask, lens, 0).astype(jnp.int64))
             if kind == "agg":
                 ocap = out_mask.shape[0]
                 kctx = EmitCtx(out_cvs, ocap)
@@ -419,31 +540,62 @@ class SpmdStageExec(TpuExec):
                 for fn in chain_fns:
                     out_cvs, out_mask = fn(out_cvs, out_mask)
                 outs, count = compact(out_cvs, out_mask)
-            stats = [count.astype(jnp.int64)]
+            # [rows received, rows out, bytes received, bytes of each
+            # var-width column out]
+            stats = [got_rows, count.astype(jnp.int64), got_bytes]
             for cv in outs:
                 if cv.offsets is not None:
                     stats.append(cv.offsets[count].astype(jnp.int64))
-            return _flatten_cvs(outs), jnp.stack(stats)
+            return list(outs), jnp.stack(stats)
 
-        def step(flat, mask):
-            return jax.shard_map(
-                shard_fn, mesh=mesh,
-                in_specs=(tuple(P(axis) for _ in flat), P(axis)),
-                out_specs=(tuple(P(axis) for _ in range(n_out_flat)),
-                           P(axis)),
-            )(tuple(flat), mask)
+        return MeshProgram(
+            shard_fn, n, axis, cls="SpmdStageExec", tag=kind,
+            key=(n_active, exprs_fp(ex_keys), kind) + ckey
+            + (tuple(has_offsets),))
 
-        return cached_program(
-            step, cls="SpmdStageExec", tag=kind,
-            key=(mesh_topology_key(n, axis), n_active, exprs_fp(ex_keys),
-                 kind) + ckey + (tuple(has_offsets),))
+    def _one_group(self, n):
+        """Staged batches that did not come in lockstep, as ONE group:
+        each shard's batches concatenated and padded to one capacity on
+        the shard's own device (power-of-two bucketed rows/bytes, like
+        the round path's bounce buffer)."""
+        wire = self.exchange.schema
+        has_offsets = [f.dtype.is_variable_width for f in wire.fields]
+        per_shard: List[List[DeviceBatch]] = [[] for _ in range(n)]
+        for (h, _), s in zip(self._staged, self._shard_of):
+            per_shard[s].append(h.materialize())
+        row_cap = bucket_capacity(max(1, max(
+            (sum(b.capacity for b in bs) for bs in per_shard if bs),
+            default=1)))
+        bcaps = []
+        for ci in range(len(wire.fields)):
+            if has_offsets[ci]:
+                mx = max((sum(b.cvs()[ci].data.shape[0] for b in bs)
+                          for bs in per_shard if bs), default=1)
+                bcaps.append(bucket_capacity(max(mx, 1)))
+            else:
+                bcaps.append(0)
+        group = []
+        for bs in per_shard:
+            if bs:
+                cvs = [concat_cvs([b.cvs()[ci] for b in bs], f.dtype)
+                       for ci, f in enumerate(wire.fields)]
+                msk = concat_masks([b.row_mask for b in bs])
+                cvs = [_pad_round_cv(cv, row_cap, bcaps[ci])
+                       for ci, cv in enumerate(cvs)]
+                msk = pad_mask(msk, row_cap)
+            else:
+                cvs = [_empty_cv(f.dtype, row_cap, bcaps[ci])
+                       for ci, f in enumerate(wire.fields)]
+                msk = jnp.zeros(row_cap, jnp.bool_)
+            group.append(DeviceBatch(make_table(wire, cvs, row_cap),
+                                     row_cap, msk, row_cap))
+        return [group]
 
     def _run_fused(self, ctx: ExecContext, m):
-        """Assemble per-shard send batches from the staged handles, run
-        THE stage program, slice each shard's live prefix, park the
-        results. Exactly one compiled program; zero intermediate
-        park/unpark."""
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        """Run THE stage program over the staged batches (each already
+        on its shard's device), cut every shard's result to one bucketed
+        capacity with a second small program over the mesh, park the
+        results. One stats fetch; zero intermediate park/unpark."""
         from ..memory.retry import retry_no_split
         from ..memory.spill import spill_store
         from ..utils.transfer import fetch
@@ -451,142 +603,94 @@ class SpmdStageExec(TpuExec):
         ex = self.exchange
         n = ex.n
         store = spill_store(ctx.conf)
-        mesh = ex._get_mesh()
-        sharding = NamedSharding(mesh, P(ex.axis_name))
-        devices = list(mesh.devices.reshape(-1))
-        wire = ex.schema
-        has_offsets = [f.dtype.is_variable_width for f in wire.fields]
+        has_offsets = [f.dtype.is_variable_width for f in ex.schema.fields]
         out_has = [f.dtype.is_variable_width for f in self.schema.fields]
 
         with m.timer("partitionTime"):
-            # deal staged batches round-robin onto shard slots; each
-            # slot concatenates to ONE padded send batch (power-of-two
-            # bucketed rows/bytes, like the round path's bounce buffer)
-            per_shard: List[List[DeviceBatch]] = [[] for _ in range(n)]
-            for i, (h, _) in enumerate(self._staged):
-                per_shard[i % n].append(h.materialize())
-            row_cap = bucket_capacity(max(1, max(
-                (sum(b.capacity for b in bs) for bs in per_shard if bs),
-                default=1)))
-            bcaps = []
-            for ci, f in enumerate(wire.fields):
-                if has_offsets[ci]:
-                    mx = max((sum(b.cvs()[ci].data.shape[0] for b in bs)
-                              for bs in per_shard if bs), default=1)
-                    bcaps.append(bucket_capacity(max(mx, 1)))
-                else:
-                    bcaps.append(0)
-            shard_cvs, shard_masks = [], []
-            for s in range(n):
-                bs = per_shard[s]
-                if bs:
-                    cvs = [concat_cvs([b.cvs()[ci] for b in bs], f.dtype)
-                           for ci, f in enumerate(wire.fields)]
-                    msk = concat_masks([b.row_mask for b in bs])
-                    cvs = [_pad_round_cv(cv, row_cap, bcaps[ci])
-                           for ci, cv in enumerate(cvs)]
-                    msk = pad_mask(msk, row_cap)
-                else:
-                    cvs = [_empty_cv(f.dtype, row_cap, bcaps[ci])
-                           for ci, f in enumerate(wire.fields)]
-                    msk = jnp.zeros(row_cap, jnp.bool_)
-                shard_cvs.append(cvs)
-                shard_masks.append(msk)
-            flat_global = []
-            for ci in range(len(wire.fields)):
-                parts = [shard_cvs[s][ci] for s in range(n)]
-                flat_global.append(self._gather_global(
-                    [p.data for p in parts], sharding, devices))
-                flat_global.append(self._gather_global(
-                    [p.validity for p in parts], sharding, devices))
-                if has_offsets[ci]:
-                    flat_global.append(self._gather_global(
-                        [p.offsets for p in parts], sharding, devices))
-            mask_global = self._gather_global(shard_masks, sharding,
-                                              devices)
-            m.add("collectiveBytes",
-                  sum(int(a.nbytes) for a in flat_global)
-                  + int(mask_global.nbytes))
+            if self._groups is not None:
+                groups = [[self._staged[i][0].materialize() for i in g]
+                          for g in self._groups]
+            else:
+                groups = self._one_group(n)
+            trees = self._group_trees(groups)
+            # what crosses the mesh: every staged buffer as it is sent
+            m.add("collectiveBytes", sum(
+                int(a.nbytes) for a in jax.tree_util.tree_leaves(trees)))
 
-        nchunks = (self._agg_nchunks([b for bs in per_shard for b in bs])
-                   if self.kind == "agg" else ())
+        nchunks = self._agg_nchunks(groups) if self.kind == "agg" else ()
         key = (tuple(has_offsets), nchunks, self._n_active)
         prog = self._jit_cache.get(key)
         if prog is None:
-            prog = self._program(has_offsets, out_has, nchunks)
+            prog = self._program(has_offsets, nchunks)
             self._jit_cache[key] = prog
 
         with m.timer("exchangeTime"):
-            out_flat, stats = prog(flat_global, mask_global)
-            n_var = sum(1 for ho in out_has if ho)
+            outs = prog(trees)
             # tpulint: allow[sync-under-lock] ONE stats fetch for the whole fused stage (the round path pays this per round); readers block on _lock until _out is set regardless
-            stats_h = fetch(stats).reshape(n, 1 + n_var)
+            fetched = fetch([st for _, st in outs])
+            stats_h = [[int(v) for v in st] for st in fetched]
 
-        out: List[List] = [[] for _ in range(n)]
-        # slice each shard's live prefix from its device-LOCAL piece:
-        # indexing the global sharded array would lower to an
-        # all-gather rendezvous, unsafe to interleave with any other
-        # in-flight collective (see _local_shards)
-        flat_loc = [_local_shards(a, n) for a in out_flat]
+        # one capacity for every shard's result, so that the next
+        # lockstep operator takes the n of them into one program
+        rows = [st[1] for st in stats_h]
+        vcap = outs[0][0][0].validity.shape[0]
+        new_cap = min(bucket_capacity(max(max(rows), 1)), vcap)
+        bcaps, si = [], 3
+        for ci, cv in enumerate(outs[0][0]):
+            if out_has[ci]:
+                bcaps.append(min(bucket_capacity(max(
+                    max(st[si] for st in stats_h), 1)), cv.data.shape[0]))
+                si += 1
+            else:
+                bcaps.append(0)
+
+        from .lockstep import cut_program
+        cutp = cut_program(n, ex.axis_name, new_cap, tuple(bcaps), 1)
+        out = []
         try:
-            for s in range(n):
-                nlive = int(stats_h[s, 0])
-                if nlive == 0:
-                    continue
-                cvs = []
-                fi = 0
-                si = 1
-                for ci in range(len(self.schema.fields)):
-                    vcap = out_flat[fi + 1].shape[0] // n
-                    new_cap = min(bucket_capacity(nlive), vcap)
-                    if out_has[ci]:
-                        dcap = out_flat[fi].shape[0] // n
-                        nbytes = int(stats_h[s, si])
-                        si += 1
-                        bcap_new = min(bucket_capacity(max(nbytes, 1)),
-                                       dcap)
-                        data = flat_loc[fi][s][:bcap_new]
-                        valid = flat_loc[fi + 1][s][:new_cap]
-                        offs = flat_loc[fi + 2][s][:new_cap + 1]
-                        cvs.append(CV(data, valid, offs))
-                        fi += 3
-                    else:
-                        data = flat_loc[fi][s][:new_cap]
-                        valid = flat_loc[fi + 1][s][:new_cap]
-                        cvs.append(CV(data, valid))
-                        fi += 2
-                tbl = make_table(self.schema, cvs, nlive)
-                batch = DeviceBatch(tbl, nlive, None, new_cap)
-                out[s].append(retry_no_split(
+            for s, (cvs, mask) in enumerate(cutp(outs)):
+                batch = DeviceBatch(make_table(self.schema, cvs, rows[s]),
+                                    rows[s], mask, new_cap)
+                out.append(retry_no_split(
                     lambda b=batch: store.add_batch(b, priority=5)))
-                m.add("numOutputRows", nlive)
         except BaseException:
-            for pile in out:
-                for h in pile:
-                    h.close()
+            for h in out:
+                h.close()
             raise
         self._out = out
+        self._out_rows = rows
         m.add("spmdStages", 1)
-        m.add("numOutputBatches", sum(len(p) for p in out))
+        m.add("numOutputRows", sum(rows))
+        m.add("numOutputBatches", sum(1 for r in rows if r))
+        # what each shard received (the skew of the hash partitioning)
+        for name, at in (("shardRowsReceived", 0), ("shardBytesReceived", 2)):
+            got = [st[at] for st in stats_h]
+            m.add(name + "Max", max(got))
+            m.add(name + "Min", min(got))
 
     # -- lifecycle -----------------------------------------------------
+    def _drop(self):
+        if self._out is not None:
+            for h in self._out:
+                h.close()
+            self._out = None
+        if self._staged is not None:
+            for h, _ in self._staged:
+                h.close()
+            self._staged = None
+
     def release(self):
         with self._lock:
-            if self._out is not None:
-                for pile in self._out:
-                    for h in pile:
-                        h.close()
-                self._out = None
-            if self._staged is not None:
-                for h, _ in self._staged:
-                    h.close()
-                self._staged = None
+            self._drop()
         # release the fused operators (reaches the shared map subtree
         # exactly once through whichever member sits on top)
         self._fallback_node().release()
 
     def __del__(self):
+        # unreachable, so there is no one to lock out; and a finalizer
+        # runs inside whatever lock region the collector interrupts, so
+        # it takes no lock of its own (members finalize themselves)
         try:
-            self.release()
+            self._drop()
         except Exception:
             pass

@@ -12,8 +12,10 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-__all__ = ["make_mesh", "P", "NamedSharding", "Mesh", "shard_rows",
-           "mesh_topology_key", "mesh_fingerprint"]
+__all__ = ["make_mesh", "get_mesh", "P", "NamedSharding", "Mesh",
+           "shard_rows", "mesh_topology_key", "mesh_fingerprint"]
+
+_meshes: dict = {}
 
 
 def mesh_topology_key(n_devices: int, axis_name: str = "data") -> tuple:
@@ -53,6 +55,17 @@ def make_mesh(n_devices: Optional[int] = None,
                 f"with JAX_PLATFORMS=cpu for virtual meshes")
         devs = devs[:n_devices]
     return Mesh(np.array(devs), (axis_name,))
+
+
+def get_mesh(n_devices: int, axis_name: str = "data") -> Mesh:
+    """The process's one Mesh over the first ``n_devices`` devices: the
+    cached table's placement, the stages and the lockstep programs of a
+    session all name the same devices in the same order."""
+    key = (int(n_devices), axis_name)
+    mesh = _meshes.get(key)
+    if mesh is None:
+        mesh = _meshes.setdefault(key, make_mesh(n_devices, axis_name))
+    return mesh
 
 
 def shard_rows(mesh: Mesh, arr, axis_name: str = "data"):

@@ -240,14 +240,17 @@ def _maybe_demote_mesh(join, ctx, conf, decisions, lore_alloc,
     if build_bytes > thr:
         return
     from ..exec.broadcast import BroadcastExchangeExec
-    src = build.staged_source(own=True)
+    from ..exec.lockstep import MeshGatherExec
+    # the staged batches and the stream's map side lie on their shards'
+    # devices; a broadcast join runs on one
+    src = MeshGatherExec(build.staged_source(own=True))
     bcast = BroadcastExchangeExec(src, src.schema)
     if not lore_alloc[0]:
         lore_alloc[0] = _max_lore_id(root)
     lore_alloc[0] += 1
     bcast.lore_id = lore_alloc[0]
     old_lores = [getattr(n, "lore_id", None) for n in (stream, build)]
-    join.children = [stream.children[0], bcast]
+    join.children = [MeshGatherExec(stream.children[0]), bcast]
     join.per_partition = False
     d = {"rule": "demote_broadcast_join", "mesh": True,
          "join_lore": getattr(join, "lore_id", None),

@@ -203,3 +203,50 @@ def fuse_spmd_stages(root: TpuExec, conf) -> Tuple[TpuExec, List[str]]:
         st.lore_id = next_id
         lines.append(st.describe())
     return new_root, lines
+
+
+def place_mesh_gathers(root: TpuExec, conf) -> TpuExec:
+    """In a mesh session a partition's batches live on its device (a
+    sharded cached table, the output of a mesh exchange or stage). An
+    operator that works partition by partition runs where its input is;
+    one that takes every partition into a single-device program (sort,
+    limit, a broadcast build, an ungrouped aggregate, a join that is not
+    co-partitioned) gets a `MeshGatherExec` over each such child, which
+    brings the batches to the default device. The root's collect exports
+    from wherever the batches are."""
+    from ..config import MESH_DEVICES
+    mesh_n = conf.get(MESH_DEVICES)
+    if not mesh_n or mesh_n <= 1:
+        return root
+    from ..exec.aggregate import HashAggregateExec
+    from ..exec.fused import FusedStageExec
+    from ..exec.join import HashJoinExec
+    from ..exec.lockstep import MeshGatherExec
+    from ..exec.mesh_exchange import MeshExchangeExec
+    from ..exec.nodes import CachedScanExec, FilterExec, ProjectExec
+    from ..exec.spmd_stage import SpmdStageExec
+
+    def partitionwise(n: TpuExec) -> bool:
+        return (isinstance(n, (FilterExec, ProjectExec, FusedStageExec))
+                or (isinstance(n, HashJoinExec) and n.per_partition)
+                or (isinstance(n, HashAggregateExec)
+                    and n.mode in ("partial", "final")))
+
+    def walk(n: TpuExec) -> bool:
+        """Whether `n`'s output may lie off the default device; wraps
+        the placed children of a node that needs them in one place."""
+        if isinstance(n, (SpmdStageExec, MeshExchangeExec)):
+            for c in n.children:
+                walk(c)         # they take their input from any device
+            return True
+        if isinstance(n, CachedScanExec):
+            return bool(n.n_shards)
+        placed = [walk(c) for c in n.children]
+        if partitionwise(n):
+            return any(placed)
+        n.children = [MeshGatherExec(c) if p else c
+                      for c, p in zip(n.children, placed)]
+        return False
+
+    walk(root)
+    return root

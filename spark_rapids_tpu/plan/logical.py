@@ -63,10 +63,15 @@ class CachedScan(LogicalPlan):
     (reference: ParquetCachedBatchSerializer.scala): df.cache() pins the
     columnar data on device so repeated queries skip host decode + H2D."""
 
-    def __init__(self, batches, schema, columns_cached=None, table_id=None):
+    def __init__(self, batches, schema, columns_cached=None, table_id=None,
+                 n_shards=0):
         self.batches = list(batches)
         self._schema = schema
         self.children = []
+        # a mesh session's cache() divides the rows over n_shards devices:
+        # `batches` is then shard after shard, each with the same number
+        # of batches at the same capacities; 0 is one device, as ever
+        self.n_shards = n_shards
         # width of the table df.cache() pinned; a pruned view reads fewer
         self.columns_cached = (len(schema.fields) if columns_cached is None
                                else columns_cached)
@@ -120,7 +125,7 @@ class CachedScan(LogicalPlan):
                              b.num_rows, b.row_mask, b.capacity)
                  for b in self.batches],
                 Schema([fields[i] for i in keep]),
-                self.columns_cached, self.table_id)
+                self.columns_cached, self.table_id, self.n_shards)
             view._ndv_cache = self._ndv_cache
             view = self._pruned_cache.setdefault(keep, view)
         return view

@@ -90,7 +90,7 @@ def _scan(meta: PlanMeta, conv, conf) -> TpuExec:
 def _cached(meta, conv, conf):
     from ..exec.nodes import CachedScanExec
     return CachedScanExec(meta.node.batches, meta.node.schema,
-                          meta.node.columns_cached)
+                          meta.node.columns_cached, meta.node.n_shards)
 
 
 @_rule(L.ParquetScan)
@@ -662,6 +662,8 @@ class Planner:
             root_exec, spmd_groups = fuse_spmd_stages(root_exec,
                                                       self.conf)
             report.fusion_groups = fusion_groups + spmd_groups
+            from .fusion import place_mesh_gathers
+            root_exec = place_mesh_gathers(root_exec, self.conf)
             # ride the physical root so the profiler wrapper can emit
             # the plan_audit event without re-walking
             root_exec.audit_report = report

@@ -279,10 +279,16 @@ class _WitnessLock:
     def acquire(self, blocking=True, timeout=-1):
         ok = self._inner.acquire(blocking, timeout)
         if ok:
-            w = _WITNESS
-            if w is not None:
-                w.acquired(self.name)
-            racedep.note_lock(self.name)
+            try:
+                w = _WITNESS
+                if w is not None:
+                    w.acquired(self.name)
+                racedep.note_lock(self.name)
+            except BaseException:
+                # a witness that raises (a lock-order cycle) must not
+                # leave the lock held: `with` never reaches __exit__
+                self._inner.release()
+                raise
         return ok
 
     def release(self):

@@ -767,11 +767,47 @@ class DataFrame:
     def cache(self) -> "DataFrame":
         """Materialize this DataFrame into HBM-resident device batches
         (GpuInMemoryTableScan analog); later queries skip decode + H2D."""
+        from .config import MESH_DEVICES
+        n = int(self._session.conf.get(MESH_DEVICES) or 0)
+        if n > 1:
+            return self._cache_sharded(n)
+
         def body(root, ctx):
             return list(root.execute_all(ctx))
         batches = self._run_action("cache", body)
         return DataFrame(self._session,
                          L.CachedScan(batches, self._plan.schema))
+
+    def _cache_sharded(self, n: int) -> "DataFrame":
+        """cache() of a mesh session: the rows divided evenly over the
+        mesh's n devices, one partition a device (a table that is not a
+        host table yet is brought to the host first)."""
+        from .columnar.table import Table
+        from .exec.batch import DeviceBatch
+        from .parallel.mesh import get_mesh
+        at = (self._plan.arrow if isinstance(self._plan, L.InMemoryScan)
+              else self.to_arrow())
+        devices = list(get_mesh(n).devices.reshape(-1))
+        groups = Table.sharded_from_arrow(
+            at, devices, self._session.conf.batch_size_rows)
+        batches = [DeviceBatch(t, rows, mask, mask.shape[0])
+                   for s in range(n) for (t, rows, mask) in
+                   (g[s] for g in groups)]
+        return DataFrame(self._session, L.CachedScan(
+            batches, self._plan.schema, n_shards=n))
+
+    def cached_devices(self) -> list:
+        """The devices that hold rows of this cached DataFrame, in mesh
+        order; [] where it is not the result of cache()."""
+        if not isinstance(self._plan, L.CachedScan):
+            return []
+        held = []
+        for b in self._plan.batches:
+            if b.num_rows:
+                for d in b.row_mask.devices():
+                    if d not in held:
+                        held.append(d)
+        return sorted(held, key=lambda d: d.id)
 
     def uncache(self) -> "DataFrame":
         """Release this DataFrame's cached physical plan (exec nodes,
