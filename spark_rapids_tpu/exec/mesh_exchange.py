@@ -13,13 +13,13 @@ per-shard input queues whose batches are registered as SPILLABLE handles,
 then exchanged in ROUNDS — each round every shard contributes at most one
 batch, padded to a fixed power-of-two row/byte capacity (the per-round
 "bounce buffer"), and ONE collective program (compiled once, reused every
-round) moves the rows. Received rows are compacted to a live prefix inside
-the program, sliced down to a bucketed capacity, and parked as spillable
-handles until the consumer pulls them. Peak device residency is therefore
-O(n_devices * round_capacity) for the in-flight round plus whatever the
-spill store lets accumulate — skew changes how many rounds a shard
-receives, not the padding (round-2's global-max padding multiplied memory
-by n_devices under skew).
+round) moves the rows. Received rows arrive as a live prefix (the exchange
+places the peers' runs end to end), are sliced down to a bucketed capacity,
+and parked as spillable handles until the consumer pulls them. Peak device
+residency is therefore O(n_devices * round_capacity) for the in-flight round
+plus whatever the spill store lets accumulate — skew changes how many rounds
+a shard receives, not the padding (round-2's global-max padding multiplied
+memory by n_devices under skew).
 
 Downstream operators see `n` output partitions (one per shard/device), each
 yielding a stream of batches holding exactly the rows whose keys hash to
@@ -39,7 +39,6 @@ from ..columnar.column import bucket_capacity
 from ..columnar.table import Schema
 from ..expr.expressions import EmitCtx, Expression
 from ..ops.concat import pad_cv, pad_mask
-from ..ops.gather import compact
 from ..ops.hash import partition_ids
 from ..ops.kernel_utils import CV
 from .base import ExecContext, TpuExec
@@ -84,9 +83,9 @@ class MeshExchangeExec(TpuExec):
         return self._mesh
 
     def _build_program(self, has_offsets):
-        """shard_map program: emit keys -> pids -> exchange -> compact.
+        """shard_map program: emit keys -> pids -> exchange.
 
-        Per shard, returns the received rows compacted to a live prefix,
+        Per shard, returns the received rows (a live prefix as they come),
         plus a stats vector [row_count, bytes_col0, bytes_col1, ...] so the
         host can slice buffers down without extra device syncs."""
         from jax.sharding import PartitionSpec as P
@@ -106,8 +105,8 @@ class MeshExchangeExec(TpuExec):
             ectx = EmitCtx(cvs, cap)
             key_cvs = [k.emit(ectx) for k in keys]
             pids = partition_ids(key_cvs, key_dtypes, n)
-            out_cvs, out_mask = exchange_cvs(cvs, mask, pids, n, axis)
-            out_cvs, count = compact(out_cvs, out_mask)
+            # the received rows are already a live prefix of `count`
+            out_cvs, _, count = exchange_cvs(cvs, mask, pids, n, axis)
             stats = [count.astype(jnp.int64)]
             for cv in out_cvs:
                 if cv.offsets is not None:

@@ -512,8 +512,9 @@ class SpmdStageExec(TpuExec):
             ectx = EmitCtx(cvs, cap)
             key_cvs = [k.emit(ectx) for k in ex_keys]
             pids = partition_ids(key_cvs, ex_key_dtypes, n_active)
-            out_cvs, out_mask = exchange_cvs(cvs, mask, pids, n, axis)
-            got_rows = jnp.sum(out_mask.astype(jnp.int64))
+            # received rows are a live prefix: out_mask is arange < count
+            out_cvs, out_mask, got = exchange_cvs(cvs, mask, pids, n, axis)
+            got_rows = got.astype(jnp.int64)
             got_bytes = jnp.int64(0)
             for cv in out_cvs:      # live values and a validity byte a row
                 if cv.offsets is None:
@@ -536,10 +537,12 @@ class SpmdStageExec(TpuExec):
                     allow_host_sort=False)
                 outs = consumer._finalize_fn(mk, mflat, mlive)
                 count = jnp.sum(mlive.astype(jnp.int32))
-            else:
+            elif kind == "chain":
                 for fn in chain_fns:
                     out_cvs, out_mask = fn(out_cvs, out_mask)
                 outs, count = compact(out_cvs, out_mask)
+            else:
+                outs, count = out_cvs, got
             # [rows received, rows out, bytes received, bytes of each
             # var-width column out]
             stats = [got_rows, count.astype(jnp.int64), got_bytes]
@@ -667,6 +670,10 @@ class SpmdStageExec(TpuExec):
             got = [st[at] for st in stats_h]
             m.add(name + "Max", max(got))
             m.add(name + "Min", min(got))
+        # and the row slots it was sent: every peer sends a bucket of the
+        # shard's whole capacity, so rows over slots is the wire's fill
+        m.add("shardSlotsReceived",
+              n * sum(g[0].row_mask.shape[0] for g in groups))
 
     # -- lifecycle -----------------------------------------------------
     def _drop(self):
