@@ -107,27 +107,6 @@ def phase(name, build, expected, ordered=False):
         peak_bytes_in_use=mem.get("peak_bytes_in_use"))
 
 
-def pallas_phase(lineitem):
-    """The one Pallas kernel, compiled by Mosaic (never interpret=True off
-    the CPU), against the jnp murmur3 it replaces, on an int32 key."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from spark_rapids_tpu.columnar import dtypes as dt
-    from spark_rapids_tpu.ops.hash import partition_ids
-    from spark_rapids_tpu.ops.kernel_utils import CV
-    from spark_rapids_tpu.ops.pallas_kernels import pallas_partition_ids_i32
-    n = min(lineitem.num_rows // 1024, 1024) * 1024
-    vals = jnp.asarray(lineitem.column("l_shipdate").to_numpy()[:n])
-    valid = jnp.arange(n) % 7 != 0
-    got = pallas_partition_ids_i32(
-        vals, valid, 16, interpret=jax.default_backend() == "cpu")
-    exp = partition_ids([CV(vals, valid)], [dt.INT32], 16)
-    need(np.array_equal(np.asarray(got), np.asarray(exp)),
-         "pallas_partition_ids_i32 != ops/hash.partition_ids")
-    say(phase="pallas_partition_ids_i32", rows=n)
-
-
 def one_chip(args, tables):
     import pyarrow.parquet as pq
     import spark_rapids_tpu as st
@@ -156,7 +135,6 @@ def one_chip(args, tables):
     finally:
         if os.path.exists(path):
             os.remove(path)
-    pallas_phase(tables["lineitem"])
 
 
 def four_chips(args, tables):

@@ -3,10 +3,10 @@
 A ground-up TPU redesign of the capabilities of NVIDIA's RAPIDS Accelerator
 for Apache Spark (the reference implementation surveyed in SURVEY.md):
 Arrow-layout columnar batches resident in TPU HBM as jax Arrays; expression
-and operator kernels compiled by XLA (with Pallas for the hot paths);
-sort-based segmented groupby/join/sort under a static-shape regime; a
-handle-based HBM->host->disk spill framework with split-and-retry
-out-of-core execution; and a partition-exchange shuffle with host-file and
+and operator kernels compiled by XLA; sort-based segmented
+groupby/join/sort under a static-shape regime; a handle-based
+HBM->host->disk spill framework with split-and-retry out-of-core
+execution; and a partition-exchange shuffle with host-file and
 ICI-collective transports.
 """
 import os as _os

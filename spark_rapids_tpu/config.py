@@ -46,16 +46,10 @@ def _conf(key, default, doc, typ, **kw):
 # ----------------------------------------------------------------------
 # Registry (grouped roughly like the reference's RapidsConf sections)
 # ----------------------------------------------------------------------
-SQL_ENABLED = _conf("sql.enabled", True,
-                    "Enable TPU acceleration of SQL operators.", bool)
 BATCH_SIZE_ROWS = _conf(
     "sql.batchSizeRows", 1 << 20,
     "Target rows per columnar batch read into HBM. Batches are padded to "
     "power-of-two capacities to bound XLA recompilation.", int)
-BATCH_SIZE_BYTES = _conf(
-    "sql.batchSizeBytes", 512 * 1024 * 1024,
-    "Soft cap on device bytes per batch (analog of "
-    "spark.rapids.sql.batchSizeBytes).", int)
 CONCURRENT_TASKS = _conf(
     "sql.concurrentTpuTasks", 2,
     "Max tasks concurrently admitted to the TPU (TpuSemaphore permits; "
@@ -82,10 +76,6 @@ HOST_SPILL_LIMIT = _conf(
 SPILL_DIR = _conf(
     "memory.spill.dir", "/tmp/srtpu-spill",
     "Directory for disk-tier spill files.", str)
-OOM_MAX_RETRIES = _conf(
-    "memory.oom.maxRetries", 8,
-    "Bounded retries after device OOM before giving up "
-    "(analog of DeviceMemoryEventHandler maxFailedOOMRetries).", int)
 SHUFFLE_PARTITIONS = _conf(
     "sql.shuffle.partitions", 8,
     "Default partition count for exchanges (spark.sql.shuffle.partitions).",
@@ -255,8 +245,7 @@ SHAPE_BUCKET_GROWTH = _conf(
     "2 is the historical next-power-of-two bucketing; 4 compiles "
     "~half as many distinct shapes per operator at a padding-waste "
     "bound of 1 - 1/growthFactor (measured waste surfaces in "
-    "columnar.column.shape_stats and the bench --compile-tail "
-    "report). String-key chunk counts canonicalize on the same grid "
+    "columnar.column.shape_stats). String-key chunk counts canonicalize on the same grid "
     "(ops/sortkeys.nchunks_for_len).", int)
 COMPILE_POOL_ENABLED = _conf(
     "sql.exec.compilePool.enabled", True,
@@ -669,9 +658,6 @@ AGG_MAX_MERGE_ROWS = _conf(
     "partial into hash buckets of disjoint keys and merges/finalizes "
     "each bucket separately — the out-of-core fallback "
     "(GpuAggregateExec.scala:863-894 repartition algorithm analog).", int)
-AGG_FORCE_MERGE_PASSES = _conf(
-    "sql.agg.forceSinglePassMerge", False,
-    "Testing: force aggregate merge in one concat pass.", bool, internal=True)
 JOIN_BUILD_BUDGET = _conf(
     "sql.join.buildSideBudgetBytes", 2 << 30,
     "When a join partition's build side exceeds this many bytes, both "

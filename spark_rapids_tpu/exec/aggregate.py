@@ -529,14 +529,13 @@ class HashAggregateExec(TpuExec):
                 f"aggs={self.agg_names}{fused}]")
 
     # -- sort/segment machinery (runs inside jit) ----------------------
-    def _sort_and_segment(self, key_cvs, mask, nchunks,
-                          allow_host_sort: bool = True):
+    def _sort_and_segment(self, key_cvs, mask, nchunks):
         cap = mask.shape[0]
         arrays = [jnp.logical_not(mask).astype(jnp.uint8)]  # dead rows last
         for kcv, kexpr, nc in zip(key_cvs, self.keys, nchunks):
             arrays.append(jnp.logical_not(kcv.validity).astype(jnp.uint8))
             arrays.extend(sk.order_keys(kcv, kexpr.dtype, nc))
-        perm = sk.lexsort(arrays, allow_host=allow_host_sort)
+        perm = sk.lexsort(arrays)
         sorted_arrays = [a[perm] for a in arrays]
         boundary = sk.group_boundaries(sorted_arrays)
         seg_ids = jnp.cumsum(boundary.astype(jnp.int32)) - 1
@@ -666,15 +665,14 @@ class HashAggregateExec(TpuExec):
         st2 = [s[idx] for s in st]
         return (ks2, st2, inb, new_cap)
 
-    def _update_fn(self, nchunks, allow_host_sort: bool = True):
+    def _update_fn(self, nchunks):
         def fn(cvs, mask):
             cvs, mask = self._stages(cvs, mask)
             cap = mask.shape[0]
             ctx = EmitCtx(cvs, cap)
             key_cvs = [k.emit(ctx) for k in self.keys]
             perm, seg_ids, live, seg_live, key_out = \
-                self._sort_and_segment(key_cvs, mask, nchunks,
-                                       allow_host_sort=allow_host_sort)
+                self._sort_and_segment(key_cvs, mask, nchunks)
             states = []
             for a in self.aggs:
                 if a.child is not None:
@@ -920,17 +918,13 @@ class HashAggregateExec(TpuExec):
             return outs, sl_c, count, overflow
         return run
 
-    def _merge_body(self, key_cvs, flat_states, mask, nchunks,
-                    allow_host_sort: bool = True):
+    def _merge_body(self, key_cvs, flat_states, mask, nchunks):
         """In-trace merge (the body of _merge_fn without the jit
         boundary): sort-segment the partial keys, reduce states; live
-        groups come out first. `allow_host_sort=False` force-disables
-        the host-callback sort — mandatory when tracing inside
-        shard_map, where pure_callback would deadlock the collective."""
+        groups come out first."""
         cap = mask.shape[0]
         perm, seg_ids, live, seg_live, key_out = \
-            self._sort_and_segment(key_cvs, mask, nchunks,
-                                   allow_host_sort=allow_host_sort)
+            self._sort_and_segment(key_cvs, mask, nchunks)
         out_flat = []
         i = 0
         for a in self.aggs:
@@ -1012,7 +1006,7 @@ class HashAggregateExec(TpuExec):
             return None
         from ..parallel.mesh_program import MeshProgram
         nchunks = (0,) * len(self.keys)
-        update = self._update_fn(nchunks, allow_host_sort=False)
+        update = self._update_fn(nchunks)
         prog = MeshProgram(
             lambda t: update(*t), n, cls="HashAggregateExec",
             tag="meshupdate", key=self._fp + (self._stage_fp,))

@@ -13,11 +13,9 @@ import threading
 import uuid
 from typing import List, Optional, Sequence
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..columnar import dtypes as dt
 from ..columnar.table import Schema
 from ..expr.expressions import EmitCtx, Expression
 from ..ops.gather import take
@@ -108,16 +106,6 @@ class ShuffleExchangeExec(TpuExec):
                         % n).astype(jnp.int32)
             ctx = EmitCtx(cvs, cap)
             key_cvs = [k.emit(ctx) for k in keys]
-            if (len(keys) == 1 and cap % 1024 == 0
-                    and jax.default_backend() == "tpu"):
-                kd = keys[0].dtype
-                if isinstance(kd, (dt.IntegerType, dt.DateType)):
-                    # hot path: fused Pallas murmur3+pmod kernel
-                    from ..ops.pallas_kernels import \
-                        pallas_partition_ids_i32
-                    kcv = key_cvs[0]
-                    return pallas_partition_ids_i32(
-                        kcv.data.astype(jnp.int32), kcv.validity, n)
             return partition_ids(key_cvs, [k.dtype for k in keys], n)
 
         def _map_fn(cvs, mask):

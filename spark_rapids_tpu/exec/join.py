@@ -577,7 +577,7 @@ class HashJoinExec(TpuExec):
             pinned = jnp.where(valid, u64(kcv, rkey.dtype),
                                jnp.uint64(0xFFFFFFFFFFFFFFFF))
             perm = sk.lexsort([jnp.logical_not(valid).astype(jnp.uint8),
-                               pinned], allow_host=False)
+                               pinned])
             return (list(bcvs), pinned[perm], perm.astype(jnp.int32),
                     jnp.sum(valid.astype(jnp.int32)))
 

@@ -19,9 +19,8 @@ a `MeshExchangeExec` + consumer pair. Three stage kinds:
 
   agg      — final-mode HashAggregateExec over the exchange: the fused
              program is emit-keys → partition_ids → all_to_all →
-             in-trace merge (`_merge_body`, host sort disabled —
-             pure_callback would deadlock inside shard_map) →
-             `_finalize_fn`. One compiled program per stage.
+             in-trace merge (`_merge_body`) → `_finalize_fn`. One
+             compiled program per stage.
   chain    — a fusable filter/project chain over the exchange: the
              chain's `fusable_stage()` transforms apply to the received
              shard in-program, then compact.
@@ -530,11 +529,8 @@ class SpmdStageExec(TpuExec):
                 mkeys = [k.emit(kctx) for k in consumer.keys]
                 nkeys = len(consumer.keys)
                 flat_states = [cv.data for cv in out_cvs[nkeys:]]
-                # in-trace merge: host-callback sort force-disabled —
-                # pure_callback deadlocks inside shard_map
                 mk, mflat, mlive = consumer._merge_body(
-                    mkeys, flat_states, out_mask, nchunks,
-                    allow_host_sort=False)
+                    mkeys, flat_states, out_mask, nchunks)
                 outs = consumer._finalize_fn(mk, mflat, mlive)
                 count = jnp.sum(mlive.astype(jnp.int32))
             elif kind == "chain":

@@ -2,7 +2,7 @@
 
 Membership is a directory of JSON files (`sql.fleet.directory`), one
 per live member, written atomically (tmp + rename) at join and removed
-at leave. Every member — and the bench/test harness — discovers the
+at leave. Every member — and a test harness — discovers the
 fleet by listing that directory: no coordinator, no gossip protocol,
 and a crashed process leaves at worst one stale file that liveness
 probing (pid check on this host) or a failed fetch skims off. This is

@@ -1,14 +1,11 @@
-"""Bench/test fleet worker: one real service process in the fabric.
+"""Fleet worker: one real service process in the fabric, the operator's
+entry point (docs/fleet.md).
 
-`bench.py --concurrent --fleet N` launches N of these via
-`python -m spark_rapids_tpu.fleet.worker`; each builds a session,
-registers the shared parquet views, starts the gateway (which joins
-the fleet named by --fleet-dir), prints one READY line with its
-addresses, and serves until stdin closes. Keeping the entry in-tree
-(rather than inline -c scripts in bench.py) makes the worker
-importable from tests and keeps the bench honest: workers are real
-interpreters with cold program caches, not forked copies of a warm
-parent.
+`python -m spark_rapids_tpu.fleet.worker` builds a session, registers
+the shared parquet views, starts the gateway (which joins the fleet
+named by --fleet-dir), prints one READY line with its addresses, and
+serves until stdin closes. Each worker is a real interpreter with a
+cold program cache, not a forked copy of a warm parent.
 """
 from __future__ import annotations
 
@@ -48,7 +45,7 @@ def main(argv=None) -> int:
     sys.stdout.write("READY " + json.dumps(ready) + "\n")
     sys.stdout.flush()
 
-    # serve until the parent closes our stdin (bench teardown) — no
+    # serve until the parent closes our stdin — no
     # signal handling needed, and an orphaned worker exits on its own
     for _line in sys.stdin:
         if _line.strip() == "stop":

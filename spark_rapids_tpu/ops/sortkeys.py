@@ -110,44 +110,18 @@ def string_chunk_keys(cv: CV, nchunks: int) -> List[jnp.ndarray]:
     return keys
 
 
-def lexsort(keys: Sequence[jnp.ndarray],
-            allow_host: bool = True) -> jnp.ndarray:
+def lexsort(keys: Sequence[jnp.ndarray]) -> jnp.ndarray:
     """Stable permutation ordering rows by keys[0], then keys[1], ...
 
-    ONE variadic `lax.sort` over all key arrays (lexicographic, stable)
-    with an iota payload operand that becomes the permutation — k times
-    less sort work than the chained-argsort (LSD) formulation.
-
-    On the CPU fallback backend, XLA's comparator sort is single-threaded
-    scalar code (~10x slower than numpy's radix-ish sorts at 1M rows). A
-    host-callback into np.lexsort recovers that — but jax.pure_callback
-    proved unsafe under CONCURRENT executions (deadlocks inside
-    shard_map; intermittent multi-minute stalls when several programs
-    with callbacks run at once, XLA callback-queue starvation), so it is
-    OPT-IN via SRTPU_HOST_SORT=1 for single-threaded batch workloads
-    only. The default is the always-correct pure XLA sort; the hot
-    paths that used to need big sorts (join builds, groupbys) now use
-    the sort-free direct/hash paths instead.
-
-    allow_host=False force-disables the callback regardless (shard_map
-    callers).
+    On the CPU backend: ONE variadic `lax.sort` over all key arrays
+    (lexicographic, stable) with an iota payload operand that becomes
+    the permutation. Off the CPU: chained stable two-operand sorts, one
+    per 32-bit word of the keys, least significant first
+    (`_lexsort_lsd32`), because the TPU compiler's time grows steeply
+    with the operands of a variadic sort.
     """
-    import os
-
     import jax
     n = keys[0].shape[0]
-    if (allow_host and os.environ.get("SRTPU_HOST_SORT") == "1"
-            and jax.default_backend() == "cpu" and n >= 1 << 15):
-        import numpy as np
-
-        def _host_lexsort(*ks):
-            # np.lexsort: LAST key is primary -> reverse
-            return np.lexsort(ks[::-1]).astype(np.int32)
-
-        return jax.pure_callback(
-            _host_lexsort,
-            jax.ShapeDtypeStruct((n,), jnp.int32),
-            *keys, vmap_method="sequential")
     iota = jnp.arange(n, dtype=jnp.int32)
     if jax.default_backend() != "cpu":
         return _lexsort_lsd32(keys, iota)

@@ -36,7 +36,7 @@ from typing import Any, Dict, List
 
 __all__ = ["run_stage_driver", "aqe_stats", "reset_stats"]
 
-# session-process AQE decision counters (bench --smoke `extra.aqe`)
+# session-process AQE decision counters (read through aqe_stats())
 _STATS_LOCK = threading.Lock()
 _STATS = {"coalesced_partitions": 0, "skew_splits": 0, "demotions": 0,
           "mesh_reshards": 0, "mesh_demotions": 0}
@@ -44,7 +44,7 @@ _STATS = {"coalesced_partitions": 0, "skew_splits": 0, "demotions": 0,
 
 def aqe_stats() -> Dict[str, int]:
     """Process-lifetime AQE decision counters, merged with the
-    calibration table's counters (bench --smoke records these)."""
+    calibration table's counters."""
     with _STATS_LOCK:
         out: Dict[str, int] = dict(_STATS)
     from .stats import calibration_stats

@@ -227,8 +227,8 @@ def op_time_seconds(metrics: dict) -> float:
 
 
 def top_operators(records: List[dict], n: int = 5) -> List[dict]:
-    """Top-n operators by attributed time (the bench --profile and
-    EXPLAIN ANALYZE sink list)."""
+    """Top-n operators by attributed time (the EXPLAIN ANALYZE sink list
+    and tools/profile_report.py)."""
     rows = []
     for r in records:
         m = r.get("metrics") or {}

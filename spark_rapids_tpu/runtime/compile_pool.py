@@ -1,8 +1,9 @@
 """Background XLA compilation: a bounded pool that moves the compile
 tail off the dispatch path.
 
-BENCH_r05 put numbers on the cold tail: q4 compiles 211 programs to do
-14 ms of work. The programs are all known *before* they are needed —
+A cold query compiles far longer than it runs (q4 needs 211 programs
+for milliseconds of work). The programs are all known *before* they
+are needed —
 the planner fixes every stage's program key at launch, and a service
 restart knows yesterday's whole key set (runtime/warm_pack.py) — so
 compilation is an amortizable, pipelinable cost, not an inline one
@@ -141,8 +142,8 @@ class CompilePool:
         return n
 
     def drain(self, timeout: float = 30.0) -> bool:
-        """Block until the queue is empty and workers are idle (tests,
-        bench --compile-tail). Returns False on timeout."""
+        """Block until the queue is empty and workers are idle (tests).
+        Returns False on timeout."""
         return self._idle.wait(timeout)
 
     def shutdown(self) -> None:

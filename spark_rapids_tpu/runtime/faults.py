@@ -77,7 +77,7 @@ __all__ = ["ACTIVE", "POINTS", "InjectedFault", "install_plan",
 ACTIVE = False
 
 #: the instrumented fault-point inventory (docs/robustness.md and the
-#: bench --chaos plan generator both derive from this tuple)
+#: plan generator of tests/test_soak.py both derive from this tuple)
 POINTS = ("block.fetch", "device.dispatch", "executor.task",
           "spill.write", "xla.compile", "exchange.map", "rpc.send",
           "mesh.collective", "peer.fetch")
@@ -306,7 +306,7 @@ def is_transient_error(e: BaseException) -> bool:
     return isinstance(e, ConnectionError)
 
 
-# -- recovery accounting (chaos soak / bench reporting) -----------------
+# -- recovery accounting (read by the chaos soak) -----------------------
 
 _recovery_lock = threading.Lock()
 _recovery: Dict[str, int] = {}

@@ -218,8 +218,7 @@ class Ledger:
                 "findings": list(self.findings)}
 
     def report(self) -> dict:
-        """Summary counters for the resource_ledger event and bench
-        extra.ledger."""
+        """Summary counters for the resource_ledger event."""
         with self._mu:
             kinds = {
                 k: {"acquires": v["acquires"], "releases": v["releases"],

@@ -189,8 +189,7 @@ class Witness:
                 "edges": len(self._edges)}
 
     def report(self) -> dict:
-        """Summary counters for the concurrency_report event and
-        bench extra.lockdep."""
+        """Summary counters for the concurrency_report event."""
         nodes = set()
         for a, b in self._edges:
             nodes.add(a)

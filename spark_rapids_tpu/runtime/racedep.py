@@ -30,7 +30,7 @@ Schedule perturbation: `perturb(seed)` arms a seeded adversarial mode
 — `sys.setswitchinterval` drops to microseconds and instrumented
 access points inject `time.sleep(0)` yields chosen by a seeded RNG —
 so interleavings that would need days of wall clock to occur by
-chance happen in one `bench --chaos` pass, which then asserts
+chance happen in one pass of tests/test_soak.py, which then asserts
 byte-identity and balanced ledgers under them.
 
 Enablement: env ``SRTPU_RACEDEP=1`` BEFORE the engine imports
@@ -205,8 +205,7 @@ class Witness:
 
     # -- reporting -----------------------------------------------------
     def report(self) -> dict:
-        """Summary counters for the race_report event and bench
-        extra.chaos."""
+        """Summary counters for the race_report event."""
         with self._mu:
             shared = sum(1 for s in self._vars.values() if s.shared)
             return {"enabled": True, "tracked": len(self._vars),

@@ -201,8 +201,7 @@ class QueryManager:
     Synchronous actions (`DataFrame.to_arrow` etc.) run on the CALLER's
     thread: `open_query()` blocks until the scheduler grants admission,
     the caller executes, then `close_query()` releases the grant. Async
-    submissions (`submit()`, used by the gateway and the throughput
-    bench) get a thread that walks the same path. Either way the
+    submissions (`submit()`, used by the gateway) get a thread that walks the same path. Either way the
     scheduler fully decides who runs: grants are handed out in `_pump()`
     under one lock whenever a slot or admitted memory frees up."""
 

@@ -812,10 +812,8 @@ class DataFrame:
     def uncache(self) -> "DataFrame":
         """Release this DataFrame's cached physical plan (exec nodes,
         their device state, materialized shuffles). The next action
-        re-plans from the logical tree — a FRESH execution, which is
-        what honest benchmarking times (`bench.py` calls this between
-        iterations so repeat runs do not silently reuse resident
-        operator state)."""
+        re-plans from the logical tree: a FRESH execution, which reuses
+        no resident operator state."""
         cached = self._cached
         if cached is not None:
             try:
@@ -960,7 +958,7 @@ class DataFrame:
     def submit(self, action: str = "collect", pool=None, timeout=None):
         """Async action through the query service: returns a QueryHandle
         immediately; `handle.result()` blocks for the arrow table (or
-        re-raises). The gateway and the throughput bench submit here."""
+        re-raises). The gateway submits here."""
         if action != "collect":
             raise ValueError("submit() supports the 'collect' action")
         import time as _time

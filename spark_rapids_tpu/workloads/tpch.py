@@ -109,7 +109,7 @@ def gen_lineitem(sf: float = 0.1, seed: int = 0,
     }
     if full:
         # independent stream: adding columns must not perturb the draws
-        # above (bench numbers stay comparable round-over-round)
+        # above (the base columns stay the same data round over round)
         r2 = np.random.default_rng(seed + 104729)
         npart = max(int(PART_ROWS_PER_SF * sf), 1)
         nsupp = max(int(SUPPLIER_ROWS_PER_SF * sf), 1)
@@ -167,7 +167,7 @@ def q1(df):
 
 def q6_numpy_baseline(ship, disc_unscaled, qty_unscaled, price_unscaled):
     """Vectorized single-core CPU reference over the raw unscaled arrays
-    (the CPU-Spark stand-in for bench.py)."""
+    (the reference tests/test_tpch.py compares q6 against)."""
     m = ((ship >= 8766) & (ship < 9131)
          & (disc_unscaled >= 5) & (disc_unscaled <= 7)
          & (qty_unscaled < 2400))
@@ -234,7 +234,7 @@ def gen_orders(sf: float = 0.1, seed: int = 1,
         r2 = np.random.default_rng(seed + 104729)
         # spec clause 4.2.3: orders reference only custkeys NOT divisible
         # by 3, so a third of customers have no orders (q13/q22 depend on
-        # this). Drawn from the r2 stream so the base (bench Q3) dataset
+        # this). Drawn from the r2 stream so the base (Q3) dataset
         # keeps its round-over-round draws.
         ncust = max(n // 10, 1)
         j = r2.integers(0, max(2 * ncust // 3, 1), n)

@@ -1,8 +1,7 @@
 """Pandas oracles for all 22 TPC-H queries.
 
 Independent implementations of the official query set used to verify the
-engine's results (tests/test_tpch.py) and as the CPU baseline for the
-bench geomean. Written directly from the TPC-H v3 SQL — NOT by
+engine's results (tests/test_tpch.py, chip_smoke.py). Written directly from the TPC-H v3 SQL — NOT by
 translating tpch_queries.py — so an engine bug and an oracle bug would
 have to coincide to go unseen.
 

@@ -1,4 +1,4 @@
-"""Regressions for the round-1 advisor findings (ADVICE.md)."""
+"""Regressions for the round-1 advisor findings."""
 import io
 
 import jax.numpy as jnp
@@ -36,7 +36,7 @@ def _cv_strings(cv):
 
 def test_concat_full_capacity_string_batch_no_trailing_nuls():
     # part 1's data buffer is exactly full: its last row must NOT extend
-    # into part 2's region after concat (ADVICE.md high finding)
+    # into part 2's region after concat (a high finding of that review)
     a = _string_cv(["row0", "row127"])            # 10 bytes, exactly full
     b = _string_cv(["xx", "yy"], byte_cap=16)     # padded buffer
     out = concat_cvs([a, b], dt.STRING)

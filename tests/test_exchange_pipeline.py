@@ -405,3 +405,41 @@ def test_hash_functions_end_to_end():
     # null k row: xxhash64 folds only v; hive contributes 0 for k
     assert all(isinstance(r[0], int) and isinstance(r[1], int)
                for r in out)
+
+
+# =====================================================================
+# the map program places rows where ops/hash.py:partition_ids says
+# =====================================================================
+@pytest.mark.parametrize("key_types", [
+    (dt.INT32,), (dt.DATE,), (dt.INT64,), (dt.INT32, dt.INT64)],
+    ids=["int32", "date", "int64", "two_keys"])
+def test_map_places_rows_by_partition_ids(key_types):
+    """At a capacity that is a multiple of 1,024, with dead rows and
+    null keys: partition p's slice of the map output holds exactly the
+    live rows whose murmur3 pmod is p, in their original order."""
+    import jax.numpy as jnp
+
+    from spark_rapids_tpu.exec.exchange import ShuffleExchangeExec
+    from spark_rapids_tpu.expr.expressions import BoundRef
+    from spark_rapids_tpu.ops.hash import partition_ids
+
+    cap, n = 2048, 16
+    rng = np.random.default_rng(11)
+    mask = rng.random(cap) < 0.9
+    key_cvs = [CV(jnp.asarray(rng.integers(-2**31, 2**31, cap).astype(
+                      np.int64 if t is dt.INT64 else np.int32)),
+                  jnp.asarray(rng.random(cap) < 0.95)) for t in key_types]
+    row_id = CV(jnp.arange(cap, dtype=jnp.int64), jnp.ones(cap, bool))
+    keys = [BoundRef(i, t) for i, t in enumerate(key_types)]
+
+    out, counts = ShuffleExchangeExec._build_map_fn(n, keys)(
+        key_cvs + [row_id], jnp.asarray(mask))
+
+    want = np.asarray(partition_ids(key_cvs, list(key_types), n))
+    counts = np.asarray(counts)
+    assert counts.sum() == mask.sum()
+    got_rows = np.asarray(out[-1].data)
+    ends = np.cumsum(counts)
+    for p in range(n):
+        assert got_rows[ends[p] - counts[p]:ends[p]].tolist() == \
+            np.flatnonzero(mask & (want == p)).tolist()

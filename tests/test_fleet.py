@@ -129,19 +129,30 @@ def test_invalidate_prefix_broadcasts(fabric):
     assert b.stats["fleet_inv_broadcasts"] == 1
 
 
-def test_lost_broadcast_soundness_via_snapshot_keys(fabric):
+@pytest.mark.parametrize("lost", ["never_sent", "every_send_fails"])
+def test_lost_broadcast_soundness_via_snapshot_keys(fabric, lost):
     """A peer that never hears an invalidation holds its stale entry
     under a key embedding the OLD file snapshot; a requester re-stats
     before computing its key, so it asks for a key nobody holds and
-    recomputes against the new bytes."""
+    recomputes against the new bytes. The broadcast is lost either
+    because none runs or because every send of it fails (counted)."""
     s, a, spawn, p = fabric
     with fctx.scoped(a):
         stale = _arrow(s)
     assert a.export.stats()["entries"] == 1
-    # external overwrite, broadcast "lost" (no invalidation runs)
+    # external overwrite
     pq.write_table(pa.table({"a": list(range(100)),
                              "b": [i * 3 for i in range(100)]}), p)
     b = spawn()
+    if lost == "every_send_fails":
+        faults.install_plan("peer.fetch:prob=1:raise=FetchFailed")
+        try:
+            with fctx.scoped(b):
+                result_cache.invalidate_prefix(os.path.dirname(p))
+        finally:
+            faults.clear_plan()
+        assert b.stats["fleet_inv_broadcast_failures"] >= 1
+        assert a.export.stats()["entries"] == 1   # A never heard it
     result_cache.clear()
     with fctx.scoped(b):
         fresh = _arrow(s)

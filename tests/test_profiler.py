@@ -350,7 +350,7 @@ def test_cli_diff_on_real_logs(tmp_path):
 
 
 # ----------------------------------------------------------------------
-# helpers: aggregation + top operators (bench --profile path)
+# helpers: aggregation + top operators (tools/profile_report.py path)
 # ----------------------------------------------------------------------
 def test_aggregate_ops_and_top_operators(tmp_path):
     s = _session(tmp_path)

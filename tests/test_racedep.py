@@ -158,7 +158,7 @@ def test_perturb_restore_switch_interval():
 
 
 def test_perturbed_queries_byte_identical():
-    """The bench --chaos schedule_perturbation pass in miniature: two
+    """tests/test_soak.py::test_schedule_perturbation in miniature: two
     threads re-running q3/q6-shaped queries under seeded yields +
     microsecond switch interval must produce byte-identical results
     and zero witnessed collapses."""

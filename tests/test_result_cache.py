@@ -306,8 +306,8 @@ def test_fragment_tier_hit_and_explain_annotation():
 
 
 def test_fragment_hit_after_invalidating_side_write(tmp_path):
-    """The BENCH_r06 `fragment_hits: 0` regression scenario, done
-    right. The zipfian bench showed zero fragment hits not because
+    """The `fragment_hits: 0` regression scenario, done right. A
+    repeat-heavy zipfian run once showed zero fragment hits not because
     fragment keying was broken but because its streams were served from
     the whole-query tier (no replanning => substitute_fragments never
     ran) and its only replanned query had no shuffle exchange. This

@@ -10,11 +10,13 @@ keyed program-cache misses, the AQE mesh re-shard rule's on/off gates,
 fault-driven degradation to the round-based exchange, and leak-free
 cancellation mid-stage under the resource-ledger witness.
 """
+import decimal
 import time
 
 import numpy as np
 import pandas as pd
 import pyarrow as pa
+import pyarrow.compute as pc
 import pytest
 
 import spark_rapids_tpu as st
@@ -22,12 +24,12 @@ import spark_rapids_tpu.functions as F
 from spark_rapids_tpu.columnar import dtypes as dt
 from spark_rapids_tpu.exec.mesh_exchange import MeshExchangeExec
 from spark_rapids_tpu.exec.spmd_stage import SpmdStageExec
-from spark_rapids_tpu.expr.expressions import col
+from spark_rapids_tpu.expr.expressions import col, lit
 from spark_rapids_tpu.parallel.mesh import (mesh_fingerprint,
                                             mesh_topology_key)
 from spark_rapids_tpu.runtime import faults
 from spark_rapids_tpu.runtime.program_cache import drain_compile_events
-from spark_rapids_tpu.workloads import spmd_bench, tpch
+from spark_rapids_tpu.workloads import tpch
 
 N_DEV = 8
 
@@ -61,7 +63,8 @@ def _walk(node):
 
 
 def _msum(df, key):
-    return spmd_bench._metric_sum(df, key)
+    """Sum `key` over the per-operator metrics of `df`'s last action."""
+    return int(sum(m.get(key, 0) for m in df.last_metrics().values()))
 
 
 def _groupby(s, data, aggs=None):
@@ -187,10 +190,49 @@ def _tpch_frames(s, sf=0.003):
                                     ("customer", tpch.gen_customer, 9))}
 
 
+def _canon(tbl):
+    """Rows sorted by every column: the three paths shard rows
+    differently, so content, not order, is the parity contract."""
+    if tbl.num_rows <= 1:
+        return tbl
+    return tbl.take(pc.sort_indices(
+        tbl, sort_keys=[(name, "ascending") for name in tbl.column_names]))
+
+
+def _q6_shape(lineitem):
+    """TPC-H Q6's predicate stack feeding a grouped revenue sum (plain
+    Q6 is a global reduction with no exchange to fuse, so this groups by
+    return flag to route the same filter+agg through the mesh)."""
+    d = decimal.Decimal
+    return (lineitem.filter(
+                (col("l_shipdate") >= 8766) & (col("l_shipdate") < 9131)
+                & (col("l_discount") >= lit(d("0.05")))
+                & (col("l_discount") <= lit(d("0.07")))
+                & (col("l_quantity") < lit(d("24"))))
+            .group_by("l_returnflag")
+            .agg(F.sum(col("l_extendedprice") * col("l_discount"))
+                 .alias("revenue")))
+
+
+def _q3_shape(customer, orders, lineitem):
+    """TPC-H Q3 without its top-10 tail (ties at the limit would make
+    parity across paths depend on row order)."""
+    d = decimal.Decimal
+    rev = col("l_extendedprice") * (lit(d("1")) - col("l_discount"))
+    return (customer.filter(col("c_mktsegment") == lit("BUILDING"))
+            .join(orders.with_column("c_custkey", col("o_custkey")),
+                  on=["c_custkey"], how="inner")
+            .filter(col("o_orderdate") < 9204)
+            .with_column("l_orderkey", col("o_orderkey"))
+            .join(lineitem, on=["l_orderkey"], how="inner")
+            .filter(col("l_shipdate") > 9204)
+            .group_by("l_orderkey", "o_orderdate", "o_shippriority")
+            .agg(F.sum(rev).alias("revenue")))
+
+
 def test_q6_shape_parity_three_paths():
     def run(s):
-        return spmd_bench._canon(
-            spmd_bench._q6_shape(_tpch_frames(s)["lineitem"]).to_arrow())
+        return _canon(_q6_shape(_tpch_frames(s)["lineitem"]).to_arrow())
     want = run(_host())
     assert run(_round()).equals(want)
     assert run(_fused()).equals(want)
@@ -200,9 +242,8 @@ def test_q6_shape_parity_three_paths():
 def test_q3_shape_parity_three_paths():
     def run(s):
         d = _tpch_frames(s)
-        q = spmd_bench._q3_shape(d["customer"], d["orders"],
-                                 d["lineitem"])
-        tbl = spmd_bench._canon(q.to_arrow())
+        q = _q3_shape(d["customer"], d["orders"], d["lineitem"])
+        tbl = _canon(q.to_arrow())
         return tbl, q
     want, _ = run(_host())
     got_r, _ = run(_round())

@@ -92,16 +92,6 @@ def test_q6_step_8mi_rows(one_chip):
     _compile(step, *args)
 
 
-def test_pallas_partition_ids_1mi_rows(one_chip):
-    """The one Pallas kernel through Mosaic (never interpret=True)."""
-    from spark_rapids_tpu.ops.pallas_kernels import pallas_partition_ids_i32
-    vals = jax.ShapeDtypeStruct((BATCH,), jnp.int32, sharding=one_chip)
-    valid = jax.ShapeDtypeStruct((BATCH,), jnp.bool_, sharding=one_chip)
-    compiled = _compile(
-        lambda v, m: pallas_partition_ids_i32(v, m, 16), vals, valid)
-    assert "tpu_custom_call" in compiled.as_text()
-
-
 def test_q1_partial_aggregate_update_1mi_rows(session, one_chip):
     """q1's first-pass aggregate program (HashAggregateExec, tag
     'hash_update': filter + projections + bucketed hash aggregate, one

@@ -18,20 +18,15 @@ Usage:
     # per-query trace waterfall + critical-path share table
     python tools/profile_report.py --trace /tmp/srtpu-events/query-123-0.jsonl
 
-    # BENCH_*.json emitted with --profile also parses
-    python tools/profile_report.py BENCH_r06.json
-
 Inputs: per-query JSONL event logs written by the engine
-(`spark.rapids.tpu.sql.eventLog.enabled`, see docs/observability.md) or
-`BENCH_*.json` files whose `extra.tpch_profile` section was produced by
-`bench.py --profile`. Operators are keyed `lore_id:name` — stable for
+(`spark.rapids.tpu.sql.eventLog.enabled`, see docs/observability.md).
+Operators are keyed `lore_id:name` — stable for
 the same plan across runs and across executor processes — so the diff
 lines up operators even when absolute times moved.
 """
 from __future__ import annotations
 
 import argparse
-import json
 import os
 import sys
 from typing import Dict, List
@@ -45,37 +40,7 @@ from spark_rapids_tpu.profiler.event_log import (  # noqa: E402
     aggregate_ops, op_time_seconds, read_event_log)
 
 
-def load_events(path: str) -> List[dict]:
-    """Load one artifact as a flat event list. Detects BENCH_*.json
-    (single JSON document; its extra.tpch_profile section becomes
-    synthetic op_metrics events) vs JSONL event logs."""
-    with open(path, encoding="utf-8") as f:
-        text = f.read()
-    try:
-        doc = json.loads(text)
-    except json.JSONDecodeError:
-        return read_event_log(path)
-    if not isinstance(doc, dict):
-        return read_event_log(path)
-    # bench artifact: either the raw one-line JSON or the archived
-    # {"parsed": {...}} wrapper
-    parsed = doc.get("parsed", doc)
-    extra = parsed.get("extra") or {}
-    prof = extra.get("tpch_profile") or {}
-    events = [{"event": "bench", "query_id": path,
-               "metric": parsed.get("metric"),
-               "value": parsed.get("value")}]
-    for qname, rows in prof.items():
-        if not isinstance(rows, list):
-            continue
-        events.append({"event": "op_metrics", "query_id": qname, "ops": [
-            {"lore_id": r.get("loreId"), "name": r.get("op"),
-             "describe": r.get("op"),
-             "metrics": {"opTime": (r.get("time_ms") or 0) / 1e3,
-                         **({"numOutputRows": r["rows"]}
-                            if r.get("rows") is not None else {})}}
-            for r in rows]})
-    return events
+load_events = read_event_log
 
 
 def _expand(paths: List[str]) -> List[str]:
@@ -376,11 +341,10 @@ def diff_report(a_events: List[dict], b_events: List[dict],
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
-        description="Post-process query event logs / bench profiles "
-                    "into per-operator breakdowns and A/B diffs.")
+        description="Post-process query event logs into per-operator "
+                    "breakdowns and A/B diffs.")
     ap.add_argument("paths", nargs="+",
-                    help="event-log .jsonl files, directories of them, "
-                         "or BENCH_*.json files")
+                    help="event-log .jsonl files or directories of them")
     ap.add_argument("--diff", action="store_true",
                     help="treat the two paths as runs A and B and "
                          "attribute the regression")
