@@ -3,9 +3,17 @@
 (reference: GpuShuffleExchangeExecBase.scala:174 — partition ids computed
 on device, contiguous-split into per-partition sub-batches, serializer on
 host.) Map side runs one fused XLA program per batch: murmur3 partition
-ids (or round-robin), stable sort by target, per-partition counts; then a
-single bulk D2H and host slicing into serializer sub-batches. Reduce side
-is LocalShuffle.reduce_batch (host concat + one H2D).
+ids (or round-robin, or range bounds), then `_finish_map` brings the rows
+into stable target order WITHOUT indexing them: every fixed-width array
+(data, validity, decimal128 limbs, a struct's leaves) rides one
+two-operand stable sort keyed by the target, a 32-bit word a turn
+(`ops/partition.py:sorted_by_target`, the routine the mesh exchange
+uses), and the per-partition counts are counted (n reductions), not
+scattered. Only a variable-width column (string, list) is still gathered,
+by the row index that rode the same sort. `mapSortWords` and
+`mapGatheredColumns` say which of the two a pass did. Then a single bulk
+D2H and host slicing into serializer sub-batches. Reduce side is
+LocalShuffle.reduce_batch (host concat + one H2D).
 """
 from __future__ import annotations
 
@@ -20,6 +28,8 @@ from ..columnar.table import Schema
 from ..expr.expressions import EmitCtx, Expression
 from ..ops.gather import take
 from ..ops.hash import partition_ids
+from ..ops.kernel_utils import CV
+from ..ops.partition import runs_by_target, sorted_by_target, word_count
 from ..shuffle.local import LocalShuffle
 from ..shuffle.serializer import HostSubBatch
 from ..utils.transfer import fetch
@@ -46,15 +56,72 @@ def _count_map_exec(n: int = 1):
         _map_exec_stats["partitions"] += n
 
 
+def _has_var(cv) -> bool:
+    return cv.offsets is not None or any(_has_var(ch) for ch in cv.children)
+
+
+def _riders(cv, out):
+    """Append the fixed-width arrays of `cv` that ride the sort, in the
+    order `_in_target_order` takes them back: validity, then data or a
+    struct's fields. A string or list goes by `take` and adds none."""
+    if cv.offsets is not None:
+        return
+    out.append(cv.validity)
+    if not cv.children:
+        out.append(cv.data)
+    for ch in cv.children:
+        _riders(ch, out)
+
+
+def _in_target_order(cv, riders, order, live):
+    """`cv` in target order: from the sorted `riders` where it rode,
+    else by `take` with the row index that rode beside them."""
+    if cv.offsets is not None:
+        return take(cv, order, in_bounds=live)
+    valid = next(riders) & live
+    if not cv.children:
+        return CV(next(riders), valid)
+    return CV(jnp.zeros(0, jnp.int8), valid, None,
+              tuple(_in_target_order(ch, riders, order, live)
+                    for ch in cv.children))
+
+
+def _payload(cvs):
+    """(riders, gathered): the arrays of `cvs` that ride the sort and
+    the number of columns that hold a variable-width buffer."""
+    riders = []
+    for cv in cvs:
+        _riders(cv, riders)
+    return riders, sum(_has_var(cv) for cv in cvs)
+
+
+def map_sort_shape(cvs):
+    """(words, gathered) of one map pass over `cvs`: the 32-bit words a
+    row takes through the sort (`mapSortWords`; the row index is one
+    where a column is gathered) and the columns that go by `take`
+    (`mapGatheredColumns`). Reads shapes and dtypes only."""
+    riders, gathered = _payload(cvs)
+    return word_count(riders) + bool(gathered), gathered
+
+
 def _finish_map(cvs, mask, pids, n):
-    """Shared map-side tail: dead rows to the overflow bucket, stable
-    sort by target partition, per-partition counts."""
-    eff = jnp.where(mask, pids, n)
-    order = jnp.argsort(eff, stable=True)
-    live_sorted = mask[order]
-    counts = jnp.bincount(eff, length=n + 1)[:n]
-    out = [take(cv, order, in_bounds=live_sorted) for cv in cvs]
-    return out, counts
+    """Shared map-side tail: the columns in stable target order (dead
+    rows after every live row, validity false), per-partition counts.
+    Every fixed-width array rides ONE sort by target
+    (`ops/partition.py`); a string or list column is gathered by the row
+    index that rode the same sort. Counts are counted, not scattered."""
+    rows = jnp.arange(mask.shape[0], dtype=jnp.int32)
+    eff, starts = runs_by_target(mask, pids, n)
+    riders, gathered = _payload(cvs)
+    if gathered:
+        riders.append(rows)
+    if riders:
+        riders = sorted_by_target(eff, riders)
+    order = riders[-1] if gathered else None
+    live = rows < starts[n]
+    riders = iter(riders)
+    out = [_in_target_order(cv, riders, order, live) for cv in cvs]
+    return out, starts[1:] - starts[:-1]
 
 
 class ShuffleExchangeExec(TpuExec):
@@ -152,8 +219,11 @@ class ShuffleExchangeExec(TpuExec):
                                op=type(self).__name__)
                 with m.timer("partitionTime"):
                     from ..shuffle.serializer import cv_shuffle_bufs
-                    out, counts = self._run_map(batch.cvs(),
-                                                batch.row_mask)
+                    cvs = batch.cvs()
+                    out, counts = self._run_map(cvs, batch.row_mask)
+                    words, gathered = map_sort_shape(cvs)
+                    m.add("mapSortWords", words)
+                    m.add("mapGatheredColumns", gathered)
                     # tpulint: allow[sync-under-lock] the map phase IS the critical section: _lock memoizes the whole shuffle build and readers only need it after _shuffle is set
                     return fetch({
                         "cols": [cv_shuffle_bufs(cv) for cv in out],

@@ -6,10 +6,13 @@ RapidsShuffleClient/Server) with a single XLA collective, and moves
 nothing element by element on either side of it:
 
   send     the rows are sorted by target shard (stable, dead rows last)
-           with the payload riding the sort (`_sorted_by_target`), after
-           which the rows for peer p are ONE contiguous run; peer p's
-           bucket is a `dynamic_slice` at the run's start. No gather by
-           a sorted index, no scatter into a zeroed (n, cap) buffer.
+           with the payload riding the sort (`sorted_by_target` of
+           `ops/partition.py`: the tree's one sort-by-target, which the
+           one-chip exchange map `exec/exchange.py:_finish_map` calls
+           too), after which the rows for peer p are ONE contiguous run;
+           peer p's bucket is a `dynamic_slice` at the run's start. No
+           gather by a sorted index, no scatter into a zeroed (n, cap)
+           buffer.
   wire     `jax.lax.all_to_all` moves the n buckets of every payload
            array over ICI; the n run lengths ride one small all_to_all
            beside them. No mask crosses.
@@ -38,83 +41,9 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
+from ..ops.partition import runs_by_target, sorted_by_target
+
 __all__ = ["exchange_cvs"]
-
-
-def _runs_by_target(mask, pids, n_shards: int):
-    """(eff_pid, starts): a row's effective target (dead rows go to
-    bucket n, past every peer's) and, for rows brought into stable
-    target order, where each peer's run begins: starts [n+1], starts[n]
-    the live row count. Counted, not searched: n reductions."""
-    eff_pid = jnp.where(mask, pids, n_shards).astype(jnp.int32)
-    per_target = jnp.sum(
-        eff_pid[None, :] == jnp.arange(n_shards, dtype=jnp.int32)[:, None],
-        axis=1, dtype=jnp.int32)
-    starts = jnp.concatenate([jnp.zeros(1, jnp.int32),
-                              jnp.cumsum(per_target)])
-    return eff_pid, starts
-
-
-def _carrier(dtype):
-    """The type an array travels as: itself, or int32 where narrower."""
-    return dtype if dtype.itemsize >= 4 else jnp.dtype(jnp.int32)
-
-
-def _to_words(arrays):
-    """Every array [cap, ...] as rows of ONE uint32 [W, cap]: a 64-bit
-    value is two rows, a narrow integer widens to one, trailing dims
-    (decimal128 limb pairs) are rows of their own, and the bool arrays
-    share rows a bit each."""
-    cap = arrays[0].shape[0]
-    rows, flags = [], []
-    for a in arrays:
-        if a.dtype == jnp.bool_:
-            flags.append(a)
-            continue
-        w = jax.lax.bitcast_convert_type(a.astype(_carrier(a.dtype)),
-                                         jnp.uint32).reshape(cap, -1)
-        rows += [w[:, j] for j in range(w.shape[1])]
-    for i in range(0, len(flags), 32):
-        word = jnp.zeros(cap, jnp.uint32)
-        for bit, f in enumerate(flags[i:i + 32]):
-            word = word | (f.astype(jnp.uint32) << bit)
-        rows.append(word)
-    return jnp.stack(rows)
-
-
-def _from_words(words, like):
-    """Inverse of `_to_words`: arrays shaped and typed as `like`."""
-    out, r, flags = [], 0, []
-    for a in like:
-        if a.dtype == jnp.bool_:
-            flags.append(len(out))
-            out.append(None)
-            continue
-        carrier = _carrier(a.dtype)
-        per = carrier.itemsize // 4
-        k = per * (a.size // a.shape[0])
-        w = jnp.stack(list(words[r:r + k]), axis=1)
-        r += k
-        w = w.reshape(a.shape + ((2,) if per == 2 else ()))
-        out.append(jax.lax.bitcast_convert_type(w, carrier).astype(a.dtype))
-    for k, at in enumerate(flags):
-        out[at] = ((words[r + k // 32] >> (k % 32)) & 1).astype(jnp.bool_)
-    return out
-
-
-def _sorted_by_target(eff_pid, arrays):
-    """`arrays` in stable target order. The payload rides the sort: each
-    32-bit word of it is the second operand of the SAME two-operand
-    stable sort by target, one word a turn of a loop, so the program
-    holds one sort to compile however wide the rows are. On a v5e a
-    gather by a sorted index costs 20 ns an element (192 ms for six
-    arrays of 1.5 M rows), these sorts 3.4 ms a word (25 ms); all words
-    as operands of one variadic sort run in 11 ms but take 17 s more to
-    compile for every word (PERF.md, PR 30)."""
-    words = jax.lax.map(
-        lambda w: jax.lax.sort((eff_pid, w), num_keys=1, is_stable=True)[1],
-        _to_words(arrays))
-    return _from_words(words, arrays)
 
 
 def _all_to_all(blocks, axis_name: str):
@@ -170,7 +99,7 @@ def exchange_cvs(cvs: Sequence, mask, pids, n_shards: int,
     from ..ops.kernel_utils import CV
 
     cap = mask.shape[0]
-    eff_pid, starts = _runs_by_target(mask, pids, n_shards)
+    eff_pid, starts = runs_by_target(mask, pids, n_shards)
     strs = [cv for cv in cvs if cv.offsets is not None]
     fixed = [cv.data for cv in cvs if cv.offsets is None]
     # one pass brings every fixed-width array into target order, and for
@@ -178,7 +107,7 @@ def exchange_cvs(cvs: Sequence, mask, pids, n_shards: int,
     payload = [cv.validity for cv in cvs] + fixed
     if strs:
         payload.append(jnp.arange(cap, dtype=jnp.int32))
-    payload = _sorted_by_target(eff_pid, payload)
+    payload = sorted_by_target(eff_pid, payload)
     valids, datas = payload[:len(cvs)], iter(payload[len(cvs):])
     # per string column: the bytes in sorted row order, and where each
     # peer's byte run begins in them

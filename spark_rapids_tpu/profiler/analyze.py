@@ -111,6 +111,12 @@ def render_analyze(tree: dict, metrics_by_lore: Dict[Optional[int], dict],
         # waits, async broadcast overlap, and plan-level reuse hits
         if m.get("mapPoolWaitMs") is not None:
             ann.append(f"mapPoolWaitMs={float(m['mapPoolWaitMs']):.1f}")
+        # how the exchange map ordered its rows: 32-bit words that rode
+        # the sort by target, columns that still went by a gather
+        if m.get("mapSortWords") is not None:
+            ann.append(f"mapSortWords={int(m['mapSortWords'])}")
+            ann.append("mapGatheredColumns="
+                       f"{int(m.get('mapGatheredColumns', 0))}")
         if m.get("broadcastBuildOverlapMs") is not None:
             ann.append("broadcastBuildOverlapMs="
                        f"{float(m['broadcastBuildOverlapMs']):.1f}")

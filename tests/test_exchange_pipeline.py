@@ -443,3 +443,396 @@ def test_map_places_rows_by_partition_ids(key_types):
     for p in range(n):
         assert got_rows[ends[p] - counts[p]:ends[p]].tolist() == \
             np.flatnonzero(mask & (want == p)).tolist()
+
+
+# =====================================================================
+# the map program's contract: stable target order by a sort the payload
+# rides (ops/partition.py), held to a plain numpy reference and, leaf
+# for leaf, to the argsort-and-take body it replaced
+# =====================================================================
+def _argsort_and_take_map(cvs, mask, pids, n):
+    """The map tail as it was before PR 32 (the reference the reduce
+    side's bytes are held to): argsort by target, gather every column
+    by the order, bincount."""
+    import jax.numpy as jnp
+
+    from spark_rapids_tpu.ops.gather import take
+    eff = jnp.where(mask, pids, n)
+    order = jnp.argsort(eff, stable=True)
+    live_sorted = mask[order]
+    counts = jnp.bincount(eff, length=n + 1)[:n]
+    return [take(cv, order, in_bounds=live_sorted) for cv in cvs], counts
+
+
+def _str_cv(strs, bcap=None):
+    """A string CV: bytes packed from the front of a power-of-two
+    buffer. None is a null (no bytes)."""
+    import jax.numpy as jnp
+    d = b"".join(b or b"" for b in strs)
+    bcap = bcap or 1 << max(len(d) - 1, 127).bit_length()
+    buf = np.zeros(bcap, np.uint8)
+    buf[:len(d)] = np.frombuffer(d, np.uint8)
+    off = np.zeros(len(strs) + 1, np.int32)
+    np.cumsum([len(b or b"") for b in strs], out=off[1:])
+    return CV(jnp.asarray(buf),
+              jnp.asarray(np.array([b is not None for b in strs])),
+              jnp.asarray(off))
+
+
+def _fixed_cv(vals, valid=None):
+    import jax.numpy as jnp
+    if valid is None:
+        valid = np.ones(len(vals), np.bool_)
+    return CV(jnp.asarray(vals), jnp.asarray(valid))
+
+
+def _some_strings(rng, n, null_share=0.0, empty_share=0.0):
+    out = []
+    for i in range(n):
+        u = rng.random()
+        if u < null_share:
+            out.append(None)
+        elif u < null_share + empty_share:
+            out.append(b"")
+        else:
+            out.append(f"s{i}-".encode() + b"x" * int(rng.integers(0, 9)))
+    return out
+
+
+def _py_rows(cv, k):
+    """The first k rows of a (nested) CV as plain python values: None
+    for a null, bytes for a string, a list for a list row, a tuple for a
+    struct row, a tuple of limbs for a decimal128, its bits for a float."""
+    valid = np.asarray(cv.validity)
+    if cv.offsets is not None:
+        off = np.asarray(cv.offsets)
+        if cv.children:
+            kids = _py_rows(cv.child, int(off[k]))
+            return [kids[off[r]:off[r + 1]] if valid[r] else None
+                    for r in range(k)]
+        data = np.asarray(cv.data)
+        return [bytes(data[off[r]:off[r + 1]]) if valid[r] else None
+                for r in range(k)]
+    if cv.children:
+        kids = [_py_rows(ch, k) for ch in cv.children]
+        return [tuple(kid[r] for kid in kids) if valid[r] else None
+                for r in range(k)]
+    data = np.asarray(cv.data)
+    if data.dtype.kind == "f":   # by bits: NaN equals itself, -0.0 not 0.0
+        data = data.view(f"i{data.dtype.itemsize}")
+    return [(tuple(data[r].tolist()) if data.ndim > 1 else data[r].item())
+            if valid[r] else None for r in range(k)]
+
+
+def _dead_tail_is_null(cv, k):
+    """Validity is false past the k live rows, at every struct level;
+    variable-width offsets are dense: they stand still past row k and
+    the bytes past the last live one are zero."""
+    assert not np.asarray(cv.validity)[k:].any()
+    if cv.offsets is not None:
+        off = np.asarray(cv.offsets)
+        assert off[0] == 0 and (np.diff(off) >= 0).all()
+        assert (off[k:] == off[k]).all()
+        if not cv.children:
+            assert not np.asarray(cv.data)[off[k]:].any()
+        return
+    for ch in cv.children:
+        _dead_tail_is_null(ch, k)
+
+
+def _map_case(name):
+    """(cvs, mask, pids, n) of one case of the map program's contract."""
+    import jax.numpy as jnp
+    cap, n = 512, 8
+    if name == "capacity_128":
+        cap = 128
+    elif name == "n_200":
+        n = 200
+    rng = np.random.default_rng(sum(map(ord, name)))
+    mask = rng.random(cap) < 0.8
+    pids = rng.integers(0, n, cap)
+    ints = rng.integers(-(1 << 40), 1 << 40, cap).astype(np.int64)
+    nulls = rng.random(cap) < 0.9
+    cvs = [_fixed_cv(ints, nulls)]
+    if name == "int32_date":
+        cvs = [_fixed_cv(rng.integers(-50, 50, cap).astype(np.int32), nulls),
+               _fixed_cv(rng.integers(-9, 9, cap).astype(np.int8)),
+               _fixed_cv(rng.integers(-9, 9, cap).astype(np.int16), nulls)]
+    elif name == "bool":
+        cvs = [_fixed_cv(rng.random(cap) < 0.5, nulls)]
+    elif name == "decimal128_limbs":
+        cvs = [_fixed_cv(rng.integers(-(1 << 62), 1 << 62, (cap, 2))
+                         .astype(np.int64), nulls)]
+    elif name == "mixed_widths":
+        cvs = [_fixed_cv(ints, nulls), _fixed_cv(rng.random(cap) < 0.5),
+               _fixed_cv(rng.integers(0, 9, cap).astype(np.int32)),
+               _fixed_cv(rng.normal(0, 1, cap).astype(np.float32), nulls),
+               _fixed_cv(np.where(rng.random(cap) < 0.1,
+                                  rng.choice([np.nan, -0.0, np.inf], cap),
+                                  rng.normal(0, 1e9, cap)), nulls),
+               _fixed_cv(rng.integers(0, 1 << 62, (cap, 2))
+                         .astype(np.int64))]
+        # 40 validity arrays: the bit-packed flags spill into a second word
+        cvs += [_fixed_cv(rng.integers(0, 9, cap).astype(np.int32),
+                          rng.random(cap) < 0.5) for _ in range(34)]
+    elif name == "strings_nulls_and_empties":
+        cvs = [_fixed_cv(ints, nulls),
+               _str_cv(_some_strings(rng, cap, 0.2, 0.2))]
+    elif name == "two_string_columns":
+        cvs = [_str_cv(_some_strings(rng, cap, 0.1)), _fixed_cv(ints, nulls),
+               _str_cv(_some_strings(rng, cap, 0.0, 0.5))]
+    elif name == "list_column":
+        lens = rng.integers(0, 4, cap)
+        off = np.zeros(cap + 1, np.int32)
+        np.cumsum(lens, out=off[1:])
+        ecap = 2048
+        elems = _fixed_cv(rng.integers(0, 99, ecap).astype(np.int64),
+                          rng.random(ecap) < 0.9)
+        cvs = [CV(jnp.zeros(0, jnp.int8), jnp.asarray(nulls),
+                  jnp.asarray(off), (elems,)), _fixed_cv(ints, nulls)]
+    elif name == "struct_column":
+        inner = CV(jnp.zeros(0, jnp.int8),
+                   jnp.asarray(rng.random(cap) < 0.7), None,
+                   (_fixed_cv(rng.random(cap) < 0.5, nulls),))
+        cvs = [CV(jnp.zeros(0, jnp.int8),
+                  jnp.asarray(rng.random(cap) < 0.8), None,
+                  (_fixed_cv(ints, nulls),
+                   _str_cv(_some_strings(rng, cap, 0.2, 0.1)), inner)),
+               CV(jnp.zeros(0, jnp.int8), jnp.asarray(nulls), None,
+                  (_fixed_cv(rng.integers(0, 9, cap).astype(np.int32)),))]
+    elif name == "every_row_to_one_partition":
+        mask = np.ones(cap, np.bool_)
+        pids = np.full(cap, 3)
+    elif name == "all_rows_dead":
+        mask = np.zeros(cap, np.bool_)
+        cvs.append(_str_cv(_some_strings(rng, cap, 0.1, 0.1)))
+    elif name == "no_row_for_some_partitions":
+        pids = rng.integers(0, 2, cap) * 5
+    else:
+        assert name in ("int64", "capacity_128", "n_200"), name
+    return cvs, mask, pids.astype(np.int32), n
+
+
+def _hold_map_to_contract(out, counts, cvs, mask, pids, n):
+    """Columns in stable order by partition id, the live rows of
+    partition p at [starts[p], starts[p+1]), dead rows after every live
+    row with validity false, counts[n], offsets dense."""
+    want = [i for p in range(n) for i in np.flatnonzero(mask & (pids == p))]
+    k = len(want)
+    counts = np.asarray(counts)
+    assert counts.shape == (n,)
+    assert counts.tolist() == np.bincount(pids[mask], minlength=n).tolist()
+    for cv_in, cv_out in zip(cvs, out):
+        rows = _py_rows(cv_in, mask.shape[0])
+        assert _py_rows(cv_out, k) == [rows[i] for i in want]
+        assert cv_out.validity.shape == cv_in.validity.shape
+        _dead_tail_is_null(cv_out, k)
+
+
+def _same_leaves(got, want):
+    import jax
+    got, want = jax.tree.leaves(got), jax.tree.leaves(want)
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        g, w = np.asarray(g), np.asarray(w)
+        assert g.dtype == w.dtype and g.shape == w.shape
+        assert g.tobytes() == w.tobytes()
+
+
+@pytest.mark.parametrize("name", [
+    "int64", "int32_date", "bool", "decimal128_limbs", "mixed_widths",
+    "strings_nulls_and_empties", "two_string_columns", "list_column",
+    "struct_column", "every_row_to_one_partition", "all_rows_dead",
+    "no_row_for_some_partitions", "capacity_128", "n_200"])
+def test_map_contract(name):
+    """What `_finish_map` promises the reduce side, against a plain
+    numpy reference; and every leaf of its output, dead rows' slots
+    included, equals the argsort-and-take body's byte for byte."""
+    import jax
+    import jax.numpy as jnp
+
+    from spark_rapids_tpu.exec.exchange import _finish_map
+    cvs, mask, pids, n = _map_case(name)
+    args = (cvs, jnp.asarray(mask), jnp.asarray(pids))
+    out, counts = jax.jit(_finish_map, static_argnums=3)(*args, n)
+    _hold_map_to_contract(out, counts, cvs, mask, pids, n)
+    ref, ref_counts = jax.jit(_argsort_and_take_map,
+                              static_argnums=3)(*args, n)
+    _same_leaves(out, ref)
+    assert np.asarray(counts).tolist() == np.asarray(ref_counts).tolist()
+
+
+def test_range_map_contract_null_keys():
+    """The range exchange's map program: null keys go to partition 0,
+    the others where the bounds say, rows in stable order."""
+    import jax.numpy as jnp
+
+    from spark_rapids_tpu.exec.exchange import RangeShuffleExchangeExec
+    from spark_rapids_tpu.expr.expressions import BoundRef
+    cap, n = 512, 4
+    rng = np.random.default_rng(32)
+    mask = rng.random(cap) < 0.85
+    keys = rng.integers(0, 1000, cap).astype(np.int64)
+    kvalid = rng.random(cap) < 0.8
+    cvs = [_fixed_cv(keys, kvalid),
+           _str_cv(_some_strings(rng, cap, 0.1, 0.1))]
+    bounds = np.array([250, 500, 750], np.int64)
+    out, counts = RangeShuffleExchangeExec._build_map_fn(
+        n, [BoundRef(0, dt.INT64)])(cvs, jnp.asarray(mask),
+                                    jnp.asarray(bounds))
+    pids = np.where(kvalid, np.searchsorted(bounds, keys, side="right"), 0)
+    assert (pids[~kvalid] == 0).all() and (~kvalid & mask).any()
+    _hold_map_to_contract(out, counts, cvs, mask, pids.astype(np.int32), n)
+
+
+def _reduce_side_leaves(plan_of):
+    """Every reduce-side batch of every shuffle exchange under a planned
+    query, as host leaves: (rows, mask bytes, leaf bytes...) a batch."""
+    import jax
+
+    from spark_rapids_tpu.exec.base import ExecContext
+    from spark_rapids_tpu.exec.exchange import ShuffleExchangeExec
+    from spark_rapids_tpu.plan.planner import Planner
+    s = _mk_session(**{
+        "spark.rapids.tpu.sql.autoBroadcastJoinThreshold": -1,
+        "spark.rapids.tpu.sql.exec.exchange.reuse.enabled": False})
+    root = Planner(s.conf).plan(plan_of(s)._plan)
+    ctx = ExecContext(s.conf, s)
+    stack, got = [root], []
+    while stack:
+        node = stack.pop()
+        stack.extend(node.children)
+        if not isinstance(node, ShuffleExchangeExec):
+            continue
+        for pid in range(node.n):
+            for b in node.execute_partition(ctx, pid):
+                got.append((b.num_rows, [
+                    (np.asarray(x).dtype.str, np.asarray(x).tobytes())
+                    for x in jax.tree.leaves((b.cvs(), b.row_mask))]))
+        node.release()
+    return got
+
+
+def test_reduce_side_bytes_equal_argsort_and_take(monkeypatch):
+    """A shuffled join over nulls, strings and a decimal128: what the
+    reduce side reads from each exchange equals, byte for byte, what it
+    read when the map ordered rows by argsort and take."""
+    import decimal
+
+    from spark_rapids_tpu.exec import exchange
+    from spark_rapids_tpu.runtime import program_cache
+    at = _mixed_table(900, seed=5).append_column(
+        "d", pa.array([decimal.Decimal(i * 7 - 300) for i in range(900)],
+                      pa.decimal128(25, 0)))
+    right = pa.table({"k": pa.array(list(range(12)) + [None], pa.int64()),
+                      "w": pa.array([f"w{i}" for i in range(13)])})
+
+    def plan_of(s):
+        return s.create_dataframe(at).join(s.create_dataframe(right),
+                                           on="k")
+
+    program_cache.clear()
+    new = _reduce_side_leaves(plan_of)
+    assert len(new) >= 2 and sum(rows for rows, _ in new) > 800
+    monkeypatch.setattr(exchange, "_finish_map", _argsort_and_take_map)
+    program_cache.clear()
+    try:
+        old = _reduce_side_leaves(plan_of)
+    finally:
+        program_cache.clear()
+    assert new == old
+
+
+# ---------------------------------------------------------------------
+# the map program holds one sort and nothing that indexes the batch's
+# rows: the gain of PR 32, held off the chip
+# ---------------------------------------------------------------------
+def _indexed_rows(text, op):
+    """Leading extent of the operand of every `op` (gather / scatter) in
+    a lowered program's StableHLO."""
+    import re
+    return [int(m) for m in re.findall(
+        r'"stablehlo\.%s"\(.*?\}[>)] : \(tensor<(\d+)[x>]' % op, text,
+        flags=re.S)]
+
+
+@pytest.mark.parametrize("mode", ["hash", "roundrobin", "range",
+                                  "with_float64", "with_string"])
+def test_map_program_is_one_sort_and_no_gather_over_rows(mode):
+    """Lower the map program on the CPU and read the StableHLO: exactly
+    one sort (the payload rides it a word a turn of a loop), no scatter
+    and no gather whose operand is as long as the batch. A float64
+    column cannot become words on the chip and rides a second sort of
+    its own type, still ungathered. With a string column the row index
+    rides too and `take_strings`' own gathers and scatter stay (the
+    reader is not blind)."""
+    import jax
+    import jax.numpy as jnp
+
+    from spark_rapids_tpu.exec.exchange import (RangeShuffleExchangeExec,
+                                                ShuffleExchangeExec)
+    from spark_rapids_tpu.expr.expressions import BoundRef
+    cap, n = 4096, 8
+
+    def col(dtype, *tail):
+        return CV(jax.ShapeDtypeStruct((cap,) + tail, dtype),
+                  jax.ShapeDtypeStruct((cap,), jnp.bool_))
+
+    cvs = [col(jnp.int64), col(jnp.int32), col(jnp.int64, 2),
+           col(jnp.bool_), col(jnp.float32)]
+    mask = jax.ShapeDtypeStruct((cap,), jnp.bool_)
+    keys = [BoundRef(0, dt.INT64)]
+    if mode == "range":
+        fn = RangeShuffleExchangeExec._build_map_fn(n, keys)
+        args = (cvs, mask, jax.ShapeDtypeStruct((n - 1,), jnp.int64))
+    else:
+        if mode == "with_float64":
+            cvs += [col(jnp.float64), col(jnp.float64)]
+        if mode == "with_string":
+            cvs.append(CV(jax.ShapeDtypeStruct((8 * cap,), jnp.uint8),
+                          jax.ShapeDtypeStruct((cap,), jnp.bool_),
+                          jax.ShapeDtypeStruct((cap + 1,), jnp.int32)))
+        fn = ShuffleExchangeExec._build_map_fn(
+            n, None if mode == "roundrobin" else keys)
+        args = (cvs, mask)
+    text = jax.jit(fn).lower(*args).as_text()
+    assert text.count('"stablehlo.sort"') == 1 + (mode == "with_float64")
+    gathers = _indexed_rows(text, "gather")
+    scatters = _indexed_rows(text, "scatter")
+    if mode == "with_string":
+        assert scatters and gathers
+    else:
+        assert scatters == [], scatters
+        assert max(gathers, default=0) < cap, gathers
+
+
+def test_map_counts_words_sorted_and_columns_gathered():
+    """`mapSortWords` / `mapGatheredColumns` in `last_metrics()` and in
+    EXPLAIN ANALYZE: three int64 columns are six words and their three
+    validities share a seventh, nothing gathered; a string column adds
+    the row index as a word and is the one column gathered."""
+    s = _mk_session(**{
+        "spark.rapids.tpu.sql.autoBroadcastJoinThreshold": -1})
+    n = 1000
+    fixed = {c: pa.array(np.arange(n, dtype=np.int64) % m)
+             for c, m in (("k", 13), ("a", 7), ("b", 5))}
+
+    def exchange_metrics(q):
+        q.to_arrow()
+        return [m for m in q.last_metrics().values()
+                if "mapSortWords" in m]
+
+    q = s.create_dataframe(fixed).repartition(4, F.col("k"))
+    (m,) = exchange_metrics(q)
+    passes = -(-n // 256)
+    assert m["mapSortWords"] == 7 * passes
+    assert m["mapGatheredColumns"] == 0
+    plan = q.explain("ANALYZE")
+    assert f"mapSortWords={7 * passes}" in plan
+    assert "mapGatheredColumns=0" in plan
+
+    with_s = dict(fixed, s=pa.array([f"s{i % 9}" for i in range(n)]))
+    q = s.create_dataframe(with_s).repartition(4, F.col("k"))
+    (m,) = exchange_metrics(q)
+    assert m["mapSortWords"] == 8 * passes       # + the row index
+    assert m["mapGatheredColumns"] == passes

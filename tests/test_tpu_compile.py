@@ -137,3 +137,30 @@ def test_q3_topk_sort_64bit_keys(session, one_chip):
     from spark_rapids_tpu.ops.kernel_utils import CV
     _compile(lambda c, m: sort_batch_cvs([CV(*x) for x in c], m,
                                          sort.orders, (0, 0)), cvs, mask)
+
+
+def test_exchange_map_every_fixed_width_type(one_chip):
+    """The one-chip exchange map (`ShuffleExchangeExec`, tag 'map') over
+    every fixed-width type a column can hold. The payload rides a sort
+    as 32-bit words, and the chip's compiler has no f64 bitcast (it
+    refused this program while float64 was made words, PR 32): a DOUBLE
+    column rides a sort of its own type. XLA:CPU accepts either form, so
+    only this compile tells them apart."""
+    from spark_rapids_tpu.columnar import dtypes as dt
+    from spark_rapids_tpu.exec.exchange import ShuffleExchangeExec
+    from spark_rapids_tpu.expr.expressions import BoundRef
+    from spark_rapids_tpu.ops.kernel_utils import CV
+    cap = 1 << 13
+
+    def col(dtype, *tail):
+        return CV(jax.ShapeDtypeStruct((cap,) + tail, dtype,
+                                       sharding=one_chip),
+                  jax.ShapeDtypeStruct((cap,), jnp.bool_,
+                                       sharding=one_chip))
+
+    cvs = [col(jnp.int64), col(jnp.float64), col(jnp.float64),
+           col(jnp.int64, 2), col(jnp.float32), col(jnp.int32),
+           col(jnp.int16), col(jnp.int8), col(jnp.bool_)]
+    fn = ShuffleExchangeExec._build_map_fn(8, [BoundRef(0, dt.INT64)])
+    _compile(fn, cvs, jax.ShapeDtypeStruct((cap,), jnp.bool_,
+                                           sharding=one_chip))
