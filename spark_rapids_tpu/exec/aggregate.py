@@ -3,10 +3,12 @@
 Reference algorithm (GpuAggregateExec.scala:863-894): first-pass per-batch
 aggregation, then merge passes until one batch remains. TPU-first redesign:
 grouping is *sort-based segmented reduction* — radix-normalized keys,
-stable lexsort, boundary flags -> segment ids, jax.ops.segment_* reductions
-— all static-shape and fused into one XLA program per pass, instead of
-cudf's dynamic hash tables. Capacity stays constant through a pass; dead
-(filtered/padding) rows sort to the back as their own segments and are
+stable lexsort, keys and aggregate inputs riding one sort into key order,
+boundary flags -> runs, segmented scans over the runs (`ops/groups.py`;
+no row is fetched by index and no group reduced by scatter) — all
+static-shape and fused into one XLA program per pass, instead of cudf's
+dynamic hash tables. Capacity stays constant through a pass; dead
+(filtered/padding) rows sort to the back as their own runs and are
 masked out of the output.
 """
 from __future__ import annotations
@@ -24,7 +26,9 @@ from ..expr.expressions import EmitCtx, Expression, UnsupportedExpr
 from ..ops import sortkeys as sk
 from ..ops.concat import concat_cvs, concat_masks, pad_cv, pad_mask
 from ..ops.gather import take, take_strings
+from ..ops.groups import RunGroups, ScatterGroups
 from ..ops.kernel_utils import CV
+from ..ops.partition import sorted_by_target, word_count
 from ..profiler import xla_stats
 from ..utils.transfer import fetch_int
 from .base import ExecContext, TpuExec
@@ -252,10 +256,6 @@ def _pad_one_row(outs):
     return cvs
 
 
-def _gather_raw(arr, perm):
-    return arr[perm]
-
-
 def _seg_ident(kind: str, dtype):
     if jnp.issubdtype(dtype, jnp.floating):
         return jnp.inf if kind == "min" else -jnp.inf
@@ -264,20 +264,23 @@ def _seg_ident(kind: str, dtype):
     return jnp.iinfo(dtype).max if kind == "min" else jnp.iinfo(dtype).min
 
 
-def _seg_reduce(reducer: str, arr, live, seg_ids, num_segments):
+def _seg_reduce(reducer: str, arr, live, groups):
     if reducer == "sum":
-        x = jnp.where(live, arr, jnp.zeros_like(arr))
-        return jax.ops.segment_sum(x, seg_ids, num_segments)
+        return groups.sum(jnp.where(live, arr, jnp.zeros_like(arr)))
     if reducer == "or":
-        x = (live & arr.astype(jnp.bool_)).astype(jnp.int32)
-        return jax.ops.segment_max(x, seg_ids, num_segments) > 0
+        return groups.any(live & arr.astype(jnp.bool_))
     if reducer == "min":
-        x = jnp.where(live, arr, _seg_ident("min", arr.dtype))
-        return jax.ops.segment_min(x, seg_ids, num_segments)
+        return groups.min(jnp.where(live, arr, _seg_ident("min", arr.dtype)))
     if reducer == "max":
-        x = jnp.where(live, arr, _seg_ident("max", arr.dtype))
-        return jax.ops.segment_max(x, seg_ids, num_segments)
+        return groups.max(jnp.where(live, arr, _seg_ident("max", arr.dtype)))
     raise ValueError(reducer)
+
+
+def _col_struct(dtype: dt.DataType, cap: int = 1):
+    """Shape and dtype of a fixed-width column's data array."""
+    shape = (cap, 2) if (isinstance(dtype, dt.DecimalType)
+                         and dtype.is_decimal128) else (cap,)
+    return jax.ShapeDtypeStruct(shape, dtype.np_dtype or jnp.int8)
 
 
 _NP2DT = None
@@ -407,27 +410,29 @@ class HashAggregateExec(TpuExec):
         self._hash_disabled = False
 
     # -- partial-state wire schema --------------------------------------
-    def _state_np_dtypes(self):
-        """Infer the flat state array dtypes via abstract evaluation."""
-        shapes = []
-        for a in self.aggs:
+    def _state_structs(self):
+        """One list an aggregate of the flat state arrays' shapes and
+        dtypes at capacity 128, via abstract evaluation."""
+        if getattr(self, "_state_structs_c", None) is None:
             cap = 128
-            shape = (cap,)
-            if a.child is not None:
-                np_dt = a.child.dtype.np_dtype or jnp.int8
-                if isinstance(a.child.dtype, dt.DecimalType) \
-                        and a.child.dtype.is_decimal128:
-                    shape = (cap, 2)
-            else:
-                np_dt = jnp.int8
-            cv = jax.ShapeDtypeStruct(shape, np_dt)
-            vcv = jax.ShapeDtypeStruct((cap,), jnp.bool_)
-            seg = jax.ShapeDtypeStruct((cap,), jnp.int32)
-            out = jax.eval_shape(
-                lambda c, v, s: a.g_update(CV(c, v), v, s, cap),
-                cv, vcv, seg)
-            shapes.extend([o.dtype for o in out])
-        return shapes
+            out = []
+            for a in self.aggs:
+                cdt = a.child.dtype if a.child is not None else None
+                cv = (_col_struct(cdt, cap) if cdt is not None
+                      and not cdt.is_variable_width
+                      else jax.ShapeDtypeStruct((cap,), jnp.int8))
+                vcv = jax.ShapeDtypeStruct((cap,), jnp.bool_)
+                seg = jax.ShapeDtypeStruct((cap,), jnp.int32)
+                out.append(list(jax.eval_shape(
+                    lambda c, v, s: a.g_update(CV(c, v), v,
+                                               ScatterGroups(s, cap)),
+                    cv, vcv, seg)))
+            self._state_structs_c = out
+        return self._state_structs_c
+
+    def _state_np_dtypes(self):
+        """The flat state array dtypes."""
+        return [o.dtype for st in self._state_structs() for o in st]
 
     def _partial_schema(self, child_schema: Schema) -> Schema:
         from ..columnar.table import Field
@@ -529,24 +534,125 @@ class HashAggregateExec(TpuExec):
                 f"aggs={self.agg_names}{fused}]")
 
     # -- sort/segment machinery (runs inside jit) ----------------------
-    def _sort_and_segment(self, key_cvs, mask, nchunks):
-        cap = mask.shape[0]
-        arrays = [jnp.logical_not(mask).astype(jnp.uint8)]  # dead rows last
+    @staticmethod
+    def _custom(a) -> bool:
+        """Does `a` reduce by its own scatters (`g_merge_custom`)?"""
+        return "custom" in a.state_reducers
+
+    def _reduce_runs(self, key_cvs, mask, nchunks, payload, reduce):
+        """THE body of the sort-segmented aggregate, for update and merge
+        alike: `(key_out, flat_states, seg_live)` at the input's
+        capacity, group k at slot k, live groups first.
+
+        No row is fetched by index and no group is reduced by scatter.
+        The key order comes by riding (`sortkeys.lexsort_riding`), the
+        permutation becomes a rank by one two-operand sort, and the row
+        mask, every fixed-width key column and `payload` (the aggregate
+        inputs, or the partial state columns) ride ONE sort by that rank
+        as 32-bit words (`ops/partition.py`). A group is then a run:
+        `reduce(groups, live, payload)` returns one tuple of state
+        columns an aggregate, which `RunGroups` scans; the runs' results
+        and keys, standing at each run's last row, go to slot k by one
+        more ride. A var-width
+        key cannot ride: its chunk words do (for the boundaries), and
+        `take` gathers that one column by the permutation. A custom
+        reducer still scatters by run number, over sorted inputs
+        (`aggScatteredColumns` counts both)."""
+        rows = jnp.arange(mask.shape[0], dtype=jnp.int32)
+        order = [jnp.logical_not(mask).astype(jnp.uint8)]  # dead rows last
+        riders = [mask]
         for kcv, kexpr, nc in zip(key_cvs, self.keys, nchunks):
-            arrays.append(jnp.logical_not(kcv.validity).astype(jnp.uint8))
-            arrays.extend(sk.order_keys(kcv, kexpr.dtype, nc))
-        perm = sk.lexsort(arrays)
-        sorted_arrays = [a[perm] for a in arrays]
-        boundary = sk.group_boundaries(sorted_arrays)
-        seg_ids = jnp.cumsum(boundary.astype(jnp.int32)) - 1
-        live_sorted = mask[perm]
-        seg_live = jax.ops.segment_max(live_sorted.astype(jnp.int32),
-                                       seg_ids, cap) > 0
-        seg_start = jax.ops.segment_min(jnp.arange(cap), seg_ids, cap)
-        src_rows = perm[jnp.clip(seg_start, 0, cap - 1)]
-        key_out = [take(kcv, src_rows, in_bounds=seg_live)
-                   for kcv in key_cvs]
-        return perm, seg_ids, live_sorted, seg_live, key_out
+            words = sk.order_keys(kcv, kexpr.dtype, nc)
+            order.append(jnp.logical_not(kcv.validity).astype(jnp.uint8))
+            order.extend(words)
+            riders.append(kcv.validity)
+            riders.extend([kcv.data] if kcv.offsets is None else words)
+        perm = sk.lexsort_riding(order)
+        rank = jax.lax.sort((perm, rows), num_keys=1)[1]
+        nk = len(riders)
+        rode = sorted_by_target(rank, riders + list(payload))
+        it = iter(rode[:nk])
+        live = next(it)
+        order = [jnp.logical_not(live).astype(jnp.uint8)]
+        keys_rode = []            # (validity, data) a key; data None: var
+        for kcv, kexpr, nc in zip(key_cvs, self.keys, nchunks):
+            valid = next(it)
+            if kcv.offsets is None:
+                data = next(it)
+                words = sk.order_keys(CV(data, valid), kexpr.dtype)
+            else:
+                data, words = None, [next(it) for _ in range(nc)]
+            order.append(jnp.logical_not(valid).astype(jnp.uint8))
+            order.extend(words)
+            keys_rode.append((valid, data))
+        groups = RunGroups(order, live)
+        states = reduce(groups, live, rode[nk:])
+        to_slots = [x for vd in keys_rode if vd[1] is not None for x in vd]
+        if any(data is None for _, data in keys_rode):
+            to_slots.append(perm)
+        nk = len(to_slots)
+        to_slots += [c for a, st in zip(self.aggs, states)
+                     if not self._custom(a) for c in st]
+        placed = groups.slots(to_slots)
+        it = iter(placed)
+        key_out = []
+        for kcv, (_, data) in zip(key_cvs, keys_rode):
+            if data is None:
+                key_out.append(take(kcv, placed[nk - 1],
+                                    in_bounds=groups.slot_live))
+            else:
+                valid = next(it)
+                key_out.append(CV(next(it), valid))
+        it = iter(placed[nk:])
+        flat = []
+        for a, st in zip(self.aggs, states):
+            flat.extend(st if self._custom(a) else [next(it) for _ in st])
+        return key_out, flat, groups.slot_live
+
+    def _ride_shape(self, nchunks, payload):
+        """(words, scattered) of one launch of `_reduce_runs` whose
+        payload has these shapes and dtypes: the 32-bit words of a row
+        that rode a sort (into key order, then to the slots:
+        `aggSortWords`), and the state columns still reduced by scatter
+        plus the key columns still gathered (`aggScatteredColumns`).
+        Reads shapes and dtypes only."""
+        flag = jax.ShapeDtypeStruct((1,), jnp.bool_)
+        word = jax.ShapeDtypeStruct((1,), jnp.int32)
+        var = [isinstance(k.dtype, (dt.StringType, dt.BinaryType))
+               for k in self.keys]
+        ordered, placed = [flag], []
+        for k, nc, v in zip(self.keys, nchunks, var):
+            ordered += [flag] + ([word] * nc if v
+                                 else [_col_struct(k.dtype)])
+            placed += [] if v else [flag, _col_struct(k.dtype)]
+        placed += [word] * any(var)
+        scattered = sum(var)
+        for a, st in zip(self.aggs, self._state_structs()):
+            if self._custom(a):
+                scattered += len(st)
+            else:
+                placed += [jax.ShapeDtypeStruct((1,) + o.shape[1:], o.dtype)
+                           for o in st]
+        return word_count(ordered + list(payload)) + word_count(placed), \
+            scattered
+
+    def _count_ride(self, m, nchunks, payload):
+        words, scattered = self._ride_shape(nchunks, payload)
+        m.add("aggSortWords", words)
+        m.add("aggScatteredColumns", scattered)
+
+    def _update_payload(self):
+        """Shapes and dtypes of what `_update_fn` lets ride: data and
+        validity of each aggregate's input (validity alone where the
+        input is var-width, nothing for count(*))."""
+        out = []
+        for a in self.aggs:
+            if a.child is None:
+                continue
+            if not a.child.dtype.is_variable_width:
+                out.append(_col_struct(a.child.dtype))
+            out.append(jax.ShapeDtypeStruct((1,), jnp.bool_))
+        return out
 
     def _hash_update_fn(self, nchunks, hash_once: bool = False):
         """Sort-free first pass: bucket rows by key hash, verify each row's
@@ -608,12 +714,13 @@ class HashAggregateExec(TpuExec):
                     for arr in arrs:
                         match = match & (arr == arr[rep_of_row])
                 states_r = []
+                buckets = ScatterGroups(b, B)
                 for a, icv in zip(self.aggs, agg_inputs):
                     if icv.offsets is not None:
                         scv = CV(jnp.zeros(cap, jnp.int8), icv.validity)
                     else:
                         scv = icv
-                    states_r.append(a.g_update(scv, match, b, B))
+                    states_r.append(a.g_update(scv, match, buckets))
                 flat_r = [c for s in states_r for c in s]
                 round_states = ([[f] for f in flat_r] if round_states is None
                                 else [o + [f] for o, f in
@@ -671,50 +778,56 @@ class HashAggregateExec(TpuExec):
             cap = mask.shape[0]
             ctx = EmitCtx(cvs, cap)
             key_cvs = [k.emit(ctx) for k in self.keys]
-            perm, seg_ids, live, seg_live, key_out = \
-                self._sort_and_segment(key_cvs, mask, nchunks)
-            states = []
+            payload = []
             for a in self.aggs:
-                if a.child is not None:
-                    cv = a.child.emit(ctx)
-                else:
-                    cv = CV(jnp.zeros(cap, jnp.int8),
-                            jnp.ones(cap, jnp.bool_))
-                if cv.offsets is not None:  # var-width: Count uses validity
-                    scv = CV(jnp.zeros(cap, jnp.int8), cv.validity[perm])
-                else:
-                    scv = CV(cv.data[perm], cv.validity[perm])
-                states.append(a.g_update(scv, live, seg_ids, cap))
-            flat = [c for s in states for c in s]
-            return key_out, flat, seg_live
+                if a.child is None:
+                    continue
+                cv = a.child.emit(ctx)
+                if cv.offsets is None:  # var-width: Count uses validity
+                    payload.append(cv.data)
+                payload.append(cv.validity)
+
+            def reduce(groups, live, rode):
+                it = iter(rode)
+                states = []
+                for a in self.aggs:
+                    if a.child is None:
+                        scv = CV(jnp.zeros(cap, jnp.int8),
+                                 jnp.ones(cap, jnp.bool_))
+                    elif a.child.dtype.is_variable_width:
+                        scv = CV(jnp.zeros(cap, jnp.int8), next(it))
+                    else:
+                        scv = CV(next(it), next(it))
+                    states.append(a.g_update(scv, live, groups))
+                return states
+            return self._reduce_runs(key_cvs, mask, nchunks, payload,
+                                     reduce)
         return fn
 
-    def _merge_fn(self, nchunks):
-        def fn(key_cvs, flat_states, mask):
-            cap = mask.shape[0]
-            perm, seg_ids, live, seg_live, key_out = \
-                self._sort_and_segment(key_cvs, mask, nchunks)
-            out_flat = []
+    def _merge_body(self, key_cvs, flat_states, mask, nchunks):
+        """Merge partials (in trace; `_merge_partials` jits it as it
+        is): the state columns ride into key order and each reduces by
+        its `state_reducers`; live groups come out first."""
+        def reduce(groups, live, rode):
+            states = []
             i = 0
             for a in self.aggs:
-                width = self._state_width(a)
-                if "custom" in a.state_reducers:
-                    cols = [flat_states[i + j][perm] for j in range(width)]
-                    out_flat.extend(a.g_merge_custom(cols, live, seg_ids,
-                                                     cap))
-                    i += width
+                cols = rode[i:i + self._state_width(a)]
+                i += len(cols)
+                if self._custom(a):
+                    states.append(tuple(a.g_merge_custom(cols, live,
+                                                         groups)))
                 else:
-                    for r in a.state_reducers:
-                        arr = flat_states[i][perm]
-                        out_flat.append(_seg_reduce(r, arr, live, seg_ids,
-                                                    cap))
-                        i += 1
-            return key_out, out_flat, seg_live
-        return fn
+                    states.append(tuple(
+                        _seg_reduce(r, c, live, groups)
+                        for r, c in zip(a.state_reducers, cols)))
+            return states
+        return self._reduce_runs(key_cvs, mask, nchunks, flat_states,
+                                 reduce)
 
     @staticmethod
     def _state_width(a) -> int:
-        if "custom" in a.state_reducers:
+        if HashAggregateExec._custom(a):
             return a.num_state_cols()
         return len(a.state_reducers)
 
@@ -868,10 +981,11 @@ class HashAggregateExec(TpuExec):
                     for arr in arrs:
                         match = match & (arr == arr[rep_of_row])
                 states_r = []
+                buckets = ScatterGroups(b, B)
                 for a, icv in zip(self.aggs, agg_inputs):
                     scv = (CV(jnp.zeros(cap, jnp.int8), icv.validity)
                            if icv.offsets is not None else icv)
-                    states_r.append(a.g_update(scv, match, b, B))
+                    states_r.append(a.g_update(scv, match, buckets))
                 flat_r = [c for st_ in states_r for c in st_]
                 round_states = ([[f] for f in flat_r]
                                 if round_states is None
@@ -917,30 +1031,6 @@ class HashAggregateExec(TpuExec):
             outs = self._finalize_fn(ks_c, flat_c, sl_c)
             return outs, sl_c, count, overflow
         return run
-
-    def _merge_body(self, key_cvs, flat_states, mask, nchunks):
-        """In-trace merge (the body of _merge_fn without the jit
-        boundary): sort-segment the partial keys, reduce states; live
-        groups come out first."""
-        cap = mask.shape[0]
-        perm, seg_ids, live, seg_live, key_out = \
-            self._sort_and_segment(key_cvs, mask, nchunks)
-        out_flat = []
-        i = 0
-        for a in self.aggs:
-            width = self._state_width(a)
-            if "custom" in a.state_reducers:
-                cols = [flat_states[i + j][perm] for j in range(width)]
-                out_flat.extend(a.g_merge_custom(cols, live, seg_ids,
-                                                 cap))
-                i += width
-            else:
-                for r in a.state_reducers:
-                    arr = flat_states[i][perm]
-                    out_flat.append(_seg_reduce(r, arr, live, seg_ids,
-                                                cap))
-                    i += 1
-        return key_out, out_flat, seg_live
 
     def _try_whole_input(self, ctx, m):
         """Single-round-trip path: cached child, bounded batch count, no
@@ -997,7 +1087,7 @@ class HashAggregateExec(TpuExec):
         them). Fixed-width keys and traceable reducers only; anything
         else keeps the partition path."""
         if (self.mode != "partial" or self._has_string_keys()
-                or any("custom" in a.state_reducers for a in self.aggs)):
+                or any(self._custom(a) for a in self.aggs)):
             return None
         self._resolve_fusion()
         from .lockstep import mesh_batches
@@ -1018,6 +1108,7 @@ class HashAggregateExec(TpuExec):
                 with m.timer("opTime"):
                     outs = prog(mb.trees())
                 xla_stats.count_dispatch()
+                self._count_ride(m, nchunks, self._update_payload())
                 shards = []
                 for b, (ks, st, sl) in zip(mb.shards, outs):
                     cvs = list(ks) + [CV(a, sl) for a in st]
@@ -1085,6 +1176,7 @@ class HashAggregateExec(TpuExec):
                 self._update_cache[nchunks] = fn
             ks, st, sl = fn(b.cvs(), b.row_mask)
             xla_stats.count_dispatch()
+            self._count_ride(m, nchunks, self._update_payload())
             return (ks, st, sl, b.capacity)
 
         from ..config import AGG_MAX_MERGE_ROWS
@@ -1107,7 +1199,7 @@ class HashAggregateExec(TpuExec):
                 if compactable and buffered > max_rows and len(handles) > 1:
                     with m.timer("opTime"):
                         parts = [self._unpark(h) for h, _ in handles]
-                        merged = self._merge_partials(parts)
+                        merged = self._merge_partials(parts, m)
                         handles = [(self._park(store, merged), merged[3])]
                         buffered = merged[3]
                         if merged[3] > max_rows // 2:
@@ -1156,7 +1248,7 @@ class HashAggregateExec(TpuExec):
                         or (not emit_partial and parts[0][3] > 4096)):
                     # the merge pass also sorts live groups first and
                     # compacts the output to the group count
-                    part = self._merge_partials(parts)
+                    part = self._merge_partials(parts, m)
                 else:
                     part = parts[0]
                 out = self._emit_batch(part, m, emit_partial)
@@ -1199,7 +1291,7 @@ class HashAggregateExec(TpuExec):
                                for p in parts_b]
                         m.add("numBucketRecursions", 1)
                     else:
-                        part = self._merge_partials(parts_b)
+                        part = self._merge_partials(parts_b, m)
                         out = self._emit_batch(part, m, emit_partial)
                 if sub is not None:
                     yield from self._emit_final(
@@ -1243,7 +1335,7 @@ class HashAggregateExec(TpuExec):
             return
         yield from self._emit_final(ctx, m, handles, force_merge=True)
 
-    def _merge_partials(self, partials):
+    def _merge_partials(self, partials, m=None):
         if len(partials) == 1:
             ks, st, sl, cap = partials[0]
         else:
@@ -1262,11 +1354,14 @@ class HashAggregateExec(TpuExec):
         if fn is None:
             from ..runtime.program_cache import cached_program
             fn = cached_program(
-                self._merge_fn(nchunks), cls="HashAggregateExec",
-                tag="merge", key=self._fp + (nchunks,))
+                lambda ks, st, sl: self._merge_body(ks, st, sl, nchunks),
+                cls="HashAggregateExec", tag="merge",
+                key=self._fp + (nchunks,))
             self._merge_cache[nchunks] = fn
         ks2, st2, sl2 = fn(ks, st, sl)
         xla_stats.count_dispatch()
+        if m is not None:
+            self._count_ride(m, nchunks, st)
         return self._compact_partial(ks2, st2, sl2)
 
     def _compact_partial(self, ks, st, sl):
@@ -1399,7 +1494,8 @@ class CollectAggExec(TpuExec):
                                  cv.validity[perm])
                     else:
                         scv = CV(cv.data[perm], cv.validity[perm])
-                    st = a.g_update(scv, live, seg_ids, cap)
+                    st = a.g_update(scv, live,
+                                    ScatterGroups(seg_ids, cap))
                     v, okv = a.finalize(st)
                     if isinstance(v, CV):
                         outs.append(CV(v.data, v.validity & okv & seg_live,

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from ..columnar import dtypes as dt
+from ..ops.groups import ScatterGroups
 from ..ops.kernel_utils import CV
 from .expressions import (Cast, Expression, Literal, UnsupportedExpr)
 
@@ -126,16 +127,14 @@ class Sum(AggExpr):
         return s[0], s[1]
 
     # --- grouped: per-segment ----
-    def g_update(self, cv: CV, mask, seg_ids, num_segments):
+    def g_update(self, cv: CV, mask, groups):
         m = mask & cv.validity
-        has = jax.ops.segment_max(m.astype(jnp.int32), seg_ids,
-                                  num_segments) > 0
+        has = groups.any(m)
         if self._d128:
             limbs = self._limbs(cv, m)
-            return tuple(jax.ops.segment_sum(l, seg_ids, num_segments)
-                         for l in limbs) + (has,)
+            return tuple(groups.sum(l) for l in limbs) + (has,)
         x = jnp.where(m, cv.data, 0).astype(self._acc_dtype)
-        return (jax.ops.segment_sum(x, seg_ids, num_segments), has)
+        return (groups.sum(x), has)
 
 
 class Count(AggExpr):
@@ -153,9 +152,8 @@ class Count(AggExpr):
     def finalize(self, s):
         return s[0], jnp.bool_(True)
 
-    def g_update(self, cv, mask, seg_ids, num_segments):
-        m = (mask & cv.validity).astype(jnp.int64)
-        return (jax.ops.segment_sum(m, seg_ids, num_segments),)
+    def g_update(self, cv, mask, groups):
+        return (groups.sum((mask & cv.validity).astype(jnp.int64)),)
 
 
 class CountStar(AggExpr):
@@ -181,9 +179,8 @@ class CountStar(AggExpr):
     def finalize(self, s):
         return s[0], jnp.bool_(True)
 
-    def g_update(self, cv, mask, seg_ids, num_segments):
-        return (jax.ops.segment_sum(mask.astype(jnp.int64), seg_ids,
-                                    num_segments),)
+    def g_update(self, cv, mask, groups):
+        return (groups.sum(mask.astype(jnp.int64)),)
 
     def __repr__(self):
         return "count(*)"
@@ -275,9 +272,12 @@ class _MinMax(AggExpr):
             return _d128_unsortable(s[0], s[1]), s[2]
         return s[0], s[1]
 
-    def g_update(self, cv, mask, seg_ids, num_segments):
+    def g_update(self, cv, mask, groups):
         m = mask & cv.validity
         if getattr(self, "_d128_in", False):
+            # custom: the low limb's candidates read the winning high
+            # limb back by id, so this one scatters in either layout
+            seg_ids, num_segments = groups.seg_ids, groups.num_segments
             hi, lo = self._d128_masked(cv, m)
             seg = (jax.ops.segment_min if self.for_min
                    else jax.ops.segment_max)
@@ -289,12 +289,11 @@ class _MinMax(AggExpr):
                                       num_segments) > 0
             return (red_hi, red_lo, has)
         x = self._masked(cv, m)
-        seg = (jax.ops.segment_min if self.for_min else jax.ops.segment_max)
-        return (seg(x, seg_ids, num_segments),
-                jax.ops.segment_max(m.astype(jnp.int32), seg_ids,
-                                    num_segments) > 0)
+        red = groups.min(x) if self.for_min else groups.max(x)
+        return (red, groups.any(m))
 
-    def g_merge_custom(self, cols_sorted, live, seg_ids, num_segments):
+    def g_merge_custom(self, cols_sorted, live, groups):
+        seg_ids, num_segments = groups.seg_ids, groups.num_segments
         hi, lo, has = cols_sorted
         eligible = live & has.astype(jnp.bool_)
         ident = _ident(jnp.dtype(jnp.int64), self.for_min)
@@ -372,12 +371,10 @@ class Avg(AggExpr):
             return q, valid
         return total.astype(jnp.float64) / safe, valid
 
-    def g_update(self, cv, mask, seg_ids, num_segments):
+    def g_update(self, cv, mask, groups):
         m = mask & cv.validity
-        x = self._acc(cv, m)
-        return (jax.ops.segment_sum(x, seg_ids, num_segments),
-                jax.ops.segment_sum(m.astype(jnp.int64), seg_ids,
-                                    num_segments))
+        return (groups.sum(self._acc(cv, m)),
+                groups.sum(m.astype(jnp.int64)))
 
 
 def _seg_extreme_pos(eligible, seg_ids, num_segments, take_first: bool):
@@ -446,14 +443,16 @@ class _FirstLast(AggExpr):
     def num_state_cols(self):
         return 3
 
-    def g_update(self, cv, mask, seg_ids, num_segments):
+    def g_update(self, cv, mask, groups):
+        seg_ids, num_segments = groups.seg_ids, groups.num_segments
         m = mask & (cv.validity if self.ignore_nulls else
                     jnp.ones_like(cv.validity))
         safe, has = _seg_extreme_pos(m, seg_ids, num_segments,
                                      self.take_first)
         return (cv.data[safe], cv.validity[safe] & has, has)
 
-    def g_merge_custom(self, cols_sorted, live, seg_ids, num_segments):
+    def g_merge_custom(self, cols_sorted, live, groups):
+        seg_ids, num_segments = groups.seg_ids, groups.num_segments
         val, valid, has = cols_sorted
         eligible = live & has.astype(jnp.bool_)
         safe, found = _seg_extreme_pos(eligible, seg_ids, num_segments,
@@ -529,7 +528,8 @@ class Variance(AggExpr):
         return var
 
     # ---- grouped ------------------------------------------------------
-    def g_update(self, cv, mask, seg_ids, num_segments):
+    def g_update(self, cv, mask, groups):
+        seg_ids, num_segments = groups.seg_ids, groups.num_segments
         m = mask & cv.validity
         x = self._xs(cv, m)
         n = jax.ops.segment_sum(m.astype(jnp.int64), seg_ids, num_segments)
@@ -539,7 +539,8 @@ class Variance(AggExpr):
         m2 = jax.ops.segment_sum(d * d, seg_ids, num_segments)
         return (n, mean, m2)
 
-    def g_merge_custom(self, cols_sorted, live, seg_ids, num_segments):
+    def g_merge_custom(self, cols_sorted, live, groups):
+        seg_ids, num_segments = groups.seg_ids, groups.num_segments
         """Chan's parallel combine across partial states of one segment:
         Mean = sum(n_i mean_i)/N; M2 = sum(M2_i) + sum(n_i (mean_i-Mean)^2).
         Differences of means stay small, so no cancellation."""
@@ -742,7 +743,8 @@ class ApproxCountDistinct(AggExpr):
         return bytes_.reshape(n, -1).astype(jnp.int32)
 
     # -- grouped --------------------------------------------------------
-    def g_update(self, cv: CV, mask, seg_ids, num_segments):
+    def g_update(self, cv: CV, mask, groups):
+        seg_ids, num_segments = groups.seg_ids, groups.num_segments
         idx, rho = self._idx_rho(cv, mask)
         # combined (segment, register) key -> one segment_max over
         # num_segments * m slots. Memory is O(cap + num_segments * m);
@@ -756,7 +758,8 @@ class ApproxCountDistinct(AggExpr):
         words = self._pack(regs.reshape(num_segments, self.m))
         return tuple(words)
 
-    def g_merge_custom(self, cols_sorted, live, seg_ids, num_segments):
+    def g_merge_custom(self, cols_sorted, live, groups):
+        seg_ids, num_segments = groups.seg_ids, groups.num_segments
         regs = self._unpack(list(cols_sorted))    # (cap, m)
         regs = jnp.where(live[:, None], regs, 0)
         merged = jax.ops.segment_max(regs, seg_ids, num_segments)
@@ -768,7 +771,7 @@ class ApproxCountDistinct(AggExpr):
     # the compiled arg count).
     def update(self, cv: CV, mask):
         zeros = jnp.zeros(mask.shape[0], jnp.int32)
-        words = self.g_update(cv, mask, zeros, 1)
+        words = self.g_update(cv, mask, ScatterGroups(zeros, 1))
         return (jnp.stack([w[0] for w in words]),)
 
     def merge(self, s1, s2):
@@ -995,7 +998,8 @@ class ApproxPercentile(Percentile):
         return p[jnp.argsort(major[p], stable=True)]
 
     # -- grouped --------------------------------------------------------
-    def g_update(self, cv: CV, mask, seg_ids, num_segments):
+    def g_update(self, cv: CV, mask, groups):
+        seg_ids, num_segments = groups.seg_ids, groups.num_segments
         C = self.C
         cap = mask.shape[0]
         valid = mask & cv.validity
@@ -1034,7 +1038,8 @@ class ApproxPercentile(Percentile):
         return (tuple(mm[:, i] for i in range(C))
                 + tuple(wm[:, i] for i in range(C)) + (vmin, vmax))
 
-    def g_merge_custom(self, cols_sorted, live, seg_ids, num_segments):
+    def g_merge_custom(self, cols_sorted, live, groups):
+        seg_ids, num_segments = groups.seg_ids, groups.num_segments
         C = self.C
         means = jnp.stack(cols_sorted[:C], axis=1)          # (cap, C)
         ws = jnp.stack(cols_sorted[C:2 * C], axis=1)
@@ -1082,7 +1087,7 @@ class ApproxPercentile(Percentile):
     # State: (means (C,), weights (C,), minmax (2,)) — three vectors.
     def update(self, cv: CV, mask):
         zeros = jnp.zeros(mask.shape[0], jnp.int32)
-        cols = self.g_update(cv, mask, zeros, 1)
+        cols = self.g_update(cv, mask, ScatterGroups(zeros, 1))
         C = self.C
         return (jnp.stack([c[0] for c in cols[:C]]),
                 jnp.stack([c[0] for c in cols[C:2 * C]]),

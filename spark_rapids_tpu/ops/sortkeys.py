@@ -27,9 +27,10 @@ import jax.numpy as jnp
 
 from ..columnar import dtypes as dt
 from .kernel_utils import CV
+from .partition import sorted_by_target
 
-__all__ = ["order_keys", "string_chunk_keys", "lexsort", "group_boundaries",
-           "nchunks_for_len"]
+__all__ = ["order_keys", "string_chunk_keys", "lexsort", "lexsort_riding",
+           "group_boundaries", "nchunks_for_len"]
 
 
 def nchunks_for_len(maxlen: int) -> int:
@@ -180,6 +181,22 @@ def _lexsort_lsd32(keys: Sequence[jnp.ndarray], iota) -> jnp.ndarray:
     for i, w in enumerate(reversed(_words32(keys))):
         _, perm = jax.lax.sort([w if i == 0 else w[perm], perm],
                                num_keys=1, is_stable=True)
+    return perm
+
+
+def lexsort_riding(keys: Sequence[jnp.ndarray]) -> jnp.ndarray:
+    """`lexsort`'s permutation with no row fetched by index, the same
+    program on every backend: one stable sort per 32-bit word of the
+    keys, least significant first, and the words not yet used ride
+    each pass beside the permutation (`ops/partition.py`) instead of
+    being gathered by it. K words cost K(K+1)/2 word-sorts and K sorts
+    to compile. On a v5e, 1 Mi rows and three words: 10.6 ms against
+    20.2 ms for `lexsort`'s chain and its two gathers (PERF.md, PR 36)."""
+    words = list(_words32(keys))
+    perm = jnp.arange(keys[0].shape[0], dtype=jnp.int32)
+    while words:
+        key = words.pop()
+        *words, perm = sorted_by_target(key, words + [perm])
     return perm
 
 

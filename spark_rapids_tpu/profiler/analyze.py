@@ -117,6 +117,13 @@ def render_analyze(tree: dict, metrics_by_lore: Dict[Optional[int], dict],
             ann.append(f"mapSortWords={int(m['mapSortWords'])}")
             ann.append("mapGatheredColumns="
                        f"{int(m.get('mapGatheredColumns', 0))}")
+        # how the sort-segmented aggregate reduced: 32-bit words of a
+        # row that rode its sorts, state and key columns that still went
+        # by scatter or gather
+        if m.get("aggSortWords") is not None:
+            ann.append(f"aggSortWords={int(m['aggSortWords'])}")
+            ann.append("aggScatteredColumns="
+                       f"{int(m.get('aggScatteredColumns', 0))}")
         if m.get("broadcastBuildOverlapMs") is not None:
             ann.append("broadcastBuildOverlapMs="
                        f"{float(m['broadcastBuildOverlapMs']):.1f}")

@@ -52,3 +52,12 @@ def assert_df_equals_pandas(df, pd_fn, ignore_order=True, approx_float=True):
     actual = df.to_arrow()
     expected = pd_fn()
     assert_rows_equal(actual, expected, ignore_order, approx_float)
+
+
+def indexed_rows(text, op):
+    """Leading extent of the operand of every `op` (gather / scatter) in
+    a lowered program's StableHLO."""
+    import re
+    return [int(m) for m in re.findall(
+        r'"stablehlo\.%s"\(.*?\}[>)] : \(tensor<(\d+)[x>]' % op, text,
+        flags=re.S)]

@@ -10,6 +10,8 @@ import numpy as np
 import pyarrow as pa
 import pytest
 
+from asserts import indexed_rows
+
 import spark_rapids_tpu as st
 import spark_rapids_tpu.functions as F
 from spark_rapids_tpu.columnar import dtypes as dt
@@ -747,15 +749,6 @@ def test_reduce_side_bytes_equal_argsort_and_take(monkeypatch):
 # the map program holds one sort and nothing that indexes the batch's
 # rows: the gain of PR 32, held off the chip
 # ---------------------------------------------------------------------
-def _indexed_rows(text, op):
-    """Leading extent of the operand of every `op` (gather / scatter) in
-    a lowered program's StableHLO."""
-    import re
-    return [int(m) for m in re.findall(
-        r'"stablehlo\.%s"\(.*?\}[>)] : \(tensor<(\d+)[x>]' % op, text,
-        flags=re.S)]
-
-
 @pytest.mark.parametrize("mode", ["hash", "roundrobin", "range",
                                   "with_float64", "with_string"])
 def test_map_program_is_one_sort_and_no_gather_over_rows(mode):
@@ -797,8 +790,8 @@ def test_map_program_is_one_sort_and_no_gather_over_rows(mode):
         args = (cvs, mask)
     text = jax.jit(fn).lower(*args).as_text()
     assert text.count('"stablehlo.sort"') == 1 + (mode == "with_float64")
-    gathers = _indexed_rows(text, "gather")
-    scatters = _indexed_rows(text, "scatter")
+    gathers = indexed_rows(text, "gather")
+    scatters = indexed_rows(text, "scatter")
     if mode == "with_string":
         assert scatters and gathers
     else:

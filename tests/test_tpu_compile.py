@@ -164,3 +164,39 @@ def test_exchange_map_every_fixed_width_type(one_chip):
     fn = ShuffleExchangeExec._build_map_fn(8, [BoundRef(0, dt.INT64)])
     _compile(fn, cvs, jax.ShapeDtypeStruct((cap,), jnp.bool_,
                                            sharding=one_chip))
+
+
+def test_q18_sorted_aggregate_update_1mi_rows(session, one_chip):
+    """q18's first aggregate (sum of a decimal(12,2) by an int64 key)
+    through the sort-segmented update (`HashAggregateExec`, tag 'update')
+    at one SF1 batch: the chip's program holds six two-operand sorts (the
+    key order by riding: three, the rank, the ride into key order, the
+    ride to the slots), no gather and no scatter (PR 36: the parent's
+    held five scatters and eleven row-long gathers). The merge is the
+    same body over the partial's columns."""
+    from decimal import Decimal
+
+    import pyarrow as pa
+    import spark_rapids_tpu.functions as F
+    from spark_rapids_tpu.exec.aggregate import HashAggregateExec
+    from spark_rapids_tpu.ops.kernel_utils import CV
+    plan = session.create_dataframe({
+        "k": pa.array([1, 2], pa.int64()),
+        "v": pa.array([Decimal(1), Decimal(2)], pa.decimal128(12, 2))}) \
+        .group_by("k").agg(F.sum("v").alias("s"))
+    stack = [plan._execute()[0]]
+    while not isinstance(stack[-1], HashAggregateExec):
+        stack.extend(stack.pop().children)
+    node = stack[-1]
+    node._resolve_fusion()
+
+    def col(dtype):
+        return CV(jax.ShapeDtypeStruct((BATCH,), dtype, sharding=one_chip),
+                  jax.ShapeDtypeStruct((BATCH,), jnp.bool_,
+                                       sharding=one_chip))
+    compiled = _compile(
+        node._update_fn((0,)), [col(jnp.int64), col(jnp.int64)],
+        jax.ShapeDtypeStruct((BATCH,), jnp.bool_, sharding=one_chip))
+    text = compiled.as_text()
+    assert text.count(" sort(") == 6
+    assert " gather(" not in text and " scatter(" not in text
