@@ -150,6 +150,12 @@ class TpuExec:
         materialization per batch). None when not fusable."""
         return None
 
+    def d128_exprs(self) -> int:
+        """128-bit decimal expression nodes this operator's own program
+        evaluates a row (expr.expressions.d128_nodes); the operators that
+        hold bound expressions of their own override it."""
+        return 0
+
     def preserves_ordinals(self) -> bool:
         """True when fusable_stage keeps the child's column ordinals
         (filters do; projections do not)."""
@@ -235,6 +241,21 @@ def prewarm_tree(root: TpuExec, pool, query_id: Optional[str] = None,
                 if n >= limit:
                     return n
     return n
+
+
+# what the tag of a cached program ends in where its body holds 128-bit
+# decimal arithmetic (TpuExec.d128_exprs() > 0): the module then reads
+# `jit_<cls>_<tag>_d128` in a device trace
+D128_MARK = "_d128"
+
+
+def report_d128(m, exprs: int, rows: int):
+    """`d128Exprs` (the 128-bit decimal expression nodes in the
+    operator's program) and `d128Rows` (the live rows its launches put
+    through them); nothing for an operator whose program holds none."""
+    if exprs:
+        m.set("d128Exprs", exprs)
+        m.add("d128Rows", rows)
 
 
 def collapse_fusable(node: TpuExec, require_ordinals: bool = False):

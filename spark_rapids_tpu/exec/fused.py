@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from ..profiler import xla_stats
-from .base import ExecContext, TpuExec
+from .base import D128_MARK, ExecContext, TpuExec, report_d128
 from .batch import DeviceBatch
 
 __all__ = ["FusedStageExec"]
@@ -52,12 +52,21 @@ class FusedStageExec(TpuExec):
         # execution order is bottom-up: the deepest member runs first
         stages = [m.fusable_stage() for m in reversed(self.members)]
         self._exec_order = list(reversed(self.members))
+        wide = [m.d128_exprs() for m in self._exec_order]
+        self._d128 = sum(wide)
 
         def _run(cvs, mask, stats):
-            counts = []
-            for fn in stages:
+            # where a member computes in 128-bit decimals, one more
+            # counter behind the members': the live rows into those
+            counts, rows128 = [], jnp.zeros((), jnp.int64)
+            for fn, w in zip(stages, wide):
+                if w:
+                    rows128 += (counts[-1] if counts
+                                else jnp.sum(mask, dtype=jnp.int64))
                 cvs, mask = fn(cvs, mask)
                 counts.append(jnp.sum(mask, dtype=jnp.int64))
+            if self._d128:
+                counts.append(rows128)
             return cvs, mask, stats + jnp.stack(counts)
 
         # donation is a no-op (with a warning) on the CPU backend; on
@@ -66,7 +75,8 @@ class FusedStageExec(TpuExec):
         donate = () if jax.default_backend() == "cpu" else (0, 1, 2)
         from ..runtime.program_cache import cached_program
         self._jit = cached_program(
-            _run, cls="FusedStageExec", tag="run",
+            _run, cls="FusedStageExec",
+            tag="run" + D128_MARK * bool(self._d128),
             key=self.stage_fingerprint(), donate_argnums=donate)
 
     # ------------------------------------------------------------------
@@ -84,6 +94,9 @@ class FusedStageExec(TpuExec):
 
     def preserves_ordinals(self) -> bool:
         return all(m.preserves_ordinals() for m in self.members)
+
+    def d128_exprs(self) -> int:
+        return self._d128
 
     def stage_fingerprint(self) -> tuple:
         return ("FusedStage",) + tuple(
@@ -103,7 +116,8 @@ class FusedStageExec(TpuExec):
         from . import degrade
         from .nodes import make_table
         m = ctx.metrics_for(self._op_id)
-        stats = jnp.zeros(len(self.members), dtype=jnp.int64)
+        stats = jnp.zeros(len(self.members) + bool(self._d128),
+                          dtype=jnp.int64)
         n_batches = 0
         for batch in self.children[0].execute_partition(ctx, pid):
             ctx.check_cancel()
@@ -143,3 +157,4 @@ class FusedStageExec(TpuExec):
             for member, v in zip(self._exec_order, list(vals)):
                 m.add(f"fusedRows.{member.node_name().replace('Exec', '')}"
                       f"[{getattr(member, 'lore_id', '?')}]", int(v))
+            report_d128(m, self._d128, int(vals[-1]))

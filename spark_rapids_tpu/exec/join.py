@@ -37,6 +37,7 @@ from ..expr.expressions import EmitCtx, Expression
 from ..ops import sortkeys as sk
 from ..ops.concat import concat_cvs, concat_masks
 from ..ops.kernel_utils import CV
+from ..ops.partition import word_count
 from ..utils.transfer import fetch_int
 from ..profiler import xla_stats
 from .base import ExecContext, TpuExec
@@ -177,6 +178,21 @@ class HashJoinExec(TpuExec):
                     else range(child.num_partitions(ctx))):
             batches.extend(child.execute_partition(ctx, pid))
         return self._concat_batches(batches, child.schema)
+
+    def _report_key_words(self, m, bkey_cvs, nchunks=None):
+        """`joinKeyWords`: the 32-bit words of key a row as this join's
+        build side is sorted (or addressed) by it: an int64 key reads 2,
+        two of them 4, a decimal128 4, a string its chunk words
+        (`nchunks`; unknown before the generic path measures them)."""
+        words = 0
+        for i, kcv in enumerate(bkey_cvs):
+            if kcv.offsets is None:
+                words += word_count([kcv.data])
+            elif nchunks is None:
+                return
+            else:
+                words += nchunks[i]
+        m.set("joinKeyWords", words)
 
     def _key_nchunks(self, bkey_cvs, bmask, skey_cvs, smask):
         ncs = []
@@ -752,6 +768,7 @@ class HashJoinExec(TpuExec):
             cap_b = bmask.shape[0]
             bctx = EmitCtx(bcvs, cap_b)
             bkey_cvs = [k.emit(bctx) for k in self.rkeys]
+        self._report_key_words(m, bkey_cvs)
         matched_b_acc = jnp.zeros(cap_b, jnp.bool_)
         fast = self._fast_path_ok()
         direct = None
@@ -1062,6 +1079,8 @@ class HashJoinExec(TpuExec):
             else:
                 nchunks = self._key_nchunks(bkey_cvs, bmask,
                                             skey_cvs, smask)
+                if any(nchunks):
+                    self._report_key_words(m, bkey_cvs, nchunks)
                 ckey = (nchunks, cap_b, cap_s)
                 cfn = self._count_cache.get(ckey)
                 if cfn is None:

@@ -17,11 +17,12 @@ import numpy as np
 from ..columnar import dtypes as dt
 from ..columnar.column import Column, bucket_capacity
 from ..columnar.table import Field, Schema, Table
-from ..expr.expressions import EmitCtx, Expression
+from ..expr.expressions import EmitCtx, Expression, d128_nodes
 from ..ops.kernel_utils import CV
 from ..profiler import tracing, xla_stats
 from ..runtime import faults
-from .base import ExecContext, TpuExec
+from ..utils.transfer import fetch_int
+from .base import D128_MARK, ExecContext, TpuExec, report_d128
 from .batch import DeviceBatch, MeshBatch
 
 __all__ = ["InMemoryScanExec", "CachedScanExec", "ParquetScanExec",
@@ -765,14 +766,21 @@ class ProjectExec(TpuExec):
                  schema: Schema):
         super().__init__([child], schema)
         self.bound = bound_exprs
+        self._d128 = d128_nodes(bound_exprs)
 
-        def _run(cvs, mask):
+        def _run(cvs, mask, *rows):
+            # `rows`: the running count of live rows a program of
+            # 128-bit decimal arithmetic keeps beside its columns
             ctx = EmitCtx(cvs, mask.shape[0])
-            return [e.emit(ctx) for e in self.bound]
+            out = [e.emit(ctx) for e in self.bound]
+            return (out, rows[0] + jnp.sum(mask, dtype=jnp.int64)) \
+                if rows else out
 
         from ..runtime.program_cache import cached_program, exprs_fp
-        self._jit = cached_program(_run, cls="ProjectExec", tag="run",
-                                   key=exprs_fp(self.bound))
+        self._jit = cached_program(
+            _run, cls="ProjectExec",
+            tag="run" + D128_MARK * bool(self._d128),
+            key=exprs_fp(self.bound))
 
     def describe(self):
         return f"ProjectExec[{', '.join(map(repr, self.bound))}]"
@@ -790,9 +798,13 @@ class ProjectExec(TpuExec):
     def preserves_ordinals(self):
         return False
 
+    def d128_exprs(self):
+        return self._d128
+
     def execute_partition(self, ctx, pid):
         from . import degrade
         m = ctx.metrics_for(self._op_id)
+        rows = (jnp.zeros((), jnp.int64),) if self._d128 else ()
         for batch in self.children[0].execute_partition(ctx, pid):
             ctx.check_cancel()
             if self._op_id not in ctx.degraded:
@@ -802,11 +814,13 @@ class ProjectExec(TpuExec):
                                    query_id=ctx.query_id,
                                    op="ProjectExec")
                     with m.timer("opTime"):
-                        out = self._jit(batch.cvs(), batch.row_mask)
+                        out = self._jit(batch.cvs(), batch.row_mask, *rows)
                 except Exception as e:  # noqa: BLE001 - classified below
                     if not degrade.should_degrade(ctx, self, e):
                         raise
                 else:
+                    if rows:
+                        out, rows = out[0], out[1:]
                     xla_stats.count_dispatch()
                     m.add("numOutputBatches", 1)
                     yield DeviceBatch(
@@ -820,21 +834,28 @@ class ProjectExec(TpuExec):
             m.add("degradedToHost", 1)
             m.add("numOutputBatches", 1)
             yield hb
+        if rows:
+            report_d128(m, self._d128, fetch_int(rows[0]))
 
 
 class FilterExec(TpuExec):
     def __init__(self, child: TpuExec, bound_cond: Expression):
         super().__init__([child], child.schema)
         self.bound = bound_cond
+        self._d128 = d128_nodes([bound_cond])
 
-        def _run(cvs, mask):
+        def _run(cvs, mask, *rows):   # `rows`: as ProjectExec's
             ctx = EmitCtx(cvs, mask.shape[0])
             cv = self.bound.emit(ctx)
-            return mask & cv.validity & cv.data.astype(jnp.bool_)
+            kept = mask & cv.validity & cv.data.astype(jnp.bool_)
+            return (kept, rows[0] + jnp.sum(mask, dtype=jnp.int64)) \
+                if rows else kept
 
         from ..runtime.program_cache import cached_program, expr_fp
-        self._jit = cached_program(_run, cls="FilterExec", tag="run",
-                                   key=(expr_fp(self.bound),))
+        self._jit = cached_program(
+            _run, cls="FilterExec",
+            tag="run" + D128_MARK * bool(self._d128),
+            key=(expr_fp(self.bound),))
 
     def describe(self):
         return f"FilterExec[{self.bound!r}]"
@@ -850,9 +871,13 @@ class FilterExec(TpuExec):
         from ..runtime.program_cache import expr_fp
         return ("Filter", expr_fp(self.bound))
 
+    def d128_exprs(self):
+        return self._d128
+
     def execute_partition(self, ctx, pid):
         from . import degrade
         m = ctx.metrics_for(self._op_id)
+        rows = (jnp.zeros((), jnp.int64),) if self._d128 else ()
         for batch in self.children[0].execute_partition(ctx, pid):
             ctx.check_cancel()
             if self._op_id not in ctx.degraded:
@@ -862,11 +887,14 @@ class FilterExec(TpuExec):
                                    query_id=ctx.query_id,
                                    op="FilterExec")
                     with m.timer("opTime"):
-                        new_mask = self._jit(batch.cvs(), batch.row_mask)
+                        new_mask = self._jit(batch.cvs(), batch.row_mask,
+                                             *rows)
                 except Exception as e:  # noqa: BLE001 - classified below
                     if not degrade.should_degrade(ctx, self, e):
                         raise
                 else:
+                    if rows:
+                        new_mask, rows = new_mask[0], new_mask[1:]
                     xla_stats.count_dispatch()
                     m.add("numOutputBatches", 1)
                     yield DeviceBatch(batch.table, batch.num_rows,
@@ -881,6 +909,8 @@ class FilterExec(TpuExec):
                 continue
             m.add("numOutputBatches", 1)
             yield hb
+        if rows:
+            report_d128(m, self._d128, fetch_int(rows[0]))
 
 
 class LimitExec(TpuExec):

@@ -342,6 +342,23 @@ class Alias(Expression):
         return f"{self.child} AS {self._name}"
 
 
+def d128_nodes(exprs) -> int:
+    """How many nodes of the bound trees `exprs` compute in 128-bit
+    decimals: every node with operands (an Alias only names its child)
+    whose own type or an operand's is a decimal past 18 digits, so its
+    emit runs the limb arithmetic of ops/decimal128.py for every row.
+    What `d128Exprs` counts and what marks a program's name `_d128`."""
+    def wide(e):
+        return isinstance(e.dtype, dt.DecimalType) and e.dtype.is_decimal128
+    n, todo = 0, list(exprs)
+    while todo:
+        e = todo.pop()
+        todo.extend(e.children)
+        if e.children and not isinstance(e, Alias):
+            n += wide(e) or any(wide(c) for c in e.children)
+    return n
+
+
 # ----------------------------------------------------------------------
 # Implicit cast insertion (Spark's binary-op type coercion)
 # ----------------------------------------------------------------------

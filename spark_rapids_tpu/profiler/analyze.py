@@ -124,6 +124,14 @@ def render_analyze(tree: dict, metrics_by_lore: Dict[Optional[int], dict],
             ann.append(f"aggSortWords={int(m['aggSortWords'])}")
             ann.append("aggScatteredColumns="
                        f"{int(m.get('aggScatteredColumns', 0))}")
+        # 128-bit decimal arithmetic a row: the expression nodes in the
+        # operator's program, the live rows its launches put through them
+        if m.get("d128Exprs"):
+            ann.append(f"d128Exprs={int(m['d128Exprs'])}")
+            ann.append(f"d128Rows={int(m.get('d128Rows', 0))}")
+        # 32-bit words of key a row of the join's build side
+        if m.get("joinKeyWords") is not None:
+            ann.append(f"joinKeyWords={int(m['joinKeyWords'])}")
         if m.get("broadcastBuildOverlapMs") is not None:
             ann.append("broadcastBuildOverlapMs="
                        f"{float(m['broadcastBuildOverlapMs']):.1f}")

@@ -200,3 +200,31 @@ def test_q18_sorted_aggregate_update_1mi_rows(session, one_chip):
     text = compiled.as_text()
     assert text.count(" sort(") == 6
     assert " gather(" not in text and " scatter(" not in text
+
+
+def test_q9_amount_stage_of_128bit_decimals_512ki_rows(session, one_chip):
+    """q9's amount, l_extendedprice * (1 - l_discount) - ps_supplycost *
+    l_quantity: a decimal(25,4) product and a decimal(26,4) difference a
+    row, in limbs (ops/decimal128.py) where the chip emulates 64-bit
+    lanes. The fused stage that holds it is the program whose name ends
+    in `_d128` (exec/base.py:D128_MARK), with its count of live rows;
+    here at 512 Ki rows, past what a partition of the SF1 join puts
+    through it (about 325,000 rows in all)."""
+    from spark_rapids_tpu.exec.join import HashJoinExec
+    from spark_rapids_tpu.plan.planner import Planner
+    from spark_rapids_tpu.workloads import tpch
+    dfs = {k: session.create_dataframe(v).cache()
+           for k, v in tpch.gen_all(0.001, seed=3).items()}
+    stack = [Planner(session.conf).plan(tpch.queries()[9](dfs)._plan)]
+    while not stack[-1].d128_exprs():
+        stack.extend(stack.pop().children)
+    stage = stack[-1]
+    assert type(stage).__name__ == "FusedStageExec"
+    assert stage.d128_exprs() == 2
+    assert stage._jit.base_key[1:3] == ("FusedStageExec", "run_d128")
+    small = HashJoinExec._concat_batches([], stage.children[0].schema)
+    cap0 = small[1].shape[0]
+    cvs, mask = _at_rows((tuple(small[0]), small[1]), cap0, 1 << 19, one_chip)
+    stats = jax.ShapeDtypeStruct((len(stage.members) + 1,), jnp.int64,
+                                 sharding=one_chip)
+    _compile(stage._jit._fn, list(cvs), mask, stats)
