@@ -17,6 +17,11 @@ cross. TPU-first redesign under the static-shape regime:
      searchsorted over the per-row offsets builds the left/right gather
      maps; gather payload columns from both sides.
 
+A single fixed-width key, or several fixed-width integral keys packed
+into one monotone uint64 word over the build side's ranges, skips the
+combined sort: the build side sorts once per join (or is addressed
+directly) and every stream batch probes it by binary search.
+
 Semi/anti joins skip phases 3-4 entirely — they are a mask update on the
 stream batch. Right/full outer track per-build-row matched flags across
 stream batches and emit unmatched build rows in a final batch.
@@ -231,6 +236,28 @@ class HashJoinExec(TpuExec):
                     ^ jnp.uint64(1 << 63))
         return None
 
+    @staticmethod
+    def _key_word(kcv, dtype, pack=()):
+        """(monotone uint64 word, key can match) of each row. One key
+        (`pack` empty): its radix word and its validity. Packed keys
+        (`kcv` the list of key columns, `pack` = (lo, hi): each key's
+        least and greatest value over the build side, int64 device
+        arrays): the mixed-radix word sum (k_i - lo_i) * prod_{j>i} span_j.
+        A key outside its [lo_i, hi_i] cannot match, and must not: its
+        word would alias another tuple's."""
+        if not pack:
+            return HashJoinExec._single_key_u64(kcv, dtype), kcv.validity
+        lo, hi = pack
+        span = (hi - lo + 1).astype(jnp.uint64)
+        word = ok = None
+        for i, c in enumerate(kcv):
+            v = c.data.astype(jnp.int64)
+            off = v.astype(jnp.uint64) - lo[i].astype(jnp.uint64)
+            inr = c.validity & (v >= lo[i]) & (v <= hi[i])
+            word = off if word is None else word * span[i] + off
+            ok = inr if ok is None else ok & inr
+        return word, ok
+
     def _fast_path_ok(self):
         if len(self.rkeys) != 1:
             return False
@@ -240,27 +267,82 @@ class HashJoinExec(TpuExec):
         return not (d.is_variable_width or d.is_nested
                     or isinstance(d, dt.DoubleType))
 
-    def _build_sorted(self, bkey_cvs, bmask):
+    def _pack_ok(self):
+        """Two keys or more, each a value of one int64 (an integral,
+        date, timestamp, boolean or decimal64 column): they may pack into
+        one word, if the build side's ranges let them (_pack_ranges).
+        The planner gives both sides of a key the same type."""
+        def packable(d):
+            if isinstance(d, dt.DecimalType):
+                return not d.is_decimal128
+            return d.is_integral or isinstance(
+                d, (dt.DateType, dt.TimestampType, dt.BooleanType))
+        return len(self.rkeys) >= 2 and all(packable(k.dtype)
+                                            for k in self.rkeys)
+
+    _WORD_MAX = (1 << 63) - 1     # packed words stay clear of the pin
+
+    def _pack_ranges(self, bkey_cvs, bmask, cap_b):
+        """((lo, hi) device arrays, product of the spans) when the keys
+        of the build side's matchable rows pack into one word, else None
+        (also for a build side with no such row). One launch, one
+        fetch."""
+        from ..utils.transfer import fetch
+        key = ("keyranges", cap_b)
+        rfn = self._count_cache.get(key)
+        if rfn is None:
+            def rfn_(kcvs, mask):
+                valid = mask
+                for c in kcvs:
+                    valid = valid & c.validity
+                vs = [c.data.astype(jnp.int64) for c in kcvs]
+                big = jnp.iinfo(jnp.int64)
+                lo = jnp.stack([jnp.min(jnp.where(valid, v, big.max))
+                                for v in vs])
+                hi = jnp.stack([jnp.max(jnp.where(valid, v, big.min))
+                                for v in vs])
+                return lo, hi, jnp.sum(valid.astype(jnp.int32))
+            from ..runtime.program_cache import cached_program
+            rfn = cached_program(rfn_, cls=type(self).__name__,
+                                 tag="keyranges", key=self._fp)
+            self._count_cache[key] = rfn
+        lo_d, hi_d, nv_d = rfn(bkey_cvs, bmask)
+        lo, hi, nv = fetch((lo_d, hi_d, nv_d))
+        if int(nv) == 0:
+            return None
+        span = 1
+        for a, b in zip(lo.tolist(), hi.tolist()):
+            span *= b - a + 1
+        if span > self._WORD_MAX:
+            return None
+        return (lo_d, hi_d), span
+
+    @staticmethod
+    def _build_sort_fn(dtype):
+        def fn_(kcv, mask, *pack):
+            ukey, kvalid = HashJoinExec._key_word(kcv, dtype, pack)
+            valid = mask & kvalid
+            pinned = jnp.where(valid, ukey,
+                               jnp.uint64(0xFFFFFFFFFFFFFFFF))
+            inv = jnp.logical_not(valid).astype(jnp.uint8)
+            perm = sk.lexsort([inv, pinned])
+            return pinned[perm], perm.astype(jnp.int32), \
+                jnp.sum(valid.astype(jnp.int32))
+        return fn_
+
+    def _build_sorted(self, bkey_cvs, bmask, pack=()):
         """jitted once per build capacity (cached in _count_cache):
         returns (sorted ukeys with invalids pinned MAX, perm sorted->orig,
         n_valid)."""
         key = ("buildsort", bmask.shape[0])
         fn = self._count_cache.get(key)
         if fn is None:
-            def fn_(kcv, mask):
-                ukey = self._single_key_u64(kcv, self.rkeys[0].dtype)
-                valid = mask & kcv.validity
-                pinned = jnp.where(valid, ukey,
-                                   jnp.uint64(0xFFFFFFFFFFFFFFFF))
-                inv = jnp.logical_not(valid).astype(jnp.uint8)
-                perm = sk.lexsort([inv, pinned])
-                return pinned[perm], perm.astype(jnp.int32), \
-                    jnp.sum(valid.astype(jnp.int32))
             from ..runtime.program_cache import cached_program
-            fn = cached_program(fn_, cls=type(self).__name__,
+            fn = cached_program(self._build_sort_fn(self.rkeys[0].dtype),
+                                cls=type(self).__name__,
                                 tag="buildsort", key=self._fp)
             self._count_cache[key] = fn
-        return fn(bkey_cvs[0], bmask)
+        return fn(bkey_cvs if pack else bkey_cvs[0], bmask, *pack)
 
     # ---- direct-address (perfect-hash) build: no sort at all -----------
     # When the single int key's value span fits a bounded table (TPC-H
@@ -272,8 +354,14 @@ class HashJoinExec(TpuExec):
     _DIRECT_SPAN_FACTOR = 8
     _DIRECT_SPAN_MIN = 1 << 22
 
-    def _try_build_direct(self, bkey_cvs, bmask, cap_b):
-        """Returns {'R', 'kmin', 'kmax', 'cnt_t', 'idx_t'} or None."""
+    def _try_build_direct(self, bkey_cvs, bmask, cap_b, packed=None):
+        """Returns {'R', 'kmin', 'kmax', 'cnt_t', 'idx_t'} or None.
+        `packed` (_pack_ranges' answer): the words lie in [0, span)."""
+        if packed is not None:
+            pack, span = packed
+            kmin_d, kmax_d = jnp.uint64(0), jnp.uint64(span - 1)
+            return self._build_direct(bkey_cvs, bmask, cap_b, span, kmin_d,
+                                      kmax_d, pack)
         from ..utils.transfer import fetch
         key = ("keyrange", cap_b)
         rfn = self._count_cache.get(key)
@@ -293,7 +381,11 @@ class HashJoinExec(TpuExec):
         kmin, kmax, nv = (int(v) for v in fetch((kmin_d, kmax_d, nv_d)))
         if nv == 0:
             return None
-        span = kmax - kmin + 1
+        return self._build_direct(bkey_cvs, bmask, cap_b, kmax - kmin + 1,
+                                  kmin_d, kmax_d)
+
+    def _build_direct(self, bkey_cvs, bmask, cap_b, span, kmin_d, kmax_d,
+                      pack=()):
         if span > max(self._DIRECT_SPAN_FACTOR * cap_b,
                       self._DIRECT_SPAN_MIN):
             return None
@@ -301,9 +393,11 @@ class HashJoinExec(TpuExec):
         bkey = ("directbuild", R, cap_b)
         bfn = self._count_cache.get(bkey)
         if bfn is None:
-            def bfn_(kcv, mask, kmin_dev):
-                ukey = self._single_key_u64(kcv, self.rkeys[0].dtype)
-                valid = mask & kcv.validity
+            dtype = self.rkeys[0].dtype
+
+            def bfn_(kcv, mask, kmin_dev, *pack):
+                ukey, kvalid = HashJoinExec._key_word(kcv, dtype, pack)
+                valid = mask & kvalid
                 d = (ukey - kmin_dev).astype(jnp.int64)
                 off = jnp.where(valid, jnp.clip(d, 0, R), R)
                 cnt_t = jnp.zeros(R + 1, jnp.int32).at[off].add(
@@ -316,18 +410,21 @@ class HashJoinExec(TpuExec):
                                  tag="directbuild",
                                  key=self._fp + (R,))
             self._count_cache[bkey] = bfn
-        cnt_t, idx_t = bfn(bkey_cvs[0], bmask, kmin_d)
+        cnt_t, idx_t = bfn(bkey_cvs if pack else bkey_cvs[0], bmask, kmin_d,
+                           *pack)
         return {"R": R, "kmin": kmin_d, "kmax": kmax_d,
                 "cnt_t": cnt_t, "idx_t": idx_t}
 
-    def _direct_probe(self, direct, skcv, smask, cap_s):
+    def _direct_probe(self, direct, skcv, smask, cap_s, pack=()):
         R = direct["R"]
         key = ("directprobe", R, cap_s)
         fn = self._count_cache.get(key)
         if fn is None:
-            def fn_(cnt_t, idx_t, kmin, kmax, skcv, smask):
-                ukey_s = self._single_key_u64(skcv, self.lkeys[0].dtype)
-                joinable = smask & skcv.validity
+            dtype = self.lkeys[0].dtype
+
+            def fn_(cnt_t, idx_t, kmin, kmax, skcv, smask, *pack):
+                ukey_s, kvalid = HashJoinExec._key_word(skcv, dtype, pack)
+                joinable = smask & kvalid
                 in_r = joinable & (ukey_s >= kmin) & (ukey_s <= kmax)
                 d = (ukey_s - kmin).astype(jnp.int64)
                 poff = jnp.where(in_r, jnp.clip(d, 0, R), R)
@@ -340,17 +437,17 @@ class HashJoinExec(TpuExec):
                                 key=self._fp + (R,))
             self._count_cache[key] = fn
         return fn(direct["cnt_t"], direct["idx_t"], direct["kmin"],
-                  direct["kmax"], skcv, smask)
+                  direct["kmax"], skcv, smask, *pack)
 
     def _probe_fn(self, cap_b, cap_s):
         """Per-stream-batch count phase against the sorted build keys."""
         # not `self`: a cached program pinning its builder must not pin
         # the operator tree (and what it parked) with it
-        u64, ldtype = self._single_key_u64, self.lkeys[0].dtype
+        word, ldtype = self._key_word, self.lkeys[0].dtype
 
-        def fn(sorted_ukey, n_valid, skcv, smask):
-            ukey_s = u64(skcv, ldtype)
-            joinable = smask & skcv.validity
+        def fn(sorted_ukey, n_valid, skcv, smask, *pack):
+            ukey_s, kvalid = word(skcv, ldtype, pack)
+            joinable = smask & kvalid
             lo = jnp.searchsorted(sorted_ukey, ukey_s, side="left")
             hi = jnp.searchsorted(sorted_ukey, ukey_s, side="right")
             lo = jnp.minimum(lo, n_valid)
@@ -771,15 +868,26 @@ class HashJoinExec(TpuExec):
         self._report_key_words(m, bkey_cvs)
         matched_b_acc = jnp.zeros(cap_b, jnp.bool_)
         fast = self._fast_path_ok()
+        packed, pack = None, ()
+        if len(self.rkeys) > 1:
+            # several fixed-width keys: one word, then the single-key path
+            if self._pack_ok():
+                with m.timer("buildTime"):
+                    packed = self._pack_ranges(bkey_cvs, bmask, cap_b)
+            m.set("joinPackedKeys", len(self.rkeys) if packed else 0)
+            if packed is not None:
+                fast, pack = True, packed[0]
+                m.set("joinKeyWords", 2)
         direct = None
         if fast and self.condition is None and self.how in (
                 "inner", "left", "left_semi", "left_anti"):
             with m.timer("buildTime"):
-                direct = self._try_build_direct(bkey_cvs, bmask, cap_b)
+                direct = self._try_build_direct(bkey_cvs, bmask, cap_b,
+                                                packed)
         if fast and direct is None:
             with m.timer("buildTime"):
                 sorted_ukey, bperm, n_valid_b = self._build_sorted(
-                    bkey_cvs, bmask)
+                    bkey_cvs, bmask, pack)
         elif direct is not None:
             # sorted structures built lazily only if a stream batch needs
             # pair enumeration (duplicate build keys)
@@ -797,7 +905,7 @@ class HashJoinExec(TpuExec):
                                          sorted_ukey if fast else None,
                                          bperm if fast else None,
                                          n_valid_b if fast else None,
-                                         direct))
+                                         direct, pack))
             return out
 
         for batch in stream_batches:
@@ -1016,7 +1124,8 @@ class HashJoinExec(TpuExec):
                 h.close()
 
     def _probe_batch(self, ctx, m, batch, bcvs, bmask, bkey_cvs, cap_b,
-                     fast, sorted_ukey, bperm, n_valid_b, direct=None):
+                     fast, sorted_ukey, bperm, n_valid_b, direct=None,
+                     pack=()):
         """One stream batch through count/probe + expand. Yields
         ("matched_b", mask) and ("batch", DeviceBatch) items. Idempotent
         (retry/split safe): all semantics are stream-row-local and
@@ -1026,10 +1135,11 @@ class HashJoinExec(TpuExec):
             cap_s = batch.capacity
             sctx = EmitCtx(scvs, cap_s)
             skey_cvs = [k.emit(sctx) for k in self.lkeys]
+            skey = skey_cvs if pack else skey_cvs[0]
             if direct is not None:
                 from ..utils.transfer import fetch
-                cnt, bidx = self._direct_probe(direct, skey_cvs[0], smask,
-                                               cap_s)
+                cnt, bidx = self._direct_probe(direct, skey, smask, cap_s,
+                                               pack)
                 if self.how == "left_semi":
                     yield ("batch", DeviceBatch(
                         batch.table, batch.num_rows,
@@ -1054,7 +1164,8 @@ class HashJoinExec(TpuExec):
                 # duplicate build keys in this batch's match set: promote
                 # to the sorted fast path (built once, reused)
                 if "sorted" not in direct:
-                    direct["sorted"] = self._build_sorted(bkey_cvs, bmask)
+                    direct["sorted"] = self._build_sorted(bkey_cvs, bmask,
+                                                          pack)
                 sorted_ukey, bperm, n_valid_b = direct["sorted"]
             if fast:
                 pkey = ("probe", cap_b, cap_s)
@@ -1067,8 +1178,7 @@ class HashJoinExec(TpuExec):
                         key=self._fp + (cap_b, cap_s))
                     self._count_cache[pkey] = pfn
                 (cnt, offsets, total, bstart,
-                 touched) = pfn(sorted_ukey, n_valid_b, skey_cvs[0],
-                                smask)
+                 touched) = pfn(sorted_ukey, n_valid_b, skey, smask, *pack)
                 xla_stats.count_dispatch()
                 perm = bperm
                 if self.how in ("right", "full") and \

@@ -132,6 +132,9 @@ def render_analyze(tree: dict, metrics_by_lore: Dict[Optional[int], dict],
         # 32-bit words of key a row of the join's build side
         if m.get("joinKeyWords") is not None:
             ann.append(f"joinKeyWords={int(m['joinKeyWords'])}")
+        # keys of a multi-key join packed into that one word (0: not)
+        if m.get("joinPackedKeys") is not None:
+            ann.append(f"joinPackedKeys={int(m['joinPackedKeys'])}")
         if m.get("broadcastBuildOverlapMs") is not None:
             ann.append("broadcastBuildOverlapMs="
                        f"{float(m['broadcastBuildOverlapMs']):.1f}")

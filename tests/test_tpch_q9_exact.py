@@ -140,9 +140,20 @@ def test_q9_holds_no_float_cast_and_no_host_operator(q9_session, tables):
 
 
 def test_counters_of_the_128_bit_arithmetic_and_the_two_key_join(
-        q9_session, tables):
+        q9_session, tables, monkeypatch):
+    from spark_rapids_tpu.runtime.program_cache import CachedProgram
+    launched = []
+    call = CachedProgram.__call__
+
+    def spy(prog, *args):
+        launched.append(prog.base_key[1:3])
+        return call(prog, *args)
+    monkeypatch.setattr(CachedProgram, "__call__", spy)
     t = tables(*CASES[1])
     df, _ = _run_q9(q9_session, t)
+    # the two-key join packs its pair into one word: no combined sort
+    joins = {tag for cls, tag in launched if cls == "HashJoinExec"}
+    assert "keyranges" in joins and "count" not in joins
     counted = list(df.last_metrics().values())
     wide = [m for m in counted if m.get("d128Exprs")]
     # ps_supplycost * l_quantity is decimal(25,4), the difference (26,4)
@@ -150,9 +161,12 @@ def test_counters_of_the_128_bit_arithmetic_and_the_two_key_join(
     assert int(wide[0]["d128Rows"]) == reference_q9.joined_rows(t)
     words = sorted(int(m["joinKeyWords"]) for m in counted
                    if "joinKeyWords" in m)
-    assert words == [2, 2, 2, 2, 4]       # four int64 keys and one pair
+    assert words == [2, 2, 2, 2, 2]       # four int64 keys, one packed pair
+    assert [int(m["joinPackedKeys"]) for m in counted
+            if "joinPackedKeys" in m] == [2]
     rendered = df.explain("analyze")
-    assert "d128Exprs=2" in rendered and "joinKeyWords=4" in rendered
+    assert "d128Exprs=2" in rendered and "joinKeyWords=2" in rendered
+    assert "joinPackedKeys=2" in rendered and "joinKeyWords=4" not in rendered
     # the program that holds the arithmetic says so in its name
     stage = next(n for n in _plan_nodes(df._last_root) if n.d128_exprs())
     assert type(stage).__name__ == "FusedStageExec"

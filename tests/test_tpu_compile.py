@@ -228,3 +228,42 @@ def test_q9_amount_stage_of_128bit_decimals_512ki_rows(session, one_chip):
     stats = jax.ShapeDtypeStruct((len(stage.members) + 1,), jnp.int64,
                                  sharding=one_chip)
     _compile(stage._jit._fn, list(cvs), mask, stats)
+
+
+def test_q9_packed_pair_join_sort_1mi_and_probe_64ki(session, one_chip):
+    """q9's two-key join against partsupp, (l_partkey, l_suppkey) =
+    (ps_partkey, ps_suppkey): both int64 keys pack into one uint64 word
+    over the build side's ranges (exec/join.py:_key_word), so the build
+    side sorts once (`buildsort`, 1 Mi rows: partsupp's 800,000 at SF1)
+    and each stream batch probes it by binary search (`probe`, 64 Ki
+    rows). The parent sorted build and stream rows together for every
+    stream batch (`count`, five words a row)."""
+    import pyarrow as pa
+    from spark_rapids_tpu.exec.join import HashJoinExec
+    from spark_rapids_tpu.expr.expressions import col
+    from spark_rapids_tpu.ops.kernel_utils import CV
+    def two(x, y):
+        return session.create_dataframe({x: pa.array([1, 2], pa.int64()),
+                                         y: pa.array([3, 4], pa.int64())})
+    df = two("a", "b").join(two("c", "d"),
+                            on=(col("a") == col("c")) & (col("b") == col("d")))
+    stack = [df._execute()[0]]
+    while not isinstance(stack[-1], HashJoinExec):
+        stack.extend(stack.pop().children)
+    node = stack[-1]
+    assert node._pack_ok() and not node._fast_path_ok()
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def keys(n):
+        return [CV(sds((n,), jnp.int64), sds((n,), jnp.bool_))
+                for _ in range(2)]
+    lo, hi = sds((2,), jnp.int64), sds((2,), jnp.int64)
+    cap_b, cap_s = BATCH, 1 << 16
+    sort = _compile(HashJoinExec._build_sort_fn(node.rkeys[0].dtype),
+                    keys(cap_b), sds((cap_b,), jnp.bool_), lo, hi)
+    assert sort.as_text().count(" sort(") == 3  # the pin's byte, two words
+    _compile(node._probe_fn(cap_b, cap_s), sds((cap_b,), jnp.uint64),
+             sds((), jnp.int32), keys(cap_s), sds((cap_s,), jnp.bool_),
+             lo, hi)
