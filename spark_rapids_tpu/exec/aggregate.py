@@ -29,7 +29,7 @@ from ..ops.gather import take, take_strings
 from ..ops.groups import RunGroups, ScatterGroups
 from ..ops.kernel_utils import CV
 from ..ops.partition import sorted_by_target, word_count
-from ..profiler import xla_stats
+from ..profiler import tracing, xla_stats
 from ..utils.transfer import fetch_int
 from .base import ExecContext, TpuExec
 from .batch import DeviceBatch
@@ -186,12 +186,13 @@ class UngroupedAggExec(TpuExec):
         from .nodes import CachedScanExec
         if not isinstance(self._base, CachedScanExec):
             return None
-        batches = self._base.whole_input(ctx)
-        if not batches or len(batches) > 64:  # unroll bound
-            return None
-        if not hasattr(self, "_whole_jit"):
-            self._whole_jit = self._whole_input_program()
-        args = tuple((tuple(b.cvs()), b.row_mask) for b in batches)
+        with tracing.span("agg.whole_args", "op"):
+            batches = self._base.whole_input(ctx)
+            if not batches or len(batches) > 64:  # unroll bound
+                return None
+            if not hasattr(self, "_whole_jit"):
+                self._whole_jit = self._whole_input_program()
+            args = tuple((tuple(b.cvs()), b.row_mask) for b in batches)
         with m.timer("opTime"):
             out = self._whole_jit(args)
         xla_stats.count_dispatch()
@@ -241,18 +242,20 @@ def _pad_one_row(outs):
     offsets+child already built."""
     cvs = []
     pad = 128 - 1
-    for (v, ok) in outs:
-        valid = jnp.concatenate([jnp.reshape(ok, (1,)).astype(jnp.bool_),
-                                 jnp.zeros(pad, jnp.bool_)])
-        if isinstance(v, CV):
-            off = v.offsets
-            off_p = jnp.concatenate(
-                [off, jnp.full((pad,), off[-1], off.dtype)])
-            cvs.append(CV(v.data, valid, off_p, v.children))
-        else:
-            data = jnp.concatenate(
-                [v, jnp.zeros((pad,) + v.shape[1:], v.dtype)])
-            cvs.append(CV(data, valid))
+    with tracing.span("agg.pad", "op"):
+        for (v, ok) in outs:
+            valid = jnp.concatenate(
+                [jnp.reshape(ok, (1,)).astype(jnp.bool_),
+                 jnp.zeros(pad, jnp.bool_)])
+            if isinstance(v, CV):
+                off = v.offsets
+                off_p = jnp.concatenate(
+                    [off, jnp.full((pad,), off[-1], off.dtype)])
+                cvs.append(CV(v.data, valid, off_p, v.children))
+            else:
+                data = jnp.concatenate(
+                    [v, jnp.zeros((pad,) + v.shape[1:], v.dtype)])
+                cvs.append(CV(data, valid))
     return cvs
 
 

@@ -21,6 +21,7 @@ import threading
 import uuid
 from typing import List, Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -30,6 +31,7 @@ from ..ops.gather import take
 from ..ops.hash import partition_ids
 from ..ops.kernel_utils import CV
 from ..ops.partition import runs_by_target, sorted_by_target, word_count
+from ..profiler import tracing
 from ..shuffle.local import LocalShuffle
 from ..shuffle.serializer import HostSubBatch
 from ..utils.transfer import fetch
@@ -225,10 +227,13 @@ class ShuffleExchangeExec(TpuExec):
                     m.add("mapSortWords", words)
                     m.add("mapGatheredColumns", gathered)
                     # tpulint: allow[sync-under-lock] the map phase IS the critical section: _lock memoizes the whole shuffle build and readers only need it after _shuffle is set
-                    return fetch({
+                    host = fetch({
                         "cols": [cv_shuffle_bufs(cv) for cv in out],
                         "counts": counts,
                     })
+                    m.add("shuffleD2HBytes", sum(
+                        a.nbytes for a in jax.tree_util.tree_leaves(host)))
+                    return host
 
             def slice_into(host, pieces):
                 """Host-side: cut one map pass output into per-reduce
@@ -273,7 +278,8 @@ class ShuffleExchangeExec(TpuExec):
                     if batch is None:
                         break
                     for host in hosts:
-                        slice_into(host, pieces)
+                        with tracing.span("shuffle.slice", "op"):
+                            slice_into(host, pieces)
                 with m.timer("writeTime"):
                     sh.write_map_partition(mpid, pieces)
                 _count_map_exec()
@@ -293,7 +299,6 @@ class ShuffleExchangeExec(TpuExec):
                         sem, priority=getattr(ctx, "sem_priority", 0),
                         token=ctx.cancel)
                     stop = threading.Event()
-                    from ..profiler import tracing
                     _tc = tracing.current()
 
                     def _map_task(mpid, rider, stop):
@@ -335,6 +340,9 @@ class ShuffleExchangeExec(TpuExec):
             # makes): serialized bytes through this exchange, for the
             # event log / EXPLAIN ANALYZE
             m.set("shuffleBytesWritten", sh.metrics["bytesWritten"])
+            # the same blocks before the codec, and how many there were
+            m.set("shuffleRawBytes", sh.metrics["rawBytesWritten"])
+            m.set("shuffleBlocksWritten", sh.metrics["blocksWritten"])
             self._pstats = sh.partition_stats()
             # exact per-reduce-partition byte distribution (write-time
             # accumulated, shuffle/local.py) — the skew detector's
@@ -363,11 +371,16 @@ class ShuffleExchangeExec(TpuExec):
             m.add("shuffleBytesRead", pstats[rpid] // max(nchunks, 1))
         with m.timer("fetchAndMergeTime"):
             if nchunks == 1:
-                return retry_no_split(
+                batch = retry_no_split(
                     lambda: self._shuffle.reduce_batch(rpid))
-            return retry_no_split(
-                lambda: self._shuffle.reduce_batch_slice(rpid, chunk,
-                                                         nchunks))
+            else:
+                batch = retry_no_split(
+                    lambda: self._shuffle.reduce_batch_slice(rpid, chunk,
+                                                             nchunks))
+        if batch is not None:
+            # the padded buffers the upload moved, bucket padding and all
+            m.add("shuffleH2DBytes", batch.table.nbytes)
+        return batch
 
     def execute_partition(self, ctx: ExecContext, pid: int):
         batch = self.read_slice(ctx, pid)

@@ -18,6 +18,9 @@ __all__ = ["render_analyze", "fmt_bytes"]
 
 _SHUFFLE_BYTE_KEYS = ("shuffleBytesWritten", "shuffleBytesRead",
                       "rawBytes")
+_SHUFFLE_PHASE_BYTE_KEYS = (("raw", "shuffleRawBytes"),
+                            ("d2h", "shuffleD2HBytes"),
+                            ("h2d", "shuffleH2DBytes"))
 
 
 def fmt_bytes(n) -> str:
@@ -64,7 +67,15 @@ def render_analyze(tree: dict, metrics_by_lore: Dict[Optional[int], dict],
             ann.append(f"time={t * 1e3:.1f}ms")
         shuffle = sum(m.get(k, 0) for k in _SHUFFLE_BYTE_KEYS)
         if shuffle:
-            ann.append(f"shuffle={fmt_bytes(shuffle)}")
+            # the one-chip exchange's bytes at each host boundary: before
+            # the codec, fetched by the map passes, uploaded padded by
+            # the reduce side; and the blocks written
+            parts = [f"{label}:{fmt_bytes(m[k])}"
+                     for label, k in _SHUFFLE_PHASE_BYTE_KEYS if k in m]
+            if "shuffleBlocksWritten" in m:
+                parts.append(f"blocks:{int(m['shuffleBlocksWritten'])}")
+            ann.append(f"shuffle={fmt_bytes(shuffle)}"
+                       + ("{" + ", ".join(parts) + "}" if parts else ""))
         if m.get("spillBytes"):
             ann.append(f"spill={fmt_bytes(m['spillBytes'])}")
         if m.get("deviceDecodedChunks"):

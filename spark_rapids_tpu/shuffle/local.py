@@ -24,6 +24,7 @@ from ..columnar import dtypes as dt
 from ..columnar.column import Column, bucket_capacity
 from ..columnar.table import Schema, Table
 from ..exec.batch import DeviceBatch
+from ..profiler import tracing
 from ..runtime import racedep
 from ..utils.transfer import fetch
 from .serializer import HostSubBatch, read_subbatch, write_subbatch
@@ -85,7 +86,10 @@ class LocalShuffle:
         # reduce-side concatenation must stay byte-identical to serial
         self._map_files: Dict[int, str] = {}
         self._arena = None  # lazy HostArena for reduce-side assembly
-        self.metrics = {"bytesWritten": 0, "blocksWritten": 0}
+        # bytes after the codec and before it (the length prefixes
+        # apart), and blocks, over every map partition written
+        self.metrics = {"bytesWritten": 0, "rawBytesWritten": 0,
+                        "blocksWritten": 0}
         # exact per-reduce-partition serialized bytes + rows, summed at
         # WRITE time (the MapOutputStatistics analog): the skew/coalesce
         # detectors read these without re-opening any map file
@@ -99,10 +103,11 @@ class LocalShuffle:
         file itself is written sequentially with a trailing index."""
         path = os.path.join(self.dir, f"map-{mpid}.bin")
 
-        def ser(sb: HostSubBatch) -> bytes:
-            buf = io.BytesIO()
-            write_subbatch(buf, sb, self.codec)
-            return buf.getvalue()
+        def ser(sb: HostSubBatch):
+            with tracing.span("shuffle.serialize", "op", rows=sb.n_rows):
+                buf = io.BytesIO()
+                raw = write_subbatch(buf, sb, self.codec)
+                return buf.getvalue(), raw
 
         flat = [(rp, sb) for rp in range(self.n)
                 for sb in pieces_per_reduce[rp]]
@@ -111,19 +116,19 @@ class LocalShuffle:
                     self.writer_threads,
                     thread_name_prefix="tpu-shufwrite") as pool:
                 # tpulint: allow[wait-under-lock] serializer pool is private, CPU/file-bound, and takes no locks or permits — join under the exchange build lock cannot cycle
-                blocks = list(pool.map(lambda t: ser(t[1]), flat))
+                done = list(pool.map(lambda t: ser(t[1]), flat))
         else:
-            blocks = [ser(sb) for _, sb in flat]
+            done = [ser(sb) for _, sb in flat]
+        blocks = [b for b, _ in done]
+        nbytes = sum(map(len, blocks))
         index = []  # (offset, length) per reduce partition
-        nbytes = nblocks = 0
-        with open(path, "wb") as f:
+        with tracing.span("shuffle.write", "io", bytes=nbytes,
+                          blocks=len(blocks)), open(path, "wb") as f:
             bi = 0
             for rp in range(self.n):
                 start = f.tell()
                 for sb in pieces_per_reduce[rp]:
                     f.write(blocks[bi])
-                    nbytes += len(blocks[bi])
-                    nblocks += 1
                     bi += 1
                 index.append((start, f.tell() - start))
             idx_off = f.tell()
@@ -134,7 +139,8 @@ class LocalShuffle:
             racedep.note_access("LocalShuffle._map_files", mpid,
                                 write=True)
             self.metrics["bytesWritten"] += nbytes
-            self.metrics["blocksWritten"] += nblocks
+            self.metrics["rawBytesWritten"] += sum(raw for _, raw in done)
+            self.metrics["blocksWritten"] += len(blocks)
             for rp in range(self.n):
                 self._rp_bytes[rp] += index[rp][1]
                 self._rp_rows[rp] += sum(sb.n_rows
@@ -193,7 +199,8 @@ class LocalShuffle:
         def read_one(args) -> List[HostSubBatch]:
             fi, path = args
             out = []
-            with open(path, "rb") as f:
+            with tracing.span("shuffle.read", "io", map_file=fi), \
+                    open(path, "rb") as f:
                 if selected is None:
                     off, ln = self._segment_extent(f, rpid)
                     f.seek(off)
@@ -250,10 +257,15 @@ class LocalShuffle:
         if total == 0:
             return None
         cap = bucket_capacity(total)
-        bufs = [self._assemble([sb.cols[ci] for sb in subs],
-                               [sb.n_rows for sb in subs], f.dtype, cap)
-                for ci, f in enumerate(self.schema.fields)]
-        dev = jax.device_put(bufs)
+        with tracing.span("shuffle.assemble", "op", rows=total,
+                          blocks=len(subs)):
+            bufs = [self._assemble([sb.cols[ci] for sb in subs],
+                                   [sb.n_rows for sb in subs], f.dtype,
+                                   cap)
+                    for ci, f in enumerate(self.schema.fields)]
+        with tracing.span("shuffle.upload", "op", rows=total,
+                          capacity=cap):
+            dev = jax.device_put(bufs)
         if self._arena is not None:
             self._arena.reset()  # safe: device_put copied the buffers
         cols = [Column.build(f.dtype, total, d)

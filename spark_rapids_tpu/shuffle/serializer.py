@@ -22,6 +22,7 @@ from typing import BinaryIO, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..profiler import tracing
 from ..utils.native import pack_validity, unpack_validity
 
 __all__ = ["write_subbatch", "read_subbatch", "HostSubBatch", "wire_spec",
@@ -105,16 +106,20 @@ def _write_col(body: io.BytesIO, c: Dict[str, np.ndarray]):
 
 
 def write_subbatch(out: BinaryIO, sb: HostSubBatch, codec=None) -> int:
+    """Write one length-prefixed block; returns the serialized bytes
+    before the codec (what `out` receives is 8 + the codec's output)."""
     body = io.BytesIO()
     body.write(struct.pack("<IIQ", _MAGIC, len(sb.cols), sb.n_rows))
     for c in sb.cols:
         _write_col(body, c)
     raw = body.getvalue()
+    n_raw = len(raw)
     if codec is not None:
-        raw = codec.compress(raw)
+        with tracing.span("shuffle.compress", "op", bytes=n_raw):
+            raw = codec.compress(raw)
     out.write(struct.pack("<Q", len(raw)))
     out.write(raw)
-    return 8 + len(raw)
+    return n_raw
 
 
 def wire_spec(dtype) -> Dict:
@@ -196,11 +201,17 @@ def read_subbatch(inp: BinaryIO, specs, codec=None) -> \
     if len(hdr) < 8:
         return None
     (blen,) = struct.unpack("<Q", hdr)
+    with tracing.span("shuffle.decode", "op", bytes=blen):
+        return _decode_block(inp, blen, specs, codec)
+
+
+def _decode_block(inp: BinaryIO, blen: int, specs, codec) -> HostSubBatch:
     raw = inp.read(blen)
     if len(raw) < blen:
         raise IOError(f"truncated shuffle block: {len(raw)}/{blen} bytes")
     if codec is not None:
-        raw = codec.decompress(raw)
+        with tracing.span("shuffle.decompress", "op", bytes=blen):
+            raw = codec.decompress(raw)
     buf = memoryview(raw)
     if len(buf) < 16:
         raise IOError("corrupt shuffle block: short header")

@@ -829,3 +829,38 @@ def test_map_counts_words_sorted_and_columns_gathered():
     (m,) = exchange_metrics(q)
     assert m["mapSortWords"] == 8 * passes       # + the row index
     assert m["mapGatheredColumns"] == passes
+
+
+def test_shuffle_counts_bytes_at_each_host_boundary():
+    """`shuffleRawBytes`, `shuffleD2HBytes`, `shuffleH2DBytes` and
+    `shuffleBlocksWritten` in `last_metrics()` and in EXPLAIN ANALYZE's
+    `shuffle=`: uncompressed, the file holds the raw blocks and an 8-byte
+    length prefix each; the codec changes what is written and nothing
+    before it; both copies move at least the live rows' bytes."""
+    n = 1000
+    table = {c: pa.array(np.arange(n, dtype=np.int64) % m)
+             for c, m in (("k", 13), ("a", 7), ("b", 5))}
+    live = n * 3 * (8 + 1)                    # int64 data + a validity byte
+    seen = {}
+    for codec in ("none", "lz4"):
+        s = _mk_session(**{
+            "spark.rapids.tpu.sql.autoBroadcastJoinThreshold": -1,
+            "spark.rapids.tpu.shuffle.compression.codec": codec})
+        q = s.create_dataframe(table).repartition(4, F.col("k"))
+        q.to_arrow()
+        (m,) = [m for m in q.last_metrics().values()
+                if "shuffleRawBytes" in m]
+        seen[codec] = m
+        assert m["shuffleBlocksWritten"] > 0
+        assert m["shuffleD2HBytes"] >= live
+        assert m["shuffleH2DBytes"] >= live
+        plan = q.explain("ANALYZE")
+        assert f"blocks:{m['shuffleBlocksWritten']}" in plan
+        assert "{raw:" in plan and "d2h:" in plan and "h2d:" in plan
+    plain, lz4 = seen["none"], seen["lz4"]
+    assert plain["shuffleBytesWritten"] == (
+        plain["shuffleRawBytes"] + 8 * plain["shuffleBlocksWritten"])
+    for key in ("shuffleRawBytes", "shuffleBlocksWritten",
+                "shuffleD2HBytes", "shuffleH2DBytes"):
+        assert lz4[key] == plain[key], key
+    assert lz4["shuffleBytesWritten"] != plain["shuffleBytesWritten"]

@@ -532,6 +532,74 @@ def test_spans_land_on_the_profilers_clock(tmp_path, trace_on):
     assert not {sp["kind"] for sp in recorded} & tracing.PROFILER_ONLY
 
 
+SHUFFLE_WRITE = ("srt.shuffle.slice", "srt.shuffle.serialize",
+                 "srt.shuffle.compress", "srt.shuffle.write")
+SHUFFLE_READ = ("srt.shuffle.read", "srt.shuffle.decompress",
+                "srt.shuffle.decode", "srt.shuffle.assemble",
+                "srt.shuffle.upload")
+
+
+@pytest.mark.parametrize("codec", ["lz4", "none"])
+def test_shuffle_and_agg_phases_land_on_the_profilers_clock(tmp_path, codec):
+    """The one-chip exchange's host phases and the whole-input aggregate's
+    argument building are spans on the profiler's clock, on the thread
+    that does the work, and never dict records."""
+    at = pa.table({
+        "k": pa.array(np.arange(20_000) % 50, type=pa.int64()),
+        "v": pa.array(np.random.default_rng(7).normal(0, 1, 20_000))})
+    s = _session(tmp_path, **{
+        "spark.rapids.tpu.sql.trace.enabled": True,
+        "spark.rapids.tpu.shuffle.compression.codec": codec})
+    df = s.create_dataframe(at)
+    cached = s.create_dataframe(at).cache()
+
+    def queries():
+        (df.filter(col("v") > 0.0).group_by("k")
+         .agg(F.sum(col("v")).alias("sv")).to_arrow())
+        cached.filter(col("v") > 0.0).agg(F.sum(col("v")).alias("s")) \
+            .to_arrow()
+
+    queries()                           # compile outside the profile
+    events = _profiled(tmp_path, queries)
+    names = {e[1] for e in events}
+    codec_spans = {"srt.shuffle.compress", "srt.shuffle.decompress"}
+    phases = set(SHUFFLE_WRITE + SHUFFLE_READ)
+    if codec == "none":
+        assert not codec_spans & names
+        phases -= codec_spans
+    assert phases | {"srt.agg.whole_args", "srt.agg.pad"} <= names
+    main = {e[0] for e in events if e[1] == "srt.query"}
+    write_time = [e for e in events
+                  if e[1] == "srt.ShuffleExchangeExec.writeTime"]
+    # the map side lies inside writeTime or on a worker thread
+    for ev in (e for e in events if e[1] in SHUFFLE_WRITE):
+        assert ev[0] not in main or any(
+            w[0] == ev[0] and w[2] <= ev[2] and ev[3] <= w[3]
+            for w in write_time), ev
+    assert _inside(events, "srt.shuffle.write",
+                   "srt.ShuffleExchangeExec.writeTime")
+    assert _inside(events, "srt.shuffle.assemble",
+                   "srt.ShuffleExchangeExec.fetchAndMergeTime")
+    assert _inside(events, "srt.shuffle.upload",
+                   "srt.ShuffleExchangeExec.fetchAndMergeTime")
+    if codec != "none":
+        assert _inside(events, "srt.shuffle.compress",
+                       "srt.shuffle.serialize")
+        assert _inside(events, "srt.shuffle.decompress",
+                       "srt.shuffle.decode")
+    assert _inside(events, "srt.agg.whole_args", "srt.collect")
+    assert _inside(events, "srt.agg.pad", "srt.collect")
+    # sizes ride as stats, not in the name
+    ser = next(e for e in events if e[1] == "srt.shuffle.serialize")
+    assert ser[4]["rows"] > 0
+    # the dict sink records none of them
+    recorded = [e for e in read_event_log(s.last_event_log)
+                if e["event"] == "trace_span"]
+    assert recorded
+    assert not [sp for sp in recorded
+                if sp["name"].startswith(("shuffle.", "agg."))]
+
+
 @pytest.mark.parametrize("kind", sorted(tracing.PROFILER_ONLY))
 def test_profiler_only_kinds_never_reach_drain_trace(kind):
     tc = tracing.TraceContext("unit-only-" + kind, None, True)
