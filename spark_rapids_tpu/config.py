@@ -65,7 +65,7 @@ HBM_POOL_BYTES = _conf(
 HOST_MEMORY_LIMIT = _conf(
     "memory.host.limitBytes", 0,
     "GLOBAL host-DRAM byte budget shared by the spill store's host "
-    "tier, async write buffers, and shuffle-assembly arenas "
+    "tier and async write buffers "
     "(HostAlloc.scala:36 analog; limits RapidsConf.scala:337-353). "
     "Reservations over budget fire the host->disk pressure cascade; "
     "0 = unlimited.", int)
@@ -170,9 +170,14 @@ ADAPTIVE_CALIBRATION = _conf(
     "fingerprints, correcting CBO row estimates (join reorder) for "
     "later plans of the same subtrees.", bool)
 SHUFFLE_COMPRESS = _conf(
-    "shuffle.compression.codec", "lz4",
-    "Shuffle wire compression: none|lz4|zstd (nvcomp analog, host-side).",
-    str)
+    "shuffle.compression.codec", "none",
+    "Codec of the local file shuffle's blocks: none|zstd|lz4 (host-side; "
+    "lz4 needs the lz4 module). The local transport writes its blocks and "
+    "reads them back on one host, so compression only trades host CPU "
+    "for bytes of a scratch file: none by default, zstd for a shuffle "
+    "directory that is small or slow. A codec whose module is missing "
+    "raises when the exchange is built: no codec stands in for another. "
+    "The mesh exchanges do not read it.", str)
 EXPLAIN = _conf(
     "sql.explain", "NONE",
     "Explain TPU planning: NONE|NOT_ON_TPU|ALL|VALIDATE "

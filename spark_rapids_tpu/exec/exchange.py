@@ -186,8 +186,8 @@ class ShuffleExchangeExec(TpuExec):
         self._pstats = None
         if sh is not None:
             try:
-                sh.cleanup()   # frees map files + the arena's host-
-            except Exception:  # budget reservation
+                sh.cleanup()   # frees the map files
+            except Exception:
                 pass
         super().release()
 

@@ -32,30 +32,38 @@ from .serializer import HostSubBatch, read_subbatch, write_subbatch
 __all__ = ["LocalShuffle", "get_codec"]
 
 
+def _missing(name: str, module: str) -> ValueError:
+    return ValueError(
+        f"shuffle codec {name!r} needs the {module!r} module, which is not "
+        f"installed: set spark.rapids.tpu.shuffle.compression.codec to "
+        f"'none' or to 'zstd'")
+
+
 def get_codec(name: str):
+    """The codec `name` selects (None for 'none'). Raises ValueError for
+    an unknown name or a codec whose module is missing: no codec ever
+    stands in for another."""
     if name in (None, "none", ""):
         return None
     if name == "lz4":
         try:
             import lz4.frame as lz4f  # optional
-            return lz4f
         except ImportError:
-            import zlib
-            return zlib  # gated fallback: zlib is always available
+            raise _missing(name, "lz4") from None
+        return lz4f
     if name == "zstd":
         try:
             import zstandard  # optional
-
-            class _Z:
-                compress = staticmethod(
-                    lambda b: zstandard.ZstdCompressor().compress(b))
-                decompress = staticmethod(
-                    lambda b: zstandard.ZstdDecompressor().decompress(b))
-            return _Z
         except ImportError:
-            import zlib
-            return zlib
-    raise ValueError(f"unknown codec {name}")
+            raise _missing(name, "zstandard") from None
+
+        class _Z:
+            compress = staticmethod(
+                lambda b: zstandard.ZstdCompressor().compress(b))
+            decompress = staticmethod(
+                lambda b: zstandard.ZstdDecompressor().decompress(b))
+        return _Z
+    raise ValueError(f"unknown shuffle codec {name!r}: use 'none' or 'zstd'")
 
 
 def _np_dtype_for(f_dtype: dt.DataType) -> np.dtype:
@@ -69,6 +77,7 @@ class LocalShuffle:
                  shuffle_dir: str = "/tmp/srtpu-shuffle",
                  writer_threads: int = 4, reader_threads: int = 4,
                  codec: Optional[str] = None):
+        self.codec = get_codec(codec)  # raises before any file exists
         self.id = shuffle_id
         self.n = num_reduce
         self.schema = schema
@@ -78,14 +87,12 @@ class LocalShuffle:
         atexit.register(self.cleanup)  # ShuffleCleanupManager analog
         self.writer_threads = writer_threads
         self.reader_threads = reader_threads
-        self.codec = get_codec(codec)
         from ..runtime import lockdep
         self._lock = lockdep.lock("LocalShuffle._lock")
         # keyed by map partition id and iterated in sorted order: with a
         # parallel map side, COMPLETION order is nondeterministic but
         # reduce-side concatenation must stay byte-identical to serial
         self._map_files: Dict[int, str] = {}
-        self._arena = None  # lazy HostArena for reduce-side assembly
         # bytes after the codec and before it (the length prefixes
         # apart), and blocks, over every map partition written
         self.metrics = {"bytesWritten": 0, "rawBytesWritten": 0,
@@ -266,8 +273,6 @@ class LocalShuffle:
         with tracing.span("shuffle.upload", "op", rows=total,
                           capacity=cap):
             dev = jax.device_put(bufs)
-        if self._arena is not None:
-            self._arena.reset()  # safe: device_put copied the buffers
         cols = [Column.build(f.dtype, total, d)
                 for f, d in zip(self.schema.fields, dev)]
         return DeviceBatch(Table(self.schema.names, cols), total)
@@ -327,10 +332,13 @@ class LocalShuffle:
                 [off, np.full(cap + 1 - len(off), off[-1], np.int32)])
             return {"data": data, "validity": validity, "offsets": off}
         np_dt = _np_dtype_for(dtype)
+        # fresh heap buffers: reduce partitions of one exchange assemble
+        # on several collect threads at once, and the H2D copy may still
+        # read a buffer after device_put returns
         if isinstance(dtype, dt.DecimalType) and dtype.is_decimal128:
             data = np.zeros((cap, 2), np_dt)
         else:
-            data = self._arena_zeros(cap, np_dt)
+            data = np.zeros(cap, np_dt)
         pos = 0
         for c, n in zip(cols, ns):
             data[pos:pos + n] = c["data"][:n]
@@ -338,52 +346,6 @@ class LocalShuffle:
             pos += n
         return {"data": data, "validity": validity}
 
-    def _arena_zeros(self, count: int, np_dt) -> np.ndarray:
-        """Assembly buffer from the native host arena (RMM-host-pool
-        analog); heap fallback when absent or full."""
-        import jax
-        from ..utils.native import HostArena, native_lib
-        # On the CPU backend device_put may ALIAS host memory, so arena
-        # reset would corrupt live batches; accelerators always copy H2D.
-        if jax.default_backend() == "cpu":
-            return np.zeros(count, np.dtype(np_dt))
-        if self._arena is None and native_lib() is not None:
-            try:
-                # the shuffle-assembly arena draws from the GLOBAL host
-                # budget (HostAlloc analog); denied -> heap fallback
-                from ..memory.host import HostBudgetExceeded, host_manager
-                hm = host_manager()
-                try:
-                    hm.reserve(256 << 20)
-                except HostBudgetExceeded:
-                    raise MemoryError("host budget")
-                try:
-                    self._arena = HostArena(256 << 20)
-                    self._arena_reserved = True
-                except MemoryError:
-                    hm.release(256 << 20)
-                    raise
-            except MemoryError:
-                self._arena = None
-        if self._arena is not None:
-            arr = self._arena.alloc_array(count, np_dt)
-            if arr is not None:
-                arr[:] = 0
-                return arr
-        return np.zeros(count, np.dtype(np_dt))
-
     def cleanup(self):
         import shutil
-        if getattr(self, "_arena_reserved", False):
-            # return the arena's host-budget reservation (one per
-            # shuffle exchange; leaking it would starve the budget)
-            from ..memory.host import host_manager
-            host_manager().release(256 << 20)
-            self._arena_reserved = False
-        if self._arena is not None:
-            try:
-                self._arena.close()
-            except Exception:
-                pass
-            self._arena = None
         shutil.rmtree(self.dir, ignore_errors=True)

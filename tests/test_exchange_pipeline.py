@@ -842,7 +842,7 @@ def test_shuffle_counts_bytes_at_each_host_boundary():
              for c, m in (("k", 13), ("a", 7), ("b", 5))}
     live = n * 3 * (8 + 1)                    # int64 data + a validity byte
     seen = {}
-    for codec in ("none", "lz4"):
+    for codec in ("none", "zstd"):
         s = _mk_session(**{
             "spark.rapids.tpu.sql.autoBroadcastJoinThreshold": -1,
             "spark.rapids.tpu.shuffle.compression.codec": codec})
@@ -857,10 +857,10 @@ def test_shuffle_counts_bytes_at_each_host_boundary():
         plan = q.explain("ANALYZE")
         assert f"blocks:{m['shuffleBlocksWritten']}" in plan
         assert "{raw:" in plan and "d2h:" in plan and "h2d:" in plan
-    plain, lz4 = seen["none"], seen["lz4"]
+    plain, zstd = seen["none"], seen["zstd"]
     assert plain["shuffleBytesWritten"] == (
         plain["shuffleRawBytes"] + 8 * plain["shuffleBlocksWritten"])
     for key in ("shuffleRawBytes", "shuffleBlocksWritten",
                 "shuffleD2HBytes", "shuffleH2DBytes"):
-        assert lz4[key] == plain[key], key
-    assert lz4["shuffleBytesWritten"] != plain["shuffleBytesWritten"]
+        assert zstd[key] == plain[key], key
+    assert zstd["shuffleBytesWritten"] != plain["shuffleBytesWritten"]

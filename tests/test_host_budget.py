@@ -1,6 +1,6 @@
 """Global host-memory budget (HostAlloc.scala:36 analog; limits
-RapidsConf.scala:337-353): the spill store's host tier, async write
-buffers, and shuffle arenas draw from ONE byte budget; overcommit
+RapidsConf.scala:337-353): the spill store's host tier and async
+write buffers draw from ONE byte budget; overcommit
 cascades host->disk instead of growing RSS (r4 verdict next #10)."""
 import numpy as np
 import pyarrow as pa

@@ -539,7 +539,7 @@ SHUFFLE_READ = ("srt.shuffle.read", "srt.shuffle.decompress",
                 "srt.shuffle.upload")
 
 
-@pytest.mark.parametrize("codec", ["lz4", "none"])
+@pytest.mark.parametrize("codec", ["zstd", "none"])
 def test_shuffle_and_agg_phases_land_on_the_profilers_clock(tmp_path, codec):
     """The one-chip exchange's host phases and the whole-input aggregate's
     argument building are spans on the profiler's clock, on the thread
