@@ -394,10 +394,12 @@ PARQUET_READER_TYPE = _conf(
 PARQUET_DEVICE_DECODE = _conf(
     "sql.format.parquet.deviceDecode.enabled", True,
     "Decode eligible Parquet column chunks ON DEVICE (flat "
-    "INT32/INT64/FLOAT/DOUBLE/BYTE_ARRAY chunks; UNCOMPRESSED or "
-    "SNAPPY; PLAIN or dictionary encoded; v1 and v2 data pages): raw "
-    "bytes upload once, PLAIN lane assembly + RLE run expansion + "
-    "string offset extraction + def-level masking run as XLA programs "
+    "INT32/INT64/FLOAT/DOUBLE/BYTE_ARRAY chunks and FIXED_LEN_BYTE_ARRAY "
+    "decimals of at most 8 bytes; UNCOMPRESSED or SNAPPY; PLAIN, "
+    "dictionary encoded, or dictionary pages then PLAIN pages; v1 and "
+    "v2 data pages): the host lays values and bit-packed runs out, and "
+    "value assembly + bit unpacking + dictionary lookup + string offset "
+    "extraction + def-level masking run as XLA programs named pq_* "
     "(GpuParquetScan.scala:3364 Table.readParquet analog). Snappy "
     "pages decompress per-page on the multithreaded prefetch pool, "
     "off the compute thread. Ineligible columns fall back to host "

@@ -278,7 +278,8 @@ class ParquetScanExec(TpuExec):
         from ..columnar.column import Column, bucket_capacity
         from ..config import PARQUET_DEVICE_SNAPPY
         from ..io.file_cache import cached_local_path
-        from ..io.parquet_device import (chunk_device_plan,
+        from ..io.parquet_device import (DecodeFallback,
+                                         chunk_device_plan,
                                          decode_chunk_device,
                                          eligible_chunks,
                                          fallback_reasons)
@@ -297,17 +298,21 @@ class ParquetScanExec(TpuExec):
             for name, (cat, _detail) in fallback_reasons(
                     pf, 0, cols).items():
                 m.add(f"deviceDecodeFallback.{cat}", 1)
+            # the whole file takes the host path
+            m.add("hostDecodedChunks",
+                  len(cols) * pf.metadata.num_row_groups)
             return None
         kept = (prune_row_groups(pf, self.filters) if self.filters
                 else list(range(pf.metadata.num_row_groups)))
 
         # the decode unit is a whole row group; cap the batch-size blowup
         # vs the host path (which slices to batch_size_rows) to bound the
-        # device-memory spike on huge row groups. Checked BEFORE any
-        # metric: the host fallback records skippedRowGroups itself.
+        # device-memory spike on huge row groups. Checked BEFORE the
+        # other metrics: the host fallback records skippedRowGroups itself.
         per = max(1, ctx.conf.batch_size_rows)
         if any(pf.metadata.row_group(rg).num_rows > 4 * per
                for rg in kept):
+            m.add("hostDecodedChunks", len(cols) * len(kept))
             return None
         m.add("skippedRowGroups", pf.metadata.num_row_groups - len(kept))
         field_by_name = {f.name: f for f in self.schema.fields}
@@ -349,21 +354,24 @@ class ParquetScanExec(TpuExec):
                             # non-micros: host path converts
                             m.add("deviceDecodeFallback.type", 1)
                             continue
-                        c = chunk_device_plan(
-                            pf, lp, rg, ci, name, af.nullable,
-                            pool=pool, decomp_pool=decomp,
-                            device_snappy=dev_snappy, metrics=m)
+                        c = None
                         try:
-                            got = (decode_chunk_device(c, cap,
-                                                       metrics=m)
-                                   if c else None)
-                        except Exception:
-                            got = None      # leases must not leak
-                        if got is None:
+                            c = chunk_device_plan(
+                                pf, lp, rg, ci, name, af.nullable,
+                                pool=pool, decomp_pool=decomp,
+                                device_snappy=dev_snappy, metrics=m)
+                            got = decode_chunk_device(
+                                c, cap, metrics=m,
+                                dtype=_np.dtype(np_dt).name)
+                        except DecodeFallback as e:
+                            m.add(f"deviceDecodeFallback.{e.cause}", 1)
                             if c is not None:
                                 c.close()
-                            m.add("deviceDecodeFallback.pages", 1)
                             continue
+                        except BaseException:
+                            if c is not None:
+                                c.close()   # leases must not leak
+                            raise
                         chunks.append(c)
                         if isinstance(fld.dtype,
                                       (dt.StringType, dt.BinaryType)):
@@ -373,16 +381,16 @@ class ParquetScanExec(TpuExec):
                                                     offsets)
                         else:
                             vals, valid = got
-                            if str(vals.dtype) != _np.dtype(np_dt).name:
-                                vals = vals.astype(np_dt)
                             dev_cols[name] = Column(fld.dtype, nrows,
                                                     vals, valid)
                         m.add("deviceDecodeBytes", rgmd.column(ci)
                               .total_compressed_size)
                     rest = [n for n in cols if n not in dev_cols]
                     if rest:
-                        at = pf.read_row_group(rg, columns=rest)
-                        host_tbl = Table.from_arrow(at)
+                        with tracing.span("io.host_decode", "io"):
+                            at = pf.read_row_group(rg, columns=rest)
+                            host_tbl = Table.from_arrow(at)
+                        m.add("hostDecodedChunks", len(rest))
                         host_by_name = dict(zip(at.schema.names,
                                                 host_tbl.columns))
                     else:

@@ -80,6 +80,8 @@ def render_analyze(tree: dict, metrics_by_lore: Dict[Optional[int], dict],
             ann.append(f"spill={fmt_bytes(m['spillBytes'])}")
         if m.get("deviceDecodedChunks"):
             ann.append(f"devDecoded={int(m['deviceDecodedChunks'])}")
+        if m.get("hostDecodedChunks"):
+            ann.append(f"hostDecoded={int(m['hostDecodedChunks'])}")
         if m.get("decompressBusySecs"):
             ann.append(
                 f"decompress={m['decompressBusySecs'] * 1e3:.1f}ms")

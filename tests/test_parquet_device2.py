@@ -195,7 +195,7 @@ def _snappy_device_roundtrip(payload: bytes):
     dst[:len(dl)], lit[:len(dl)], src[:len(dl)] = dl, ll, sl
     cap = pd.bucket_len(max(out_len, 1), floor=128)
     kbits = max(1, (cap - 1).bit_length())
-    got = pd.snappy_expand(
+    got = pd.pq_snappy_expand(
         jnp.asarray(np.frombuffer(comp, np.uint8)), jnp.asarray(dst),
         jnp.asarray(lit), jnp.asarray(src), len(dl), out_len, kbits,
         cap)
