@@ -6,9 +6,13 @@ item 1): cuDF launches warp-per-row kernels with dynamic outputs; XLA wants
 static shapes and regular parallelism. The design here works in the BYTE
 DOMAIN: a byte->row map (searchsorted over offsets) turns every per-row
 variable-length loop into a dense vectorized pass over the data buffer,
-and per-row results come back via segment reductions. Output buffers are
-sized by exact computed byte totals (cumsum of per-row output lengths) —
-capacity equals the input's byte capacity for non-growing ops.
+and per-row results come back via segment reductions. A literal pattern
+(contains, LIKE, instr) needs no byte->row map: shifted compares of the
+buffer mark match starts, and a prefix count of them read at each row's
+bounds answers per row; startswith/endswith read only each row's ends.
+Output buffers are sized by exact computed byte totals (cumsum of per-row
+output lengths) — capacity equals the input's byte capacity for
+non-growing ops.
 
 ASCII-only case mapping round-1 (documented in docs/compatibility.md).
 """
@@ -19,6 +23,7 @@ from typing import List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from .groups import _segmented_scan
 from .kernel_utils import CV
 
 __all__ = ["byte_row_map", "str_len_bytes", "str_len_chars", "upper",
@@ -199,22 +204,44 @@ def compare(a: CV, b: CV):
     return jnp.where(has_diff, cmp_diff, cmp_len).astype(jnp.int8)
 
 
-def _find_literal(cv: CV, pattern: bytes, wildcard=None):
-    """bool per byte position: pattern matches starting here (within the
-    row). Bytes equal to `wildcard` (e.g. ord('_')) match anything."""
-    dcap = cv.data.shape[0]
-    row = byte_row_map(cv.offsets, dcap)
-    pos = jnp.arange(dcap, dtype=jnp.int32)
-    rel = pos - cv.offsets[row]
-    lens = str_len_bytes(cv)
+def _match_starts(data, pattern: bytes, wildcard=None):
+    """bool per byte position: the pattern's bytes start here. One
+    compare of the byte buffer a pattern byte, against the buffer
+    shifted by that byte's place (padded so each shift keeps its
+    length); bytes equal to `wildcard` (e.g. ord('_')) match anything.
+    A start whose match runs past its row is the caller's to drop."""
+    dcap = data.shape[0]
     m = len(pattern)
-    ok = (rel >= 0) & (rel + m <= lens[row])
+    padded = jnp.concatenate([data, jnp.zeros((m,), data.dtype)])
+    hit = jnp.ones((dcap,), jnp.bool_)
     for j, pb in enumerate(pattern):
         if wildcard is not None and pb == wildcard:
             continue
-        idx = jnp.clip(pos + j, 0, dcap - 1)
-        ok = ok & (cv.data[idx] == pb)
-    return ok, row, rel, lens
+        hit = hit & (padded[j:j + dcap] == pb)
+    return hit
+
+
+def _starts_in(cv: CV, pattern: bytes, wildcard, skip_prefix: int,
+               skip_suffix: int):
+    """Per row: the prefix count of match starts, the count before the
+    row's first admissible start, and whether a start lies in
+    [offset + skip_prefix, end - skip_suffix - m]. A match that would run
+    into the next row, or into the bytes past the last offset, starts
+    after that range and is never counted. Reads the count at 2n
+    positions: nothing per byte is gathered, scattered or searched. The
+    count is the doubling scan of `ops/groups.py`: `jnp.cumsum` and
+    `lax.associative_scan` take 50-70 s to compile for a v5e at 1 Mi
+    elements."""
+    m = len(pattern)
+    hit = _match_starts(cv.data, pattern, wildcard)
+    count = _segmented_scan(jnp.add, jnp.arange(hit.shape[0]) == 0,
+                            hit.astype(jnp.int32))
+    lo = cv.offsets[:-1] + skip_prefix
+    hi = cv.offsets[1:] - skip_suffix - m
+    last = count.shape[0] - 1
+    before = jnp.where(lo > 0, count[jnp.clip(lo - 1, 0, last)], 0)
+    found = (hi >= lo) & (count[jnp.clip(hi, 0, last)] > before)
+    return count, before, found
 
 
 def contains(cv: CV, pattern: bytes, wildcard=None,
@@ -225,30 +252,32 @@ def contains(cv: CV, pattern: bytes, wildcard=None,
     n = cv.offsets.shape[0] - 1
     if len(pattern) == 0:
         return jnp.ones(n, jnp.bool_)
-    ok, row, rel, lens = _find_literal(cv, pattern, wildcard)
-    if skip_prefix:
-        ok = ok & (rel >= skip_prefix)
-    if skip_suffix:
-        ok = ok & (rel + len(pattern) <= lens[row] - skip_suffix)
-    return jax.ops.segment_max(ok.astype(jnp.int32), row, n) > 0
+    if cv.data.shape[0] < len(pattern):
+        return jnp.zeros(n, jnp.bool_)
+    return _starts_in(cv, pattern, wildcard, skip_prefix, skip_suffix)[2]
+
+
+def _bytes_at(cv: CV, start, pattern: bytes, wildcard=None):
+    """Per row: the row is long enough and its bytes from `start` on
+    equal the pattern's; one gather of n elements a pattern byte."""
+    lens = str_len_bytes(cv)
+    dcap = cv.data.shape[0]
+    ok = lens >= len(pattern)
+    if dcap < len(pattern):
+        return jnp.zeros_like(ok)
+    for j, pb in enumerate(pattern):
+        if wildcard is not None and pb == wildcard:
+            continue
+        ok = ok & (cv.data[jnp.clip(start + j, 0, dcap - 1)] == pb)
+    return ok
 
 
 def startswith(cv: CV, pattern: bytes, wildcard=None):
-    n = cv.offsets.shape[0] - 1
-    if len(pattern) == 0:
-        return jnp.ones(n, jnp.bool_)
-    ok, row, rel, lens = _find_literal(cv, pattern, wildcard)
-    at0 = ok & (rel == 0)
-    return jax.ops.segment_max(at0.astype(jnp.int32), row, n) > 0
+    return _bytes_at(cv, cv.offsets[:-1], pattern, wildcard)
 
 
 def endswith(cv: CV, pattern: bytes, wildcard=None):
-    n = cv.offsets.shape[0] - 1
-    if len(pattern) == 0:
-        return jnp.ones(n, jnp.bool_)
-    ok, row, rel, lens = _find_literal(cv, pattern, wildcard)
-    at_end = ok & (rel == lens[row] - len(pattern))
-    return jax.ops.segment_max(at_end.astype(jnp.int32), row, n) > 0
+    return _bytes_at(cv, cv.offsets[1:] - len(pattern), pattern, wildcard)
 
 
 def trim(cv: CV, left: bool = True, right: bool = True) -> CV:
@@ -294,14 +323,18 @@ def reverse(cv: CV) -> CV:
 
 def find_first(cv: CV, pattern: bytes):
     """1-based position of the first occurrence per row; 0 if absent
-    (Spark instr/locate semantics)."""
+    (Spark instr/locate semantics), counted in bytes. The first start
+    of a row is the first position whose prefix count passes the count
+    before the row: a binary search of n queries over the count."""
     n = cv.offsets.shape[0] - 1
     if len(pattern) == 0:
         return jnp.ones(n, jnp.int32)
-    ok, row, rel, lens = _find_literal(cv, pattern)
-    first = jax.ops.segment_min(
-        jnp.where(ok, rel, jnp.int32(2**30)), row, n)
-    return jnp.where(first < 2**30, first + 1, 0).astype(jnp.int32)
+    if cv.data.shape[0] < len(pattern):
+        return jnp.zeros(n, jnp.int32)
+    count, before, found = _starts_in(cv, pattern, None, 0, 0)
+    first = jnp.searchsorted(count, before, side="right").astype(jnp.int32)
+    return jnp.where(found, first - cv.offsets[:-1] + 1, 0).astype(
+        jnp.int32)
 
 
 

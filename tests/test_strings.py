@@ -227,3 +227,185 @@ def test_like_middle_run_not_in_prefix(session):
     out = df.select(col("s").like("ab%_b%cd").alias("m")).to_arrow()
     # '_b' must occur BETWEEN the 'ab' prefix and 'cd' suffix
     assert out.column(0).to_pylist() == [False, True]
+
+
+# --- literal-pattern kernels (ops/strings.py) against Python on bytes ----
+
+def _string_column(rows, tail=b"", capacity=None):
+    """A string CV holding `rows` (bytes, or None for a null), with `tail`
+    after the last offset and zeros up to `capacity` bytes."""
+    import jax.numpy as jnp
+    import numpy as np
+    from spark_rapids_tpu.ops.kernel_utils import CV
+    body = b"".join(r or b"" for r in rows)
+    lens = [len(r or b"") for r in rows]
+    buf = (body + tail).ljust(capacity or 0, b"\0")
+    return CV(jnp.asarray(np.frombuffer(buf, np.uint8).copy()),
+              jnp.asarray([r is not None for r in rows], jnp.bool_),
+              jnp.asarray(np.cumsum([0] + lens), jnp.int32))
+
+
+def _literal_match_columns():
+    import random
+    rng = random.Random(4242)
+    alphabet = [b"g", b"r", b"e", b"n", b"a", b" ", "é".encode(),
+                "☃".encode()]
+    rows = [None if rng.random() < 0.1 else
+            b"".join(rng.choice(alphabet) for _ in range(rng.randrange(13)))
+            for _ in range(300)]
+    rows += [b"", b"forest green", b"green", "grün".encode(), b"engr",
+             b"gr\xc3\xa9en", b"gren", None]
+    rng.shuffle(rows)
+    return {
+        "random": _string_column(rows, tail=b"green" * 8, capacity=4096),
+        # a pattern that only spans the boundary of two rows
+        "adjacent": _string_column([b"xxgr", b"een", b"gre", b"en", b"g",
+                                    b"reen", None, b"green", b"gree"],
+                                   tail=b"n"),
+        # the pattern only in the buffer past offsets[n]
+        "tail": _string_column([b"ab", b"", b"gr"],
+                               tail=b"eengreen" + b"green" * 4,
+                               capacity=64),
+        "empty": _string_column([b"", None, b""]),
+    }
+
+
+def _like_regex(pat):
+    import re
+    out = []
+    for ch in pat.encode():
+        out.append(b".*" if ch == ord("%") else
+                   b"." if ch == ord("_") else re.escape(bytes([ch])))
+    return re.compile(b"".join(out), re.DOTALL)
+
+
+def _like_reference(pat):
+    rx = _like_regex(pat)
+    return lambda r: rx.fullmatch(r) is not None
+
+
+_LONGEST = b"green" * 4  # longer than every row; only in the tails
+
+_LITERAL_MATCH_CASES = [
+    ("contains", b"green", lambda p: lambda r: p in r),
+    ("contains", b"gre", lambda p: lambda r: p in r),
+    ("contains", b"e", lambda p: lambda r: p in r),
+    ("contains", "é".encode(), lambda p: lambda r: p in r),
+    ("contains", "☃".encode(), lambda p: lambda r: p in r),
+    ("contains", b"forest green", lambda p: lambda r: p in r),
+    ("contains", _LONGEST, lambda p: lambda r: p in r),
+    ("startswith", b"gr", lambda p: lambda r: r.startswith(p)),
+    ("startswith", b"green", lambda p: lambda r: r.startswith(p)),
+    ("startswith", _LONGEST, lambda p: lambda r: r.startswith(p)),
+    ("startswith", b"", lambda p: lambda r: r.startswith(p)),
+    ("endswith", b"en", lambda p: lambda r: r.endswith(p)),
+    ("endswith", "é".encode(), lambda p: lambda r: r.endswith(p)),
+    ("endswith", _LONGEST, lambda p: lambda r: r.endswith(p)),
+    ("instr", b"green", lambda p: lambda r: r.find(p) + 1),
+    ("instr", b"e", lambda p: lambda r: r.find(p) + 1),
+    ("instr", b"", lambda p: lambda r: 1),
+    ("instr", _LONGEST, lambda p: lambda r: r.find(p) + 1),
+    ("like", "%green%", _like_reference),
+    ("like", "%r_e%", _like_reference),
+    ("like", "g%e%n", _like_reference),
+    ("like", "gr%e%en", _like_reference),
+    ("like", "g_e%", _like_reference),
+    ("like", "%_n", _like_reference),
+    ("like", "gr%_n", _like_reference),
+    ("like", "%é%", _like_reference),
+    ("like", "g___n", _like_reference),
+    ("like", "green", _like_reference),
+    ("like", "", _like_reference),
+    ("like", "%", _like_reference),
+]
+
+
+@pytest.mark.parametrize(
+    "op,pattern,reference", _LITERAL_MATCH_CASES,
+    ids=[f"{op}-{p if isinstance(p, str) else p.decode()}-{i}"
+         for i, (op, p, _) in enumerate(_LITERAL_MATCH_CASES)])
+def test_literal_match_vs_python(op, pattern, reference):
+    """contains / startswith / endswith / instr / LIKE per row against
+    Python on the row's bytes, over random rows (empty, null, non-ASCII),
+    rows a pattern only spans the boundary of, a buffer with the pattern
+    past its last offset, and a buffer of no bytes."""
+    import jax
+    import numpy as np
+    from spark_rapids_tpu.expr.string_exprs import Like
+    from spark_rapids_tpu.ops import strings as S
+
+    class _Given:  # the column as a child expression of LIKE
+        def __init__(self, cv):
+            self.cv = cv
+
+        def emit(self, ctx):
+            return self.cv
+
+    def run(cv):
+        if op == "like":
+            return Like(_Given(cv), pattern).emit(None).data
+        kernel = {"contains": S.contains, "startswith": S.startswith,
+                  "endswith": S.endswith, "instr": S.find_first}[op]
+        return kernel(cv, pattern)
+
+    ref = reference(pattern)
+    for name, cv in _literal_match_columns().items():
+        got = np.asarray(jax.jit(run)(cv))
+        off = np.asarray(cv.offsets)
+        data = np.asarray(cv.data).tobytes()
+        valid = np.asarray(cv.validity)
+        for i in range(len(valid)):
+            if not valid[i]:
+                continue
+            row = data[off[i]:off[i + 1]]
+            assert got[i] == ref(row), (name, i, row)
+
+
+@pytest.mark.parametrize("kernel", ["contains", "startswith", "endswith",
+                                    "find_first"])
+def test_literal_match_gathers_no_byte(kernel):
+    """The literal-pattern kernels work on the byte buffer by shifted
+    compares and a doubling prefix count: no gather reads or indexes as
+    many elements as the buffer has bytes, nothing is scattered, no loop
+    carries the bytes (find_first's search loops over the rows), nothing
+    is sorted, and no cumsum or associative scan (a minute to compile for
+    a v5e at 1 Mi elements) is traced."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from spark_rapids_tpu.ops import strings as S
+    from spark_rapids_tpu.ops.kernel_utils import CV
+
+    n, nbytes = 100, 4096
+    fn = getattr(S, kernel)
+    jaxpr = jax.make_jaxpr(lambda d, v, o: fn(CV(d, v, o), b"gr_en"))(
+        jax.ShapeDtypeStruct((nbytes,), jnp.uint8),
+        jax.ShapeDtypeStruct((n,), jnp.bool_),
+        jax.ShapeDtypeStruct((n + 1,), jnp.int32))
+
+    def eqns(jx):
+        for e in jx.eqns:
+            yield e
+            for p in e.params.values():
+                for sub in (p if isinstance(p, (list, tuple)) else [p]):
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):
+                        yield from eqns(inner)
+
+    def size(v):
+        return int(np.prod(v.aval.shape))
+
+    seen = list(eqns(jaxpr.jaxpr))
+    assert seen
+    for e in seen:
+        name = e.primitive.name
+        assert "scatter" not in name, name
+        assert name not in ("sort", "cumsum", "cumprod", "cummax",
+                            "cummin", "cumlogsumexp"), name
+        if name in ("while", "scan"):
+            assert all(size(v) < nbytes for v in e.outvars), e
+        if name == "gather":
+            assert size(e.invars[1]) < nbytes, e
+            assert size(e.outvars[0]) < nbytes, e
+        if name == "slice":
+            assert e.params.get("strides") in (None, (1,)), e
